@@ -16,12 +16,45 @@ Env vars (the DL4J_TPU_* namespace replaces ND4J_*):
 - ``DL4J_TPU_MAX_THREADS=N`` — exposed via ``Environment.maxThreads()``
   for host-side worker pools user code spins up; the bundled native
   codec sizes its own std::thread pool internally.
+
+``configure_compile_cache()`` places JAX's persistent compilation cache;
+every process entry point calls it before its first compile.
 """
 
 from __future__ import annotations
 
 import os
 from typing import Any, Dict, Optional
+
+
+#: programs that took at least this long to compile are stored; JAX's
+#: default (1 s) would skip the smaller serving warm-pool programs
+_CACHE_MIN_COMPILE_SECS = 0.1
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this does nothing. Otherwise the cache is ``<checkout>/.jax_cache``,
+    derived from this file's location: a cache that moves between runs
+    is never hit, so the path must not come from a temp dir, a pid or
+    the clock. Call it first thing in a process entry point
+    (``chip_smoke.py``, ``bench*.py``, ``prof*.py``,
+    ``control/worker.py``, ``examples/``) — before the first compile,
+    never at package import."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      _CACHE_MIN_COMPILE_SECS)
+    return path
 
 
 class Environment:

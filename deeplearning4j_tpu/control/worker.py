@@ -392,6 +392,18 @@ def main(argv: Sequence[str]) -> int:
     p.add_argument("name")
     p.add_argument("--heartbeat-s", type=float, default=0.2)
     args = p.parse_args(list(argv))
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
+    if os.environ.get("TPU_VISIBLE_CHIPS"):
+        # the supervisor assigned this process a chip: take it now, so
+        # that a worker which cannot have it ends here, non-zero, with
+        # JAX's reason in worker.log
+        import jax
+
+        jax.devices()
     return _WorkerMain(args.control_dir, args.name,
                        args.heartbeat_s).run()
 
@@ -591,6 +603,21 @@ class WorkerSupervisor:
 
             env.update(worker_env(self.coordinator, len(self._names),
                                   self._names.index(name)))
+        elif env.get("JAX_PLATFORMS", "").split(",")[0] == "tpu":
+            # One process per chip. On a TPU host the first process
+            # that touches JAX takes every chip and the next one fails,
+            # so worker i is given chip i and nothing else (libtpu's
+            # own variables; brought up on a v5e 2x2 host, PR 21). The
+            # worker's JAX_PLATFORMS names the TPU alone: a worker that
+            # cannot have its chip must not run on the CPU instead.
+            i = self._names.index(name)
+            env.update({
+                "JAX_PLATFORMS": "tpu",
+                "TPU_VISIBLE_CHIPS": str(i),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + i}",
+                "TPU_MESH_CONTROLLER_PORT": str(8476 + i)})
         return env
 
     def _spawn(self, name: str) -> None:

@@ -33,7 +33,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.common.serde import serializable
-from deeplearning4j_tpu.parallel.mesh import axis_size as _axis_size
 
 
 @serializable
@@ -406,7 +405,7 @@ class TransformerEncoder:
         cfg = self.cfg
         cd = self._cdtype
         n, t = ids.shape
-        n_sp = _axis_size(sp_axis)  # static inside shard_map
+        n_sp = lax.axis_size(sp_axis)  # static inside shard_map
         if t * n_sp > cfg.max_len:
             raise ValueError(
                 f"global sequence {t}*{n_sp}={t * n_sp} exceeds "
@@ -489,13 +488,13 @@ class TransformerEncoder:
                                                           None, r),
                     mesh=mesh,
                     in_specs=(rep, dp_sp, dp_sp, dp_sp, rep),
-                    out_specs=(rep, rep), check_rep=False)
+                    out_specs=(rep, rep), check_vma=False)
                 loss, grads = smapped(params, ids, labels, mask_pos, rng)
             else:
                 smapped = shard_map(
                     per_shard_grads, mesh=mesh,
                     in_specs=(rep, dp_sp, dp_sp, dp_sp, dp_sp, rep),
-                    out_specs=(rep, rep), check_rep=False)
+                    out_specs=(rep, rep), check_vma=False)
                 loss, grads = smapped(params, ids, labels, mask_pos,
                                       pad_mask, rng)
             new_params, new_opt = self._apply_updates(

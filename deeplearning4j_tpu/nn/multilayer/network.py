@@ -650,8 +650,8 @@ class MultiLayerNetwork:
         # deliberately so; blocking here would stall the pipeline
         _telemetry.record_phase("device_step", t_step)
         # keep the loss on-device: a float() here would force a host sync
-        # every step and stall the dispatch pipeline (very costly over a
-        # remote/tunneled accelerator); score() converts lazily
+        # every step and stall the dispatch pipeline; score() converts
+        # lazily
         self._score = loss
         self._iteration += 1
         self._last_batch_size = int(x.shape[0])

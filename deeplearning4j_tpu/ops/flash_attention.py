@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 from deeplearning4j_tpu.ops.registry import register_op
 
@@ -140,14 +141,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *,
     a0 = jnp.zeros((bq, dh), jnp.float32)
     m, l, acc = lax.fori_loop(0, nblk, body, (m0, l0, a0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-try:  # pallas import is cheap; kernels only build when called
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
 
 
 def pallas_flash_forward(q, k, v, mask=None, causal: bool = False,

@@ -121,13 +121,6 @@ def _k_fused(sc_ref, g_ref, m_ref, v_ref, p_ref, om_ref, ov_ref,
                    - (al * m / (jnp.sqrt(v) + eps)).astype(op_ref.dtype))
 
 
-def _pick_block(total, cap):
-    b = min(cap, total)
-    while total % b:
-        b -= 1
-    return b
-
-
 def _pallas_update(master, m, v, grad, scalars, beta1, beta2, eps,
                    interpret):
     from jax.experimental import pallas as pl
@@ -138,9 +131,13 @@ def _pallas_update(master, m, v, grad, scalars, beta1, beta2, eps,
     as2d = lambda a: a.reshape(rows, _LANE)
     # VMEM budget: 7 row-block buffers (4 in + 3 out) double-buffered
     # in f32 — cap each at ~512 KB so the working set stays well under
-    # the ~16 MB VMEM even with pipelining
-    bm = _pick_block(rows, max(8, (512 * 1024) // (4 * _LANE)))
-    grid = (rows // bm,)
+    # the ~16 MB VMEM even with pipelining. Mosaic wants the row block
+    # a multiple of 8 or the whole array, so the block is the cap (or
+    # all rows) and the last grid step may be partial: its
+    # out-of-range rows are read as padding and never written back,
+    # which an elementwise kernel does not notice.
+    bm = min(rows, (512 * 1024) // (4 * _LANE))
+    grid = (pl.cdiv(rows, bm),)
     row_spec = pl.BlockSpec((bm, _LANE), lambda i: (i, 0))
     sc_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     # out_shape order matches the kernel's out refs: (m', v', master')

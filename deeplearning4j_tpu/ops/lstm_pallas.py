@@ -142,10 +142,7 @@ def _run(x_proj, w_hh, h0, c0, k_steps, interpret, collect_cell):
             pltpu.VMEM((n, hidden), jnp.float32),
             pltpu.VMEM((n, hidden), jnp.float32),
         ],
-        # name drift across pallas versions: TPUCompilerParams (older)
-        # was renamed CompilerParams (newer)
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x_proj, w_hh, h0, c0)

@@ -192,11 +192,15 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret):
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [q, kv["k"], kv["v"]]
     if fp8:
-        sc_spec = pl.BlockSpec((1, 1, 1),
+        # one scale per (layer, page, head): viewed as [L, n_pages, H,
+        # 1, 1] so the block's last two dims are the array's (Mosaic
+        # refuses a (1, 1) block over the [n_pages, H] plane itself)
+        sc_spec = pl.BlockSpec((1, 1, 1, 1, 1),
                                lambda n, h, p, t, b: (layer, t[n, p],
-                                                      h))
+                                                      h, 0, 0))
         in_specs += [sc_spec, sc_spec]
-        args += [kv["k_scale"], kv["v_scale"]]
+        args += [kv["k_scale"][..., None, None],
+                 kv["v_scale"][..., None, None]]
     kernel = functools.partial(
         _kernel, layer=layer, page_size=ps,
         sm_scale=float(1.0 / np.sqrt(np.float32(hd))), fp8=fp8)

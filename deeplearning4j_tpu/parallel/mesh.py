@@ -18,33 +18,12 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (re-exported to the call sites)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 log = logging.getLogger("deeplearning4j_tpu")
 
 _dist_initialized = False
-
-try:  # canonical import point: jax.shard_map landed in 0.8
-    from jax import shard_map as _jax_shard_map
-
-    def shard_map(f, **kw):
-        # accept the older check_rep spelling everywhere in this codebase
-        if "check_rep" in kw:
-            kw["check_vma"] = kw.pop("check_rep")
-        return _jax_shard_map(f, **kw)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def axis_size(axis_name: str) -> int:
-    """STATIC size of a named mesh axis inside shard_map.
-    ``jax.lax.axis_size`` only exists on newer jax; a psum of a unit
-    constant is special-cased to a static Python int on every version,
-    so loops like ``for i in range(axis_size('sp'))`` stay unrolled."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def maybe_init_distributed(env: Optional[dict] = None) -> bool:

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel.mesh import axis_size as _axis_size, shard_map
+from deeplearning4j_tpu.parallel.mesh import shard_map
 
 
 def _tmap(f, *trees, **kw):
@@ -214,7 +214,7 @@ class PipelinedTransformer:
 
         def per_shard(params, ids, labels, mask_pos, rng):
             rng = jax.random.fold_in(rng, lax.axis_index("data"))
-            dp = _axis_size("data")
+            dp = lax.axis_size("data")
             n_mb = ids.shape[0]
             # global mask count is params-independent — precompute so
             # the MoE aux term can be pre-scaled by it inside the local
@@ -263,7 +263,7 @@ class PipelinedTransformer:
         in_specs = (specs, P("data"), P("data"), P("data"), P())
         out_specs = (P(), specs)
         smapped = shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+                            out_specs=out_specs, check_vma=False)
 
         def step(params, opt_state, it_step, ids, labels, mask_pos, rng):
             sm = self._split_micro(mesh, n_micro)
@@ -314,7 +314,7 @@ class PipelinedTransformer:
         smapped = shard_map(
             per_shard, mesh=mesh,
             in_specs=(specs, P("data"), P("data"), P("data")),
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
         sm = self._split_micro(mesh, n_micro)
         fn = jax.jit(lambda p, i, l, m: smapped(p, sm(i), sm(l), sm(m)))
         self._eval_cache[key] = fn

@@ -36,8 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.parallel.mesh import axis_size as _axis_size
-
 
 def _online_block(carry, k, v, bias):
     """Fold one K/V block into the streaming-softmax state.
@@ -73,7 +71,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     key (travels around the ring with its K/V block). Returns
     ``[B, H, T_local, D]`` in q's dtype.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, h, tq, d = q.shape
     tk = k.shape[2]
@@ -132,7 +130,7 @@ def ulysses_attention(q, k, v, axis_name: str = "sp",
     [B, H/sp, T, D] (heads sharded), runs dense attention on the full
     sequence locally, and swaps back. Requires sp | H.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, h, t_loc, d = q.shape
     if h % n != 0:
         raise ValueError(f"ulysses needs sp|heads: {n} heads {h}")

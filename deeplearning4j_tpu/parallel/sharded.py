@@ -431,7 +431,7 @@ class ShardedTrainer:
                         in_specs=(P(), P("data"), P("data"), P("data"),
                                   P("data")),
                         out_specs=(P("data"), P("data"), P("data")),
-                        check_rep=False)(
+                        check_vma=False)(
                         sc, flat_m, flat_o["m"], flat_o["v"], fg)
                 else:
                     nm, om, ov = _fused.adam_segment_update(
@@ -614,7 +614,7 @@ class ShardedTrainer:
                 rep,
             )
             fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+                           out_specs=out_specs, check_vma=False)
             return fn(params, states, opt_s, residual, thresholds,
                       it_step, ep_step, x, y, rng)
 
@@ -677,7 +677,7 @@ class ShardedTrainer:
                         _tmap(lambda a: a[None], no_), loss)
 
             fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+                           out_specs=out_specs, check_vma=False)
             return fn(params_stacked, states, opt_stacked, it_step, ep_step,
                       x, y, rng, do_avg)
 

@@ -151,9 +151,8 @@ def check_numerics(tree, mode: ProfilerMode, context: str = "") -> None:
 
     Panic-mode cost is ONE device->host transfer per call: floating
     leaves are fetched together via a single ``jax.device_get`` (a
-    per-leaf ``np.asarray`` would sync the pipeline once per leaf —
-    ruinous over a remote/tunneled accelerator), and the NaN/Inf flags
-    are reduced across all leaves before raising."""
+    per-leaf ``np.asarray`` would sync the pipeline once per leaf), and
+    the NaN/Inf flags are reduced across all leaves before raising."""
     if mode in (ProfilerMode.DISABLED, ProfilerMode.OPERATIONS):
         return
     float_leaves = []

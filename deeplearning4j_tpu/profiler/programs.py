@@ -23,10 +23,11 @@ GB/s, arithmetic intensity and a **roofline verdict** per program:
 - ``unknown``        — XLA reported no flops/bytes for the program
 
 Peaks come from ``profiler/flops.py`` (``PEAK_FLOPS`` +
-``PEAK_HBM_GBPS``); an unknown device kind is warned-and-omitted, never
-guessed — classification then falls back to NOMINAL_* v5e-class ratios
-(labeled ``"nominal"`` in snapshots) so verdicts stay available on CPU
-smoke runs without publishing bogus utilization numbers.
+``PEAK_HBM_GBPS``). On the CPU backend, which has no entry,
+classification uses the NOMINAL_* v5e-class ratios (labeled
+``"nominal"`` in snapshots) so verdicts stay available in CPU test runs
+without publishing utilization numbers; an accelerator whose kind is
+not in the table raises.
 
 Populated from ``telemetry.instrument_jit`` (training/eval step sites)
 and the serving engines' AOT warm pools (decode/prefill/adopt sites).
@@ -85,8 +86,8 @@ log = logging.getLogger("deeplearning4j_tpu")
 
 _FORMAT = "dl4j-tpu-profile-1"
 
-#: classification fallbacks when device 0 has no peak-table entry
-#: (v5e-class bf16 ratios); used for the VERDICT only, never for
+#: classification ratios for the CPU backend, which has no peak-table
+#: entry (v5e-class bf16 ratios); used for the VERDICT only, never for
 #: published utilization numbers.
 NOMINAL_PEAK_FLOPS = 197e12
 NOMINAL_PEAK_HBM_GBPS = 819.0
@@ -255,15 +256,20 @@ class ProgramRegistry:
     # ------------------------------------------------------- snapshot
     @staticmethod
     def _device_peaks() -> Dict[str, Any]:
-        """Device-0 peak entries, warn-once omitted when unknown (same
-        contract as ``peak_flops``)."""
-        dev: Dict[str, Any] = {}
-        try:
-            import jax
+        """Device-0 peak entries. The CPU has none and is classified
+        against the nominal ratios (labelled ``"nominal"``); any other
+        device missing from the peaks table is an error, not a default.
+        """
+        import jax
 
-            dev["kind"] = jax.devices()[0].device_kind
-        except Exception:
-            return dev
+        d0 = jax.devices()[0]
+        dev: Dict[str, Any] = {"kind": d0.device_kind}
+        if d0.platform != "cpu" and d0.device_kind not in PEAK_FLOPS:
+            raise RuntimeError(
+                f"no peak-FLOPs entry for {d0.platform} device kind "
+                f"{d0.device_kind!r}: add it to profiler.flops.PEAK_FLOPS"
+                " (roofline verdicts are not computed against another "
+                "chip's ratios)")
         fl = PEAK_FLOPS.get(dev["kind"])
         if fl is not None:
             dev["peak_flops"] = dict(fl)

@@ -439,7 +439,9 @@ class ServingFleet:
     replicas : engine count (ignored when ``devices`` is given).
     devices : one jax device per replica; None places every replica on
         the default device (the N-CPU-replicas test topology), which
-        also lets them share one AOT compile.
+        also lets them share one AOT compile. On a TPU backend with
+        more than one device, ``replicas > 1`` without ``devices``
+        raises instead of piling onto device 0.
     prefill_threshold : prompts with at least this many tokens prefill
         on the dedicated lane; None disables disaggregation (and then
         a 1-replica fleet is greedy token-identical to a solo engine).
@@ -467,6 +469,17 @@ class ServingFleet:
             replicas = len(devices)
         if replicas < 1:
             raise ValueError("need at least one replica")
+        if (devices is None and replicas > 1
+                and jax.default_backend() == "tpu"
+                and jax.device_count() > 1):
+            # the CPU test mesh shares one default device (and one AOT
+            # compile) across replicas on purpose; on chips that would
+            # pile every replica onto chip 0 while the others idle
+            raise ValueError(
+                f"ServingFleet(replicas={replicas}) on a TPU host with "
+                f"{jax.device_count()} devices needs devices= (one per "
+                "replica, e.g. jax.devices()[:replicas]): without it "
+                "every replica would land on device 0")
         engine_kwargs.setdefault("max_queue", max(256, max_queue))
         self.model = model
         self.fleet_id = f"fleet-{next(_FLEET_IDS)}"

@@ -101,19 +101,15 @@ class PagePool:
         #: ``kv_dtype=`` label value on every SERVING_KV_* series
         self.dtype_label = self.kv_dtype or jnp.dtype(dtype).name
         shape = (n_layers, n_pages, n_heads, page_size, head_dim)
-        self.k = jnp.zeros(shape, store)
-        self.v = jnp.zeros(shape, store)
+        # allocated where they live (device=None is the default
+        # device): a pool must never pass through another chip's HBM
+        self.k = jnp.zeros(shape, store, device=device)
+        self.v = jnp.zeros(shape, store, device=device)
         self.k_scale = self.v_scale = None
         if self.kv_dtype:
             sshape = (n_layers, n_pages, n_heads)
-            self.k_scale = jnp.ones(sshape, jnp.float32)
-            self.v_scale = jnp.ones(sshape, jnp.float32)
-        if device is not None:
-            self.k = jax.device_put(self.k, device)
-            self.v = jax.device_put(self.v, device)
-            if self.kv_dtype:
-                self.k_scale = jax.device_put(self.k_scale, device)
-                self.v_scale = jax.device_put(self.v_scale, device)
+            self.k_scale = jnp.ones(sshape, jnp.float32, device=device)
+            self.v_scale = jnp.ones(sshape, jnp.float32, device=device)
         # LIFO free list: recently-freed pages are re-used first, which
         # keeps the hot working set of pages small and cache-friendly
         self._free: List[int] = list(range(n_pages - 1, 0, -1))

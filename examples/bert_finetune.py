@@ -36,4 +36,9 @@ def main(steps=80):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     main()

@@ -93,6 +93,11 @@ def main(epochs: int = 8, batch: int = 16) -> float:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=8)
     ap.add_argument("--batch", type=int, default=16)

@@ -54,6 +54,11 @@ def main(steps: int = 20, batch: int = 64, num_classes: int = 100):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=64)

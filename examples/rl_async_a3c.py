@@ -49,4 +49,9 @@ def main(updates: int = 800):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     main()

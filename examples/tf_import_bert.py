@@ -96,6 +96,11 @@ def main(layers: int = 2, hidden: int = 64, steps: int = 15):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--hidden", type=int, default=64)

@@ -116,4 +116,9 @@ def main(batch: int = 2, seq: int = 6, d_in: int = 5,
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     main()

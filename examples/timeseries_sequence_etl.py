@@ -97,6 +97,11 @@ def main(epochs: int = 20):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=20)
     main(ap.parse_args().epochs)

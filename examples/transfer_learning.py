@@ -91,6 +91,11 @@ def main(epochs: int = 8):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=8)
     main(ap.parse_args().epochs)

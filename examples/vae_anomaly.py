@@ -65,6 +65,11 @@ def main(steps: int = 200):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     main(ap.parse_args().steps)

@@ -126,6 +126,11 @@ def main(iters: int = 300):
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=300)
     main(ap.parse_args().iters)

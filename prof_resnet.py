@@ -77,6 +77,11 @@ def report(trace_dir: str) -> None:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.common.environment import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     td = sys.argv[1] if len(sys.argv) > 1 else "/tmp/jaxprof"
     capture(td)
     report(td)

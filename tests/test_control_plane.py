@@ -227,6 +227,11 @@ class TestScheduler:
             b = s.submit(control.TrainJob(run("b"), chips=1))
             s.wait(a.job_id, timeout=30, states=("running",))
             s.wait(b.job_id, timeout=30, states=("running",))
+            # "running" is set before run_fn executes: wait, bounded,
+            # for both runner threads to record their grants
+            deadline = time.time() + 30
+            while len(grants) < 2 and time.time() < deadline:
+                time.sleep(0.01)
             assert len(grants["a"]) == 2 and len(grants["b"]) == 1
             assert not set(grants["a"]) & set(grants["b"])
             ev.set()

@@ -687,6 +687,12 @@ class TestWorkerSupervisor:
             task.wait(120)
             assert task.state == "completed"
             assert task.result["echo"] == {"value": 42}
+            # nothing orders the other worker's first heartbeat before
+            # this worker's echo: wait for it, bounded
+            deadline = time.time() + 60
+            while {v["state"] for v in sup.workers_status().values()} \
+                    != {"alive"} and time.time() < deadline:
+                time.sleep(0.05)
             st = sup.workers_status()
             assert {v["state"] for v in st.values()} == {"alive"}
             sup._publish_gauges(force=True)
@@ -698,6 +704,25 @@ class TestWorkerSupervisor:
             assert telemetry.MetricsRegistry.get_default().gauge(
                 telemetry.WORKER_HEARTBEAT_AGE).values()
         assert control.default_supervisor() is None
+
+    def test_worker_env_assigns_one_chip_per_process_on_a_tpu_host(
+            self, tmp_path):
+        """The supervisor stays off JAX; whether the host has chips is
+        what its environment says (JAX_PLATFORMS). Worker i gets chip i
+        and a TPU-only platform list, so it cannot fall back to CPU."""
+        tpu = control.WorkerSupervisor(
+            ["w0", "w1"], control_dir=str(tmp_path / "a"),
+            env={"JAX_PLATFORMS": "tpu,cpu"}, make_default=False)
+        e0, e1 = tpu._worker_env("w0"), tpu._worker_env("w1")
+        assert (e0["TPU_VISIBLE_CHIPS"], e1["TPU_VISIBLE_CHIPS"]) \
+            == ("0", "1")
+        assert e0["JAX_PLATFORMS"] == e1["JAX_PLATFORMS"] == "tpu"
+        assert e0["TPU_MESH_CONTROLLER_PORT"] \
+            != e1["TPU_MESH_CONTROLLER_PORT"]
+        cpu = control.WorkerSupervisor(
+            ["w0"], control_dir=str(tmp_path / "b"),
+            env={"JAX_PLATFORMS": "cpu"}, make_default=False)
+        assert "TPU_VISIBLE_CHIPS" not in cpu._worker_env("w0")
 
     def test_sigkill_migrates_task_and_respawns_worker(self):
         """A SIGKILLed worker PROCESS: its task migrates onto the

@@ -137,6 +137,16 @@ class TestFleetParity:
 
 
 # -------------------------------------------------- routing + affinity
+class TestPlacement:
+    def test_replicas_without_devices_raise_on_a_multichip_tpu(
+            self, model, params, monkeypatch):
+        """The CPU mesh shares the default device across replicas on
+        purpose; on a TPU host that is a pile-up on chip 0."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="needs devices="):
+            ServingFleet(model, params, replicas=2)
+
+
 class TestRouting:
     def test_session_affinity_routes_back_warm(self, model, params):
         rng = np.random.default_rng(3)

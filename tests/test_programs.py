@@ -98,6 +98,21 @@ class TestPeakTables:
         assert len(first) == 2 and len(again) == 2
 
 
+    def test_snapshot_raises_on_an_accelerator_without_peaks(
+            self, monkeypatch):
+        """The CPU is classified against the labelled nominal ratios;
+        an accelerator missing from the peaks table is an error."""
+        reg = programs.ProgramRegistry()
+        assert reg.snapshot()["peak_source"] == "nominal"     # CPU
+
+        class _Chip:
+            platform, device_kind = "tpu", "TPU v0 imaginary"
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Chip()])
+        with pytest.raises(RuntimeError, match="PEAK_FLOPS"):
+            reg.snapshot()
+
+
 # --------------------------------------------------------- verdicts
 class TestRooflineVerdict:
     def test_no_cost_numbers_is_unknown(self):

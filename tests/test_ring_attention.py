@@ -37,11 +37,11 @@ def _run_sharded(fn, mesh, q, k, v, kv_mask=None):
     if kv_mask is None:
         f = shard_map(lambda a, b, c: fn(a, b, c), mesh=mesh,
                       in_specs=(spec, spec, spec), out_specs=spec,
-                      check_rep=False)
+                      check_vma=False)
         return jax.jit(f)(q, k, v)
     f = shard_map(lambda a, b, c, m: fn(a, b, c, kv_mask=m), mesh=mesh,
                   in_specs=(spec, spec, spec, mspec), out_specs=spec,
-                  check_rep=False)
+                  check_vma=False)
     return jax.jit(f)(q, k, v, kv_mask)
 
 
@@ -93,7 +93,7 @@ def test_ring_grads_match_dense():
     def loss_ring(q, k, v):
         f = shard_map(
             lambda a, b, c: ring_attention(a, b, c), mesh=mesh,
-            in_specs=(spec, spec, spec), out_specs=spec, check_rep=False)
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
         return jnp.sum(f(q, k, v) ** 2)
 
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
