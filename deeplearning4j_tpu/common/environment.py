@@ -43,6 +43,11 @@ def configure_compile_cache() -> str:
     (``chip_smoke.py``, ``bench*.py``, ``prof*.py``,
     ``control/worker.py``, ``examples/``) — before the first compile,
     never at package import."""
+    # every compilation and every load from this cache is a record in
+    # the telemetry ring from here on (jit.compile / jit.cache_load)
+    from deeplearning4j_tpu.profiler import telemetry
+
+    telemetry.watch_compilations()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -216,6 +216,8 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret):
                             pltpu.VMEM((Q, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((N, H, Q, hd), q.dtype),
         interpret=interpret,
+        # what a device trace calls the kernel (PERF.md section 3)
+        name="paged_attention",
     )(tables.astype(jnp.int32), qbase.astype(jnp.int32), *args)
 
 

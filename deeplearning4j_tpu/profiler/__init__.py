@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.ops import registry as _registry
 from deeplearning4j_tpu.profiler import flight_recorder, telemetry, tracing
 from deeplearning4j_tpu.profiler.model_health import HealthMonitor
 
@@ -104,6 +103,11 @@ class OpProfiler:
     def _install(self) -> None:
         if self._orig_get_op is not None:
             return
+        # imported here: the op library (and Pallas behind it) takes a
+        # second to load, and everything that only wants telemetry
+        # imports this package
+        from deeplearning4j_tpu.ops import registry as _registry
+
         self._orig_get_op = _registry.get_op
         prof = self
 
@@ -123,6 +127,8 @@ class OpProfiler:
 
     def _uninstall(self) -> None:
         if self._orig_get_op is not None:
+            from deeplearning4j_tpu.ops import registry as _registry
+
             _registry.get_op = self._orig_get_op
             self._orig_get_op = None
 

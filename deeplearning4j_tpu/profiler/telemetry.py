@@ -13,10 +13,16 @@ the single process-wide sink every layer reports through:
   histograms with percentile summaries; Prometheus text exposition
   (``to_prometheus``) and JSON dump (``to_json``). Served by
   ``ui/server.py`` at ``/metrics`` and ``/telemetry``.
-- ``span()`` — nestable host-side timing context manager. Events land
-  in a bounded trace buffer and export as Chrome trace-event JSON
-  (``export_chrome_trace``; loadable in perfetto / chrome://tracing),
-  complementing ``jax.profiler`` DEVICE traces with the HOST story.
+- ``span()`` — the one span primitive: a nestable host-side timing
+  context manager. Every record has an id and the id of the span that
+  caused it; events land in a bounded trace buffer and export as
+  Chrome trace-event JSON (``export_chrome_trace``; loadable in
+  perfetto / chrome://tracing), and while it runs the span is also a
+  ``jax.profiler.TraceAnnotation`` (``dl4j:<name>``), so a profiler
+  capture shows the HOST story beside the DEVICE plane on one clock.
+  ``spans_between`` reads the buffer by time. Compilations and loads
+  from the persistent cache arrive as ``jit.compile`` /
+  ``jit.cache_load`` records (``watch_compilations``).
 - ``instrument_jit(site, fn)`` — recompilation detector. Wraps a
   jitted callable; a growing executable cache (``_cache_size``) marks
   a compile, which is counted + timed per site, and shape/dtype churn
@@ -39,7 +45,7 @@ from __future__ import annotations
 
 import bisect
 import collections
-import contextlib
+import itertools
 import json
 import logging
 import os
@@ -531,22 +537,25 @@ _span_stack = threading.local()
 #: SPANS_DROPPED counter (guarded by _trace_lock — the hot path pays
 #: one int increment, not a registry lookup per wrapped span)
 _spans_dropped_pending = 0
+#: process-unique span ids (``next`` on a count is atomic in CPython)
+_span_ids = itertools.count(1)
+#: args that say where a span sits, not what it measured: never a
+#: metric label (they would explode the label cardinality)
+_STRUCTURAL = ("id", "parent", "depth", "request")
+#: ``jax.profiler.TraceAnnotation``, looked up once on first use; False
+#: where jax is absent (spans are then ring-only)
+_annotation_cls: Any = None
 
 
 def _now_us() -> float:
     return (time.perf_counter() - _T0) * 1e6
 
 
-def record_span(name: str, t0: float, t1: Optional[float] = None,
-                metric: Optional[str] = None, **attrs) -> None:
-    """Record one completed host span. ``t0``/``t1`` are
-    ``time.perf_counter()`` readings; ``metric`` names a histogram in
-    the default registry that receives the duration in SECONDS, with
-    ``attrs`` as its labels."""
-    if not _ENABLED:
-        return
-    if t1 is None:
-        t1 = time.perf_counter()
+def _append(name: str, t0: float, t1: float, args: Dict[str, Any]) -> None:
+    """One completed span into the bounded ring."""
+    global _spans_dropped_pending
+    if not _compile_watch:
+        watch_compilations()
     ev = {
         "name": name,
         "ph": "X",
@@ -554,10 +563,8 @@ def record_span(name: str, t0: float, t1: Optional[float] = None,
         "dur": max(t1 - t0, 0.0) * 1e6,
         "pid": os.getpid(),
         "tid": threading.get_ident(),
+        "args": args,
     }
-    if attrs:
-        ev["args"] = {k: v for k, v in attrs.items()}
-    global _spans_dropped_pending
     with _trace_lock:
         if _trace_events.maxlen is not None \
                 and len(_trace_events) == _trace_events.maxlen:
@@ -567,38 +574,201 @@ def record_span(name: str, t0: float, t1: Optional[float] = None,
             # incomplete trace is attributable, not silently short
             _spans_dropped_pending += 1
         _trace_events.append(ev)
-    if metric is not None:
-        # depth/parent describe span nesting, not a metric dimension —
-        # letting them through would explode the label cardinality
-        labels = {k: str(v) for k, v in attrs.items()
-                  if k not in ("depth", "parent")}
-        MetricsRegistry.get_default().histogram(metric).observe(
-            t1 - t0, **labels)
 
 
-@contextlib.contextmanager
-def span(name: str, metric: Optional[str] = None, **attrs):
-    """Nestable host-side timing span. Nesting is tracked per thread
-    and recorded as a ``depth``/``parent`` arg on the trace event, so
-    perfetto's flame view reconstructs the stack from the complete
-    ('X') events."""
+def _observe(metric: str, seconds: float, attrs: Dict[str, Any]) -> None:
+    labels = {k: str(v) for k, v in attrs.items() if k not in _STRUCTURAL}
+    MetricsRegistry.get_default().histogram(metric).observe(
+        seconds, **labels)
+
+
+def record_span(name: str, t0: float, t1: Optional[float] = None,
+                metric: Optional[str] = None, **attrs) -> Optional[int]:
+    """Record one completed host span — for an interval that is over
+    before anyone can wrap it in ``span()`` (a request's queue wait
+    starts on the client's thread). Ring only: it never enters the
+    profiler's trace. ``t0``/``t1`` are ``time.perf_counter()``
+    readings; ``metric`` names a histogram in the default registry that
+    receives the duration in SECONDS, with ``attrs`` as its labels.
+    ``parent=`` (the id of the span that caused this one) and
+    ``request=`` are kept apart from the labels. Returns the record's
+    id, None when telemetry is off."""
     if not _ENABLED:
-        yield
-        return
-    stack = getattr(_span_stack, "names", None)
-    if stack is None:
-        stack = _span_stack.names = []
-    attrs = dict(attrs)
-    attrs["depth"] = len(stack)
-    if stack:
-        attrs["parent"] = stack[-1]
-    stack.append(name)
-    t0 = time.perf_counter()
+        return None
+    if t1 is None:
+        t1 = time.perf_counter()
+    sid = next(_span_ids)
+    _append(name, t0, t1, dict(attrs, id=sid))
+    if metric is not None:
+        _observe(metric, t1 - t0, attrs)
+    return sid
+
+
+class _Span:
+    """A live span: the context manager ``span()`` returns and the
+    handle its ``with`` yields. ``id`` is the record's id (pass it as
+    ``parent=`` to a span this one causes on another thread); ``set``
+    adds what is known only at the end."""
+
+    __slots__ = ("name", "id", "t0", "t1", "_metric", "_entry", "_late",
+                 "_ann")
+
+    def __init__(self, name: str, metric: Optional[str],
+                 attrs: Dict[str, Any]):
+        self.name = name
+        self.id = next(_span_ids)
+        self.t0 = self.t1 = 0.0
+        self._metric = metric
+        self._entry = attrs
+        self._late: Optional[Dict[str, Any]] = None
+        self._ann = None
+
+    def set(self, **attrs) -> None:
+        """Attributes learnt while the span runs (a burst does not know
+        its steps when it starts). They go to the ring's record; the
+        profiler's annotation and the metric's labels have what was
+        known at entry."""
+        if self._late is None:
+            self._late = attrs
+        else:
+            self._late.update(attrs)
+
+    def __enter__(self) -> "_Span":
+        global _annotation_cls
+        stack = getattr(_span_stack, "spans", None)
+        if stack is None:
+            stack = _span_stack.spans = []
+        a = self._entry
+        parent = a.pop("parent", None)
+        if parent is None and stack:
+            parent = stack[-1].id
+        if parent is not None:
+            a["parent"] = parent
+        a["depth"] = len(stack)
+        if _annotation_cls is None:
+            try:
+                from jax.profiler import TraceAnnotation
+                _annotation_cls = TraceAnnotation
+            except ImportError:
+                _annotation_cls = False
+        self.t0 = time.perf_counter()
+        if _annotation_cls:
+            # an inactive TraceMe while no profiler session runs; with
+            # one, the span lies beside the device plane on the
+            # profiler's clock, and pc_us is its start on the ring's
+            self._ann = _annotation_cls(
+                "dl4j:" + self.name, id=self.id,
+                pc_us=(self.t0 - _T0) * 1e6, **a)
+            self._ann.__enter__()
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _span_stack.spans.pop()
+        args = dict(self._entry, id=self.id)
+        if self._late:
+            args.update(self._late)
+        _append(self.name, self.t0, self.t1, args)
+        if self._metric is not None:
+            _observe(self._metric, self.t1 - self.t0, self._entry)
+        return False
+
+
+class _InertSpan:
+    """What ``span()`` returns with telemetry off: nothing is recorded
+    or annotated and ``set`` keeps nothing. It still reads the clock at
+    both ends, because a caller may hand ``t0``/``t1`` to a record kept
+    under another switch (the per-request timeline,
+    ``DL4J_TPU_TRACING``)."""
+
+    __slots__ = ("t0", "t1")
+    id = None
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def __enter__(self) -> "_InertSpan":
+        self.t0 = self.t1 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        return False
+
+
+def span(name: str, metric: Optional[str] = None, **attrs):
+    """Nestable host-side timing span: ``with span(name, **attrs) as
+    sp:``. The ring's record carries an ``id``, the id of the span that
+    caused it (``parent``: the enclosing span on this thread, or one
+    passed as ``parent=`` where the cause ran on another thread), the
+    nesting ``depth`` and ``request=`` where one applies; ``sp.set``
+    adds what is known only at the end. For its lifetime the span is
+    also a ``jax.profiler.TraceAnnotation`` named ``dl4j:<name>``, so a
+    profiler capture shows it beside the device plane. With telemetry
+    off the handle records nothing; it has ``t0``, ``t1`` and ``id``
+    (None) all the same."""
+    if not _ENABLED:
+        return _InertSpan()
+    return _Span(name, metric, attrs)
+
+
+def spans_between(t0: float, t1: float,
+                  name: Optional[str] = None) -> List[Dict[str, Any]]:
+    """The ring's records that ENDED between two ``perf_counter``
+    readings (a record is written when its span ends), oldest first,
+    optionally of one name."""
+    lo, hi = (t0 - _T0) * 1e6, (t1 - _T0) * 1e6
+    with _trace_lock:
+        events = list(_trace_events)
+    return [e for e in events if lo <= e["ts"] + e["dur"] <= hi
+            and (name is None or e["name"] == name)]
+
+
+# ------------------------------------------------ compilations as events
+_compile_watch = False
+_cache_load = threading.local()
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    """jax 0.9 reports ``backend_compile_duration`` once per program
+    built, and not on a call served from the in-memory cache. A program
+    loaded from the persistent cache reports its retrieval first and
+    then the same backend event (the retrieval's time included), on the
+    same thread: the pair is one ``jit.cache_load``."""
+    if event == _CACHE_RETRIEVAL:
+        _cache_load.pending = True
+    elif event == _BACKEND_COMPILE:
+        loaded = getattr(_cache_load, "pending", False)
+        _cache_load.pending = False
+        if _ENABLED:
+            now = time.perf_counter()
+            record_span("jit.cache_load" if loaded else "jit.compile",
+                        now - duration, now,
+                        fun=str(kw.get("fun_name", "")))
+
+
+def watch_compilations() -> None:
+    """Register the one process-wide listener that writes a ring record
+    for every compilation (``jit.compile``) and every load from the
+    persistent cache (``jit.cache_load``), whoever asked for it: a bare
+    ``jax.jit``, the engine's AOT warm pool. Idempotent; called by the
+    first record and by ``configure_compile_cache``. Nothing to do
+    where jax is absent."""
+    global _compile_watch
+    with _trace_lock:
+        if _compile_watch:
+            return
+        _compile_watch = True
     try:
-        yield
-    finally:
-        stack.pop()
-        record_span(name, t0, metric=metric, **attrs)
+        from jax import monitoring
+    except ImportError:
+        return
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def record_phase(phase: str, t0: float, t1: Optional[float] = None,
@@ -719,8 +889,6 @@ def recent_trace_events(n: int) -> List[Dict[str, Any]]:
     """The newest ``n`` trace events, copying only that slice (a full
     ``chrome_trace()`` copies the whole 50k-event buffer under the
     lock — too heavy for per-poll aggregation)."""
-    import itertools
-
     with _trace_lock:
         k = len(_trace_events)
         if k <= n:
@@ -1185,7 +1353,8 @@ def reset() -> None:
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
-    "span", "record_span", "record_phase", "flush_dropped_spans",
+    "span", "record_span", "spans_between", "watch_compilations",
+    "record_phase", "flush_dropped_spans",
     "chrome_trace", "recent_trace_events", "export_chrome_trace",
     "clear_trace",
     "instrument_jit", "sample_device_memory", "snapshot",

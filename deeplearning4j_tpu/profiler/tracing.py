@@ -8,9 +8,11 @@ module adds that identity layer:
 
 - ``TraceContext`` — a lightweight per-request (or per-fit-site) trace:
   ``trace_id``, optional ``request_id``, host/process id, and a bounded
-  event list. Every event is ALSO emitted into the telemetry span
-  buffer tagged ``trace``/``request``/``host``, so one Chrome-trace
-  export carries both the process story and the per-request story.
+  event list. An event is ALSO emitted into the telemetry span buffer
+  tagged ``trace``/``request``/``host``, so one Chrome-trace export
+  carries both the process story and the per-request story, unless
+  the interval is one the program has already spanned: the timeline
+  then carries that span's id, and the buffer holds it once.
 - The serving engine threads a context through a request's whole life:
   ``submit -> queue_wait -> prefill -> decode_burst* -> finish``. The
   finished timeline lands in a bounded registry served at
@@ -115,10 +117,14 @@ class TraceContext:
         self._elock = threading.Lock()
 
     def event(self, name: str, t0: float, t1: Optional[float] = None,
-              **attrs) -> None:
-        """Record one completed span: into this trace's timeline AND
-        into the process Chrome-trace buffer, tagged with the trace /
-        request / host identity."""
+              span: Optional[int] = None, **attrs) -> None:
+        """Record one completed interval in this trace's timeline.
+        Without ``span`` it ALSO goes into the process Chrome-trace
+        buffer, tagged with the trace / request / host identity. With
+        ``span=<id>`` the interval is one the caller has already spanned
+        (``telemetry.span`` / ``record_span``): the timeline takes the
+        event with that record's id, and the ring is not written a
+        second time."""
         if t1 is None:
             t1 = time.perf_counter()
         ev: Dict[str, Any] = {
@@ -126,10 +132,14 @@ class TraceContext:
             "ts_ms": round((t0 - self._t0) * 1e3, 3),
             "dur_ms": round(max(t1 - t0, 0.0) * 1e3, 3),
         }
+        if span is not None:
+            ev["span"] = span
         if attrs:
             ev.update(attrs)
         with self._elock:
             self._events.append(ev)
+        if span is not None:
+            return
         tags = dict(attrs)
         tags["trace"] = self.trace_id
         tags["host"] = self.host

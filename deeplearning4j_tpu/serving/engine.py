@@ -140,6 +140,10 @@ class ServingRequest:
         #: disaggregated-prefill handoff (ks, vs, bucket, logits) from
         #: the fleet's prefill lane; None for every normal request
         self._handoff = None
+        #: id of the span that caused this request on another thread
+        #: (the fleet's ``fleet.route``): ``engine.admit`` records it as
+        #: its parent. None outside a fleet.
+        self.caused_by: Optional[int] = None
         #: per-request trace (profiler/tracing.py) — None with tracing
         #: off; the timeline is served at /v1/serving/requests/<id>
         self._trace = None
@@ -624,6 +628,15 @@ class DecodeEngine:
         self.n_completed = 0
         self.n_steps = 0         # decode steps (tokens per slot-lane)
         self.n_dispatches = 0    # chunked device calls
+        #: counted where the spans open (engine thread only, no lock):
+        #: K/V positions the paged kernel's calls had to read, a layer
+        #: (a decode step: what each live slot holds; a verify dispatch
+        #: or a suffix prefill: the lane's context once, its queries
+        #: share the read), and prompt tokens prefilled against the
+        #: padded buckets they ran in
+        self.n_attended_tokens = 0
+        self.n_prefill_tokens = 0
+        self.n_prefill_bucket_tokens = 0
         self.n_tokens = 0
         self._occupancy_sum = 0.0
         # newest finished requests (id + finish reason + timings), so
@@ -1292,6 +1305,9 @@ class DecodeEngine:
             "completed": self.n_completed,
             "decode_steps": self.n_steps,
             "dispatches": self.n_dispatches,
+            "attended_tokens": self.n_attended_tokens,
+            "prefill_tokens": self.n_prefill_tokens,
+            "prefill_bucket_tokens": self.n_prefill_bucket_tokens,
             "tokens": self.n_tokens,
             "active_slots": int(self._active.sum()),
             "queued": self._queue.qsize() + len(self._waiting),
@@ -1526,8 +1542,14 @@ class DecodeEngine:
             if plan is None:
                 break        # head-of-line waits for evictions
             self._waiting.popleft()
+            slot = int(np.flatnonzero(~self._active)[0])
             try:
-                self._admit(req, plan)
+                with _telemetry.span(
+                        "engine.admit", parent=req.caused_by,
+                        request=req.request_id, engine=self.engine_id,
+                        slot=slot, reuse=plan["kind"],
+                        pages=len(plan["rows"])):
+                    self._admit(req, plan, slot)
             except BaseException as e:
                 self._release_plan(plan)
                 req._finish("error", e)
@@ -1727,7 +1749,8 @@ class DecodeEngine:
         self.pool.free(plan["rows"] + plan["drop_after_copy"])
 
     # ---------------------------------------------------------- admit
-    def _admit(self, req: ServingRequest, plan: Dict[str, Any]) -> None:
+    def _admit(self, req: ServingRequest, plan: Dict[str, Any],
+               s: int) -> None:
         t0 = int(req.prompt.size)
         ps = self.page_size
         rows: List[int] = plan["rows"]
@@ -1742,76 +1765,89 @@ class DecodeEngine:
         if plan["drop_after_copy"]:
             self.pool.free(plan["drop_after_copy"])
             plan["drop_after_copy"] = []
-        t_pre = time.perf_counter()
-        if plan["kind"] == "handoff":
-            # fleet handoff: the prefill lane computed ks/vs/logits on
-            # its own executable stream; commit is one page scatter
-            ks, vs, bucket, last = req._handoff
-            req._handoff = None
-            if self._device is not None:
-                # cross-device fleet: the lane computed on the default
-                # device; land the stacks on this replica's device
-                ks = jax.device_put(ks, self._device)
-                vs = jax.device_put(vs, self._device)
-            page_row = np.zeros((bucket // ps,), np.int32)
-            n_real = min(len(rows), bucket // ps)
-            page_row[:n_real] = rows[:n_real]
-            extra = ((jnp.asarray(t0, jnp.int32),)
-                     if self.kv_dtype else ())
-            kvt = self._warm.run(
-                ("adopt", bucket), self._adopt_fallback,
-                self.pool.tree(), ks, vs, jnp.asarray(page_row),
-                *extra)
-        elif t_start == 0:
-            bucket = next((b for b in self.prefill_buckets if b >= t0),
-                          kv_pages.pages_needed(t0, ps) * ps)
-            prompt = np.zeros((1, bucket), np.int32)
-            prompt[0, :t0] = req.prompt
-            page_row = np.zeros((bucket // ps,), np.int32)
-            n_real = min(len(rows), bucket // ps)
-            page_row[:n_real] = rows[:n_real]
-            kvt, last = self._warm.run(
-                ("prefill", bucket), self._prefill_fallback, self.params,
-                self.pool.tree(), jnp.asarray(prompt),
-                jnp.asarray(page_row), jnp.asarray(t0, jnp.int32))
+        handoff = plan["kind"] == "handoff"
+        sl = t0 - t_start            # prompt tokens this prefill computes
+        if handoff:
+            bucket = req._handoff[2]
         else:
-            # warm path: prefill ONLY the uncached suffix, mid-page
-            # starts included — attention reads the shared prefix
-            # pages through the full table
-            sl = t0 - t_start
             bucket = next((b for b in self.prefill_buckets if b >= sl),
                           kv_pages.pages_needed(sl, ps) * ps)
-            suffix = np.zeros((bucket,), np.int32)
-            suffix[:sl] = req.prompt[t_start:]
-            table = np.zeros((self.pages_per_slot,), np.int32)
-            table[:len(rows)] = rows
-            kvt, last = self._warm.run(
-                ("prefix_prefill", bucket),
-                self._prefix_prefill_fallback, self.params,
-                self.pool.tree(), jnp.asarray(suffix),
-                jnp.asarray(table), jnp.asarray(t_start, jnp.int32),
-                jnp.asarray(t0, jnp.int32))
-        logits = np.asarray(last)
-        t_post = time.perf_counter()
+        t_pre = time.perf_counter()
+        wait_id = _telemetry.record_span(
+            "request.queue_wait", req._t_submit, t_pre,
+            request=req.request_id, engine=self.engine_id,
+            prompt_tokens=t0)
+        if not handoff:
+            self.n_prefill_tokens += sl
+            self.n_prefill_bucket_tokens += bucket
+        # from the dispatch to the host's read of the last logits: the
+        # engine waits for the device here
+        with _telemetry.span(
+                "engine.prefill",
+                metric=(_telemetry.SERVING_HANDOFF_SECONDS if handoff
+                        else _telemetry.SERVING_PREFILL_SECONDS),
+                request=req.request_id, bucket=bucket,
+                engine=self.engine_id) as sp:
+            # set(), not entry attributes: those label the histogram
+            sp.set(prompt_tokens=t0, hit_tokens=t_start)
+            if handoff:
+                # fleet handoff: the prefill lane computed ks/vs/logits
+                # on its own executable stream; commit is one page
+                # scatter
+                ks, vs, _, last = req._handoff
+                req._handoff = None
+                if self._device is not None:
+                    # cross-device fleet: the lane computed on the
+                    # default device; land the stacks on this replica's
+                    ks = jax.device_put(ks, self._device)
+                    vs = jax.device_put(vs, self._device)
+                page_row = np.zeros((bucket // ps,), np.int32)
+                n_real = min(len(rows), bucket // ps)
+                page_row[:n_real] = rows[:n_real]
+                extra = ((jnp.asarray(t0, jnp.int32),)
+                         if self.kv_dtype else ())
+                kvt = self._warm.run(
+                    ("adopt", bucket), self._adopt_fallback,
+                    self.pool.tree(), ks, vs, jnp.asarray(page_row),
+                    *extra)
+            elif t_start == 0:
+                prompt = np.zeros((1, bucket), np.int32)
+                prompt[0, :t0] = req.prompt
+                page_row = np.zeros((bucket // ps,), np.int32)
+                n_real = min(len(rows), bucket // ps)
+                page_row[:n_real] = rows[:n_real]
+                kvt, last = self._warm.run(
+                    ("prefill", bucket), self._prefill_fallback,
+                    self.params, self.pool.tree(), jnp.asarray(prompt),
+                    jnp.asarray(page_row), jnp.asarray(t0, jnp.int32))
+            else:
+                # warm path: prefill ONLY the uncached suffix, mid-page
+                # starts included — attention reads the shared prefix
+                # pages through the full table: one call of the paged
+                # kernel a layer, whose queries share one read of the
+                # t0 positions the slot then holds
+                self.n_attended_tokens += t0
+                sp.set(ctx_tokens=t0)
+                suffix = np.zeros((bucket,), np.int32)
+                suffix[:sl] = req.prompt[t_start:]
+                table = np.zeros((self.pages_per_slot,), np.int32)
+                table[:len(rows)] = rows
+                kvt, last = self._warm.run(
+                    ("prefix_prefill", bucket),
+                    self._prefix_prefill_fallback, self.params,
+                    self.pool.tree(), jnp.asarray(suffix),
+                    jnp.asarray(table), jnp.asarray(t_start, jnp.int32),
+                    jnp.asarray(t0, jnp.int32))
+            logits = np.asarray(last)
         self.pool.rebind(kvt)
-        if plan["kind"] == "handoff":
-            _telemetry.record_span(
-                "serving_handoff", t_pre, t_post,
-                metric=_telemetry.SERVING_HANDOFF_SECONDS,
-                bucket=bucket, engine=self.engine_id)
-        else:
-            _telemetry.record_span(
-                "serving_prefill", t_pre,
-                metric=_telemetry.SERVING_PREFILL_SECONDS,
-                bucket=bucket, engine=self.engine_id)
         first = self._sample_first(req, logits)
-        s = int(np.flatnonzero(~self._active)[0])
         req.cache_hit_tokens = t_start
         if req._trace is not None:
-            req._trace.event("queue_wait", req._t_submit, t_pre)
-            req._trace.event("prefill", t_pre, t_post, bucket=bucket,
-                             slot=s, hit_tokens=t_start,
-                             handoff=plan["kind"] == "handoff")
+            req._trace.event("queue_wait", req._t_submit, t_pre,
+                             span=wait_id)
+            req._trace.event("prefill", sp.t0, sp.t1, span=sp.id,
+                             bucket=bucket, slot=s, hit_tokens=t_start,
+                             handoff=handoff)
         _flight.record("serving_admit", request_id=req.request_id,
                        engine=self.engine_id,
                        slot=s, bucket=bucket, pages=len(rows),
@@ -1879,7 +1915,6 @@ class DecodeEngine:
         Returns False when NO active slot is spec-eligible this pass,
         and the caller falls through to the plain chunked burst — a
         fully opted-out roster never pays the wider program."""
-        t0 = time.perf_counter()
         active_idx = np.flatnonzero(self._active)
         K = self._spec.k
         S = self.slots
@@ -1903,91 +1938,104 @@ class DecodeEngine:
             n_draft[s] = prop.size
         if not n_draft.any():
             return False
-        tables, active, temps = self._dev_slot_state()
-        occupancy = float(len(active_idx)) / S
-        (kvt, out, adv, pos, tok, kd) = self._warm.run(
-            ("verify", K), self._verify_fallback, self._decode_params,
-            self.pool.tree(), tables, jnp.asarray(self._pos), active,
-            jnp.asarray(self._tok), jnp.asarray(drafts),
-            jnp.asarray(n_draft), jnp.asarray(self._keydata), temps)
-        self.pool.rebind(kvt)
-        self.n_dispatches += 1
-        self.n_verify_dispatches += 1
-        # ONE host sync for the whole burst (np.array copies: _admit
-        # writes joined slots' state into these buffers in place)
-        out = np.asarray(out)
-        nacc = np.array(adv)
-        self._pos = np.array(pos)
-        self._tok = np.array(tok)
-        self._keydata = np.array(kd)
-        self.n_steps += 1
-        self._occupancy_sum += occupancy
         lanes = int(len(active_idx))
-        self.n_verify_lane_steps += lanes
-        proposed = int(n_draft[active_idx].sum())
-        accepted = int((nacc[active_idx] - 1).sum())
-        self.n_spec_proposed += proposed
-        self.n_spec_accepted += accepted
-        _telemetry.record_span(
-            "serving_verify", t0,
-            metric=_telemetry.SERVING_VERIFY_SECONDS,
-            engine=self.engine_id)
-        _flight.record("serving_verify", engine=self.engine_id,
-                       k=K, lanes=lanes, proposed=proposed,
-                       accepted=accepted,
-                       occupancy=round(occupancy, 4))
-        if _tracing.enabled():
-            t_end = time.perf_counter()
-            for s in active_idx:
-                r = self._slot_req[int(s)]
-                if r is not None and r._trace is not None:
-                    r._trace.event("verify", t0, t_end, slot=int(s),
-                                   proposed=int(n_draft[s]),
-                                   accepted=int(nacc[s] - 1))
-        if _telemetry.enabled():
-            reg = _telemetry.MetricsRegistry.get_default()
-            reg.gauge(_telemetry.SERVING_SLOT_OCCUPANCY,
-                      "fraction of decode slots occupied by live "
-                      "requests this step").set(occupancy,
-                                                engine=self.engine_id)
-            reg.counter(_telemetry.SERVING_DECODE_STEPS,
-                        "fixed-shape decode steps executed").inc(
-                engine=self.engine_id)
-            if proposed:
-                reg.counter(
-                    _telemetry.SERVING_SPEC_PROPOSED,
-                    "draft tokens proposed to the verify "
-                    "program").inc(proposed, engine=self.engine_id)
-            if accepted:
-                reg.counter(
-                    _telemetry.SERVING_SPEC_ACCEPTED,
-                    "draft tokens the target model accepted").inc(
-                    accepted, engine=self.engine_id)
-            if self.n_spec_proposed:
-                reg.gauge(
-                    _telemetry.SERVING_SPEC_ACCEPTANCE,
-                    "cumulative accepted / proposed draft "
-                    "tokens").set(
-                    self.n_spec_accepted / self.n_spec_proposed,
+        occupancy = float(lanes) / S
+        # a lane scores its n_draft + 1 positions in the one dispatch:
+        # one call of the paged kernel a layer, whose queries share one
+        # read of the pos + n_draft + 1 positions the lane then holds
+        ctx = int((self._pos[active_idx].astype(np.int64)
+                   + n_draft[active_idx] + 1).sum())
+        self.n_attended_tokens += ctx
+        with _telemetry.span(
+                "engine.burst", metric=_telemetry.SERVING_VERIFY_SECONDS,
+                engine=self.engine_id) as burst:
+            tables, active, temps = self._dev_slot_state()
+            with _telemetry.span("engine.dispatch", k=1, live=lanes,
+                                 ctx_tokens=ctx, verify=K):
+                (kvt, out, adv, pos, tok, kd) = self._warm.run(
+                    ("verify", K), self._verify_fallback,
+                    self._decode_params, self.pool.tree(), tables,
+                    jnp.asarray(self._pos), active,
+                    jnp.asarray(self._tok), jnp.asarray(drafts),
+                    jnp.asarray(n_draft), jnp.asarray(self._keydata),
+                    temps)
+            self.pool.rebind(kvt)
+            self.n_dispatches += 1
+            self.n_verify_dispatches += 1
+            # ONE host sync for the whole burst (np.array copies: _admit
+            # writes joined slots' state into these buffers in place)
+            with _telemetry.span("engine.sync", steps=1,
+                                 dispatches=1) as sync:
+                out = np.asarray(out)
+                nacc = np.array(adv)
+                self._pos = np.array(pos)
+                self._tok = np.array(tok)
+                self._keydata = np.array(kd)
+            self.n_steps += 1
+            self._occupancy_sum += occupancy
+            self.n_verify_lane_steps += lanes
+            proposed = int(n_draft[active_idx].sum())
+            accepted = int((nacc[active_idx] - 1).sum())
+            self.n_spec_proposed += proposed
+            self.n_spec_accepted += accepted
+            _flight.record("serving_verify", engine=self.engine_id,
+                           k=K, lanes=lanes, proposed=proposed,
+                           accepted=accepted,
+                           occupancy=round(occupancy, 4))
+            if _tracing.enabled():
+                for s in active_idx:
+                    r = self._slot_req[int(s)]
+                    if r is not None and r._trace is not None:
+                        r._trace.event("verify", burst.t0, sync.t1,
+                                       span=burst.id, slot=int(s),
+                                       proposed=int(n_draft[s]),
+                                       accepted=int(nacc[s] - 1))
+            if _telemetry.enabled():
+                reg = _telemetry.MetricsRegistry.get_default()
+                reg.gauge(_telemetry.SERVING_SLOT_OCCUPANCY,
+                          "fraction of decode slots occupied by live "
+                          "requests this step").set(
+                    occupancy, engine=self.engine_id)
+                reg.counter(_telemetry.SERVING_DECODE_STEPS,
+                            "fixed-shape decode steps executed").inc(
                     engine=self.engine_id)
-            if self.n_verify_lane_steps:
-                reg.gauge(
-                    _telemetry.SERVING_TOKENS_PER_DISPATCH,
-                    "tokens emitted per weight read per decode lane "
-                    "(plain decode = 1.0)").set(
-                    (self.n_spec_accepted + self.n_verify_lane_steps)
-                    / self.n_verify_lane_steps,
-                    engine=self.engine_id)
-        emitted0 = self.n_tokens
-        for s in active_idx:
-            req = self._slot_req[int(s)]
-            if req is not None:
-                req.spec_proposed += int(n_draft[s])
-                req.spec_accepted += int(nacc[s] - 1)
-            for i in range(int(nacc[s])):
-                if not self._active[s]:
-                    break          # finished on eos mid-acceptance
-                self._emit(int(s), int(out[s, i]))
+                if proposed:
+                    reg.counter(
+                        _telemetry.SERVING_SPEC_PROPOSED,
+                        "draft tokens proposed to the verify "
+                        "program").inc(proposed, engine=self.engine_id)
+                if accepted:
+                    reg.counter(
+                        _telemetry.SERVING_SPEC_ACCEPTED,
+                        "draft tokens the target model accepted").inc(
+                        accepted, engine=self.engine_id)
+                if self.n_spec_proposed:
+                    reg.gauge(
+                        _telemetry.SERVING_SPEC_ACCEPTANCE,
+                        "cumulative accepted / proposed draft "
+                        "tokens").set(
+                        self.n_spec_accepted / self.n_spec_proposed,
+                        engine=self.engine_id)
+                if self.n_verify_lane_steps:
+                    reg.gauge(
+                        _telemetry.SERVING_TOKENS_PER_DISPATCH,
+                        "tokens emitted per weight read per decode lane "
+                        "(plain decode = 1.0)").set(
+                        (self.n_spec_accepted + self.n_verify_lane_steps)
+                        / self.n_verify_lane_steps,
+                        engine=self.engine_id)
+            emitted0 = self.n_tokens
+            with _telemetry.span("engine.emit") as emit:
+                for s in active_idx:
+                    req = self._slot_req[int(s)]
+                    if req is not None:
+                        req.spec_proposed += int(n_draft[s])
+                        req.spec_accepted += int(nacc[s] - 1)
+                    for i in range(int(nacc[s])):
+                        if not self._active[s]:
+                            break      # finished on eos mid-acceptance
+                        self._emit(int(s), int(out[s, i]))
+                emit.set(tokens=self.n_tokens - emitted0)
         self.last_progress = time.monotonic()
         if _telemetry.enabled() and self.n_tokens > emitted0:
             _telemetry.MetricsRegistry.get_default().counter(
@@ -2006,7 +2054,6 @@ class DecodeEngine:
         slot."""
         if self._spec is not None and self._spec_burst():
             return
-        t0 = time.perf_counter()
         active_idx = np.flatnonzero(self._active)
         min_rem = min(
             self._slot_req[s].max_new_tokens - int(self._slot_emitted[s])
@@ -2014,68 +2061,83 @@ class DecodeEngine:
         has_eos = any(self._slot_req[s].eos_id is not None
                       for s in active_idx)
         free_slots = not self._active.all()
-        tables, active, temps = self._dev_slot_state()
-        pos = jnp.asarray(self._pos)
-        tok = jnp.asarray(self._tok)
-        kd = jnp.asarray(self._keydata)
-        occupancy = float(len(active_idx)) / self.slots
-        chunks: List[Any] = []
-        steps = 0
-        while True:
-            k = 1
-            while k * 2 <= min(min_rem - steps, self.max_chunk):
-                k *= 2
-            (kvt, toks, pos, tok, kd) = self._warm.run(
-                ("decode", k), self._decode_fallbacks[k],
-                self._decode_params, self.pool.tree(), tables,
-                pos, active, tok, kd, temps)
-            self.pool.rebind(kvt)
-            chunks.append(toks)
-            steps += k
-            self.n_dispatches += 1
-            if has_eos or steps >= min_rem \
-                    or len(chunks) >= self.MAX_BURST_DISPATCHES:
-                break
-            if free_slots and not self._queue.empty():
-                break          # a waiting request can join a free slot
-        # ONE host sync for the whole burst
-        toks = np.concatenate([np.asarray(c) for c in chunks], axis=1)
-        # np.array (copy): device views are read-only, and _admit
-        # writes newly-joined slots' state into these buffers in place
-        self._pos = np.array(pos)
-        self._tok = np.array(tok)
-        self._keydata = np.array(kd)
-        self.n_steps += steps
-        self._occupancy_sum += occupancy * steps
-        _telemetry.record_span(
-            "serving_decode_step", t0,
-            metric=_telemetry.SERVING_DECODE_STEP_SECONDS,
-            engine=self.engine_id)
-        _flight.record("serving_burst", engine=self.engine_id,
-                       steps=steps, dispatches=len(chunks),
-                       occupancy=round(occupancy, 4))
-        if _tracing.enabled():
-            t_burst_end = time.perf_counter()
-            for s in active_idx:
-                r = self._slot_req[int(s)]
-                if r is not None and r._trace is not None:
-                    r._trace.event("decode_burst", t0, t_burst_end,
-                                   tokens=steps, slot=int(s))
-        if _telemetry.enabled():
-            reg = _telemetry.MetricsRegistry.get_default()
-            reg.gauge(_telemetry.SERVING_SLOT_OCCUPANCY,
-                      "fraction of decode slots occupied by live "
-                      "requests this step").set(occupancy,
-                                                engine=self.engine_id)
-            reg.counter(_telemetry.SERVING_DECODE_STEPS,
-                        "fixed-shape decode steps executed").inc(
-                steps, engine=self.engine_id)
-        emitted0 = self.n_tokens
-        for s in active_idx:
-            for k in range(steps):
-                if not self._active[s]:
-                    break              # finished on eos mid-chunk
-                self._emit(int(s), int(toks[s, k]))
+        live = int(len(active_idx))
+        occupancy = float(live) / self.slots
+        pos_sum = int(self._pos[active_idx].sum())
+        with _telemetry.span(
+                "engine.burst",
+                metric=_telemetry.SERVING_DECODE_STEP_SECONDS,
+                engine=self.engine_id) as burst:
+            tables, active, temps = self._dev_slot_state()
+            pos = jnp.asarray(self._pos)
+            tok = jnp.asarray(self._tok)
+            kd = jnp.asarray(self._keydata)
+            chunks: List[Any] = []
+            steps = 0
+            while True:
+                k = 1
+                while k * 2 <= min(min_rem - steps, self.max_chunk):
+                    k *= 2
+                # step j of the chunk attends the pos + steps + j + 1
+                # positions each live slot holds by then
+                ctx = k * (pos_sum + live * steps) \
+                    + live * (k * (k + 1) // 2)
+                self.n_attended_tokens += ctx
+                with _telemetry.span("engine.dispatch", k=k, live=live,
+                                     ctx_tokens=ctx):
+                    (kvt, toks, pos, tok, kd) = self._warm.run(
+                        ("decode", k), self._decode_fallbacks[k],
+                        self._decode_params, self.pool.tree(), tables,
+                        pos, active, tok, kd, temps)
+                self.pool.rebind(kvt)
+                chunks.append(toks)
+                steps += k
+                self.n_dispatches += 1
+                if has_eos or steps >= min_rem \
+                        or len(chunks) >= self.MAX_BURST_DISPATCHES:
+                    break
+                if free_slots and not self._queue.empty():
+                    break      # a waiting request can join a free slot
+            # ONE host sync for the whole burst
+            with _telemetry.span("engine.sync", steps=steps,
+                                 dispatches=len(chunks)) as sync:
+                toks = np.concatenate([np.asarray(c) for c in chunks],
+                                      axis=1)
+                # np.array (copy): device views are read-only, and
+                # _admit writes newly-joined slots' state into these
+                # buffers in place
+                self._pos = np.array(pos)
+                self._tok = np.array(tok)
+                self._keydata = np.array(kd)
+            self.n_steps += steps
+            self._occupancy_sum += occupancy * steps
+            _flight.record("serving_burst", engine=self.engine_id,
+                           steps=steps, dispatches=len(chunks),
+                           occupancy=round(occupancy, 4))
+            if _tracing.enabled():
+                for s in active_idx:
+                    r = self._slot_req[int(s)]
+                    if r is not None and r._trace is not None:
+                        r._trace.event("decode_burst", burst.t0, sync.t1,
+                                       span=burst.id, tokens=steps,
+                                       slot=int(s))
+            if _telemetry.enabled():
+                reg = _telemetry.MetricsRegistry.get_default()
+                reg.gauge(_telemetry.SERVING_SLOT_OCCUPANCY,
+                          "fraction of decode slots occupied by live "
+                          "requests this step").set(
+                    occupancy, engine=self.engine_id)
+                reg.counter(_telemetry.SERVING_DECODE_STEPS,
+                            "fixed-shape decode steps executed").inc(
+                    steps, engine=self.engine_id)
+            emitted0 = self.n_tokens
+            with _telemetry.span("engine.emit") as emit:
+                for s in active_idx:
+                    for k in range(steps):
+                        if not self._active[s]:
+                            break          # finished on eos mid-chunk
+                        self._emit(int(s), int(toks[s, k]))
+                emit.set(tokens=self.n_tokens - emitted0)
         self.last_progress = time.monotonic()
         if _telemetry.enabled() and self.n_tokens > emitted0:
             _telemetry.MetricsRegistry.get_default().counter(

@@ -122,6 +122,7 @@ class FleetRequest:
         self._engine: Optional[DecodeEngine] = None
         self._replica_index: Optional[int] = None
         self._lane_result = None     # cached (handoff, lane_span)
+        self._route_span = None      # id of the fleet.route that placed it
         self._no_lane = False        # lane failed once: go direct
         self._cancelled = False      # cancel(): never reroute/re-run
         self._skip = 0               # replayed tokens to suppress
@@ -140,6 +141,7 @@ class FleetRequest:
         with self._lock:
             self._inner = inner
             self._engine = engine
+            inner.caused_by = self._route_span
             self._seen = 0
             self._skip = len(self.tokens)
             self.engine_id = engine.engine_id
@@ -1119,23 +1121,31 @@ class ServingFleet:
         eng = target.engine
         freq.attempts += 1
         freq._replica_index = target.rid
-        t_s0 = time.perf_counter()
         try:
-            if handoff is not None:
-                ho, lane_span = handoff
-                inner = eng.submit_prepared(
-                    freq.prompt, freq.max_new_tokens,
-                    freq.temperature, freq.eos_id, freq.sample_seed,
-                    session_id=freq.session_id,
-                    spec_decode=freq.spec_decode, handoff=ho,
-                    lane_span=lane_span, _sink=freq)
-                freq._lane_result = None
-            else:
-                inner = eng.submit(
-                    freq.prompt, freq.max_new_tokens,
-                    freq.temperature, freq.eos_id, freq.sample_seed,
-                    session_id=freq.session_id,
-                    spec_decode=freq.spec_decode, _sink=freq)
+            # the replica's engine.admit names this span as its parent:
+            # the cause runs here, on the router's (or the lane's) thread
+            with _telemetry.span(
+                    "fleet.route", replica=eng.engine_id,
+                    reason=freq.routing.get("reason"),
+                    lane=freq.routing.get("lane", False),
+                    attempts=freq.attempts) as sp:
+                freq._route_span = sp.id
+                if handoff is not None:
+                    ho, lane_span = handoff
+                    inner = eng.submit_prepared(
+                        freq.prompt, freq.max_new_tokens,
+                        freq.temperature, freq.eos_id, freq.sample_seed,
+                        session_id=freq.session_id,
+                        spec_decode=freq.spec_decode, handoff=ho,
+                        lane_span=lane_span, _sink=freq)
+                    freq._lane_result = None
+                else:
+                    inner = eng.submit(
+                        freq.prompt, freq.max_new_tokens,
+                        freq.temperature, freq.eos_id, freq.sample_seed,
+                        session_id=freq.session_id,
+                        spec_decode=freq.spec_decode, _sink=freq)
+                sp.set(request=inner.request_id)
         except CapacityRejected:
             # replica queue full (rare: fleet sizes replica queues
             # generously) — try again through the router
@@ -1147,7 +1157,7 @@ class ServingFleet:
             self._requeue(freq, "dead_on_submit")
             return
         if inner._trace is not None:
-            inner._trace.event("route", t_s0,
+            inner._trace.event("route", sp.t0, sp.t1, span=sp.id,
                                replica=eng.engine_id,
                                reason=freq.routing.get("reason"),
                                lane=freq.routing.get("lane", False),

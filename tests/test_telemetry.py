@@ -256,7 +256,10 @@ class TestSpans:
                 time.sleep(0.001)
         evs = telemetry.chrome_trace()["traceEvents"]
         names = {e["name"]: e for e in evs}
-        assert names["inner"]["args"]["parent"] == "outer"
+        # the parent is named by its id, which every record carries
+        assert names["inner"]["args"]["parent"] == \
+            names["outer"]["args"]["id"]
+        assert "parent" not in names["outer"]["args"]
         assert names["inner"]["args"]["depth"] == 1
         assert names["outer"]["args"]["depth"] == 0
         # inner completes first, nests inside outer's interval
