@@ -48,6 +48,30 @@ garbage, exactly like their page contents). Scale planes initialize
 to ONES so the zero-filled pools round-trip exactly and no division
 ever sees zero.
 
+Layout contract (PR 27): a write into the pools keeps them in the
+paged kernel's layout. The Mosaic kernel
+(ops/paged_attention_pallas.py) takes the pools row-major,
+``{4,3,2,1,0}``. A scatter whose update window is ``[H, hd]`` with the
+indexed ``page_size`` dimension BETWEEN the two — what
+``pool.at[layer, page, :, offset].set(x)`` spells — is given the
+layout ``{4,2,3,1,0}`` by XLA's layout assignment, and with it the
+whole decode loop's carry; XLA then copies each whole pool, every
+layer of every step, to hand the kernel its operand (72 copies of 379
+MB a step at GPT-2-large, 72.5% of device time). So a per-position
+write keeps its update window to the trailing ``hd`` (every other
+index spelled out: ``_write_rows``, the one such site) or is a
+``lax.dynamic_update_slice``; whole-page writes (``commit_prefill``,
+``copy_page``: window ``[H, ps, hd]``, nothing indexed inside it) are
+row-major as they stand. ``tests/test_kv_layout_aot.py`` compiles the
+serving programs for a described v5e and fails on any pool-shaped
+``copy``; it needs no chip. What is left is at the programs' boundary,
+not in any write: at rest a v5e gives this shape (``hd`` 64, half a
+lane tile) the layout ``{1,4,3,2,0}``, so each program re-lays both
+pools out once at entry and once at exit. Pinning them row-major
+there (``jax.experimental.layout``) compiles copy-free, but an
+executable loaded back from the persistent compilation cache comes
+without its pinned layouts (jax 0.9.0; PERF.md section 6, PR 27).
+
 The jax functions here are pure and shape-static, so the engine's one
 decode executable serves every mix of request lengths.
 """
@@ -320,6 +344,20 @@ def commit_prefill(kv, ks, vs, page_row, page_size: int, n_valid=None):
     return out
 
 
+def _write_rows(pool, layer: int, page_idx, offset, x):
+    """``pool[layer, page_idx[i], :, offset[i]] = x[i]`` for every lane
+    ``i`` of ``page_idx`` / ``offset`` (``[S]``, or ``[S, W]`` for a
+    verify dispatch; ``x`` is ``[..., H, hd]``), cast to the pool's
+    dtype. THE per-position write into a pool: the head index is
+    spelled out beside the page and the offset, so the scatter's update
+    window is the trailing ``hd`` alone and the pool keeps the
+    row-major layout the paged kernel reads (module docstring, "Layout
+    contract")."""
+    heads = jnp.arange(pool.shape[2], dtype=page_idx.dtype)
+    return pool.at[layer, page_idx[..., None], heads,
+                   offset[..., None]].set(x.astype(pool.dtype))
+
+
 def append_token(kv, layer: int, page_idx, offset, k, v):
     """Write one DECODE position's K/V per lane: lane ``s`` lands at
     ``(layer, page_idx[s], :, offset[s])``. Inactive slots' page_idx
@@ -335,10 +373,8 @@ def append_token(kv, layer: int, page_idx, offset, k, v):
     """
     out = dict(kv)
     if not _is_fp8(kv):
-        out["k"] = kv["k"].at[layer, page_idx, :, offset].set(
-            k.astype(kv["k"].dtype))
-        out["v"] = kv["v"].at[layer, page_idx, :, offset].set(
-            v.astype(kv["v"].dtype))
+        out["k"] = _write_rows(kv["k"], layer, page_idx, offset, k)
+        out["v"] = _write_rows(kv["v"], layer, page_idx, offset, v)
         return out
     fresh = (offset == 0)[:, None]
 
@@ -347,7 +383,7 @@ def append_token(kv, layer: int, page_idx, offset, k, v):
         cand = _precision.fp8_scale(jnp.max(jnp.abs(xf), axis=-1))
         sc = jnp.where(fresh, cand, scales[layer, page_idx])  # [S, H]
         q = _precision.quantize_fp8(xf, sc[..., None])
-        return (pool.at[layer, page_idx, :, offset].set(q),
+        return (_write_rows(pool, layer, page_idx, offset, q),
                 scales.at[layer, page_idx].set(sc))
 
     out["k"], out["k_scale"] = one(kv["k"], kv["k_scale"], k)
@@ -391,7 +427,7 @@ def append_suffix(kv, layer: int, page_idx, offset, k, v, *,
         sc = jnp.where(real[:, None],
                        sc_pg[jnp.minimum(chunk, P - 1)], 1.0)
         q = _precision.quantize_fp8(xf, sc[..., None])
-        return (pool.at[layer, page_idx, :, offset].set(q),
+        return (_write_rows(pool, layer, page_idx, offset, q),
                 scales.at[layer, table].set(sc_pg))
 
     out["k"], out["k_scale"] = one(kv["k"], kv["k_scale"], k)
@@ -430,12 +466,7 @@ def append_spec(kv, layer: int, page_idx, offset, k, v, *,
     for every slot (writers own their pages at refcount 1), so their
     scale rows always re-write the stored value."""
     if not _is_fp8(kv):
-        out = dict(kv)
-        out["k"] = kv["k"].at[layer, page_idx, :, offset].set(
-            k.astype(kv["k"].dtype))
-        out["v"] = kv["v"].at[layer, page_idx, :, offset].set(
-            v.astype(kv["v"].dtype))
-        return out
+        return append_token(kv, layer, page_idx, offset, k, v)
     S, W = real.shape
     P = tables.shape[1]
     # per-(slot, page) segments: slot s's table row c -> s*(P+1) + c,
@@ -462,7 +493,7 @@ def append_spec(kv, layer: int, page_idx, offset, k, v, *,
             sc_pg, jnp.minimum(chunk, P - 1)[..., None], axis=1)
         sc = jnp.where(real[..., None], sc, 1.0)           # [S, W, H]
         q = _precision.quantize_fp8(xf, sc[..., None])
-        return (pool.at[layer, page_idx, :, offset].set(q),
+        return (_write_rows(pool, layer, page_idx, offset, q),
                 scales.at[layer, tables].set(sc_pg))
 
     out["k"], out["k_scale"] = one(kv["k"], kv["k_scale"], k)

@@ -382,6 +382,76 @@ class TestFp8Pages:
             PagePool(1, 2, 4, 4, n_pages=3, kv_dtype="int4")
 
 
+# ------------------------------------------- per-position write sites
+class TestPositionWrites:
+    """kv_pages._write_rows behind its three callers: the same values
+    in the same cells as a plain loop, whatever spells the scatter."""
+
+    L, NP, H, PS, HD, P = 3, 20, 3, 4, 8, 3
+    LAYER = 1
+
+    def _lanes(self, rng, writer):
+        """Random DISTINCT live (page, offset) lanes plus lanes parked
+        on the null page 0, in the index shapes ``writer`` takes, with
+        the fp8 segment arguments consistent with them. Every writer
+        is ``rows`` page tables of ``lanes`` positions each: a decode
+        step is one position a slot, a suffix one slot's positions."""
+        P, ps = self.P, self.PS
+        rows, lanes = {"append_token": (6, 1), "append_suffix": (1, 6),
+                       "append_spec": (4, 3)}[writer]
+        tables = rng.permutation(np.arange(1, self.NP))[:rows * P] \
+            .reshape(rows, P)
+        cells = np.stack([rng.permutation(P * ps)[:lanes]
+                          for _ in range(rows)])        # distinct a row
+        chunk, off = cells // ps, cells % ps
+        real = rng.random((rows, lanes)) < 0.6
+        real.flat[:2], real.flat[-2:] = True, False
+        page = np.where(real, np.take_along_axis(tables, chunk, 1), 0)
+        seg = np.where(real, chunk, P)
+        idx = lambda a: jnp.asarray(a, jnp.int32)
+        if writer == "append_token":
+            return idx(page[:, 0]), idx(off[:, 0]), real[:, 0], {}
+        if writer == "append_suffix":
+            return (idx(page[0]), idx(off[0]), real[0],
+                    dict(chunk=idx(seg[0]), real=jnp.asarray(real[0]),
+                         table=idx(tables[0])))
+        return (idx(page), idx(off), real,
+                dict(chunk=idx(seg), real=jnp.asarray(real),
+                     tables=idx(tables)))
+
+    @pytest.mark.parametrize("fp8", [False, True], ids=["bf16", "fp8"])
+    @pytest.mark.parametrize(
+        "writer", ["append_token", "append_suffix", "append_spec"])
+    def test_matches_numpy_loop(self, writer, fp8):
+        rng = np.random.default_rng(7)
+        kv = _mk_kv(rng, self.L, self.NP, self.H, self.PS, self.HD,
+                    fp8=fp8)
+        if not fp8:
+            kv = {n: a.astype(jnp.bfloat16) for n, a in kv.items()}
+        page, off, real, extra = self._lanes(rng, writer)
+        x = {n: rng.standard_normal(page.shape + (self.H, self.HD))
+             .astype(np.float32) * 3 for n in ("k", "v")}
+        out = getattr(kv_pages, writer)(
+            kv, self.LAYER, page, off, jnp.asarray(x["k"]),
+            jnp.asarray(x["v"]), **extra)
+        for n in ("k", "v"):
+            want = np.asarray(kv[n].astype(jnp.float32)).copy()
+            for lane in np.ndindex(page.shape):
+                pg, o = int(page[lane]), int(off[lane])
+                xs = jnp.asarray(x[n][lane])
+                if fp8:   # under the scale the page ends up with
+                    sc = out[n + "_scale"][self.LAYER, pg]
+                    xs = precision.quantize_fp8(xs, sc[:, None])
+                want[self.LAYER, pg, :, o] = np.asarray(
+                    xs.astype(kv[n].dtype).astype(jnp.float32))
+            got = np.asarray(out[n].astype(jnp.float32))
+            assert out[n].dtype == kv[n].dtype
+            # page 0 takes the parked lanes' writes: unordered, unread
+            np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+            assert not np.array_equal(got[:, 1:], np.asarray(
+                kv[n].astype(jnp.float32))[:, 1:])
+
+
 # ------------------------------------------------- engine token identity
 VOCAB = 13
 
