@@ -30,6 +30,14 @@ sequence ``n`` sits at absolute position ``qbase[n] + i`` — Q=1 with
 per-slot positions for the decode step, N=1 with consecutive suffix
 positions for the prefix-prefill program.
 
+Grouped-query attention: the pools hold ``Hkv`` heads and the queries
+``H = Hkv * G``; query head ``h`` reads KV head ``h // G``. The ``G``
+query heads of a group ride the kernel's QUERY axis (``[N, Hkv, Q * G,
+hd]``, row ``i`` = query ``i // G`` of head ``i % G`` of the group), so
+a page is read once a group and the grid is ``(N, Hkv, P)``; row ``i``
+is masked at position ``qbase + i // G``. ``G = 1`` is the kernel as it
+was, instruction for instruction.
+
 fp8 KV (``kv_dtype="fp8_e4m3"``): the pools store float8_e4m3fn with
 per-page-per-head fp32 scale planes ``[L, n_pages, H]`` beside them;
 the kernel dequantizes each page block in VMEM (one scalar multiply
@@ -82,7 +90,7 @@ def paged_attention_mode() -> str:
 
 
 # ------------------------------------------------------- xla reference
-def _xla_paged_attention(q, kv, layer, tables, qbase):
+def _xla_paged_attention(q, kv, layer, tables, qbase, group=1):
     """The exact einsum pair from the pre-kernel decode core /
     prefix-prefill program (serving/engine.py PR 8-9 lineage). This is
     the dispatch target when the kernel is off, so it must stay
@@ -99,7 +107,10 @@ def _xla_paged_attention(q, kv, layer, tables, qbase):
             ..., None, None].astype(cd)
     P, ps = ck.shape[1], ck.shape[3]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, q.dtype))
-    qpos = qbase[:, None] + jnp.arange(Q, dtype=jnp.int32)[None, :]
+    qi = jnp.arange(Q, dtype=jnp.int32)
+    if group > 1:
+        qi = qi // group
+    qpos = qbase[:, None] + qi[None, :]
     # page-major contraction: (p, o) together are the flat key axis
     logits = jnp.einsum("nhqd,nphod->nhqpo", q, ck) \
         .reshape(N, H, Q, P * ps) * scale
@@ -113,7 +124,7 @@ def _xla_paged_attention(q, kv, layer, tables, qbase):
 
 # -------------------------------------------------------------- kernel
 def _kernel(tables_ref, qbase_ref, q_ref, k_ref, v_ref, *rest,
-            layer, page_size, sm_scale, fp8):
+            layer, page_size, sm_scale, fp8, group):
     """One (sequence n, head h, page p) grid step of the online-softmax
     walk. Scratch (m, l, acc) persists across the sequential innermost
     page dimension; initialized at p == 0, finalized into the output
@@ -146,6 +157,8 @@ def _kernel(tables_ref, qbase_ref, q_ref, k_ref, v_ref, *rest,
     # query i iff it is <= qbase[n] + i (2-D iotas per the TPU rule)
     qi = lax.broadcasted_iota(jnp.int32, s.shape, 0)
     oi = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if group > 1:
+        qi = qi // group        # row i is query i // G of its head
     valid = (p * page_size + oi) <= (qbase_ref[n] + qi)
     s = jnp.where(valid, s, _MASK_MIN)
     m_prev = m_ref[...]                          # [Q, 1]
@@ -169,7 +182,8 @@ def _kernel(tables_ref, qbase_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret):
+def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
+                            group=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -203,7 +217,8 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret):
                  kv["v_scale"][..., None, None]]
     kernel = functools.partial(
         _kernel, layer=layer, page_size=ps,
-        sm_scale=float(1.0 / np.sqrt(np.float32(hd))), fp8=fp8)
+        sm_scale=float(1.0 / np.sqrt(np.float32(hd))), fp8=fp8,
+        group=group)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -229,8 +244,9 @@ def paged_attention(q, kv, layer, tables, qbase, *, mode=None):
     ----------
     q : ``[N, H, Q, hd]`` queries in the compute dtype.
     kv : the page-pool tree (``kv_pages.PagePool.tree()``): ``"k"`` /
-        ``"v"`` pools ``[L, n_pages, H, ps, hd]``, plus ``"k_scale"`` /
-        ``"v_scale"`` planes ``[L, n_pages, H]`` when the pool is fp8.
+        ``"v"`` pools ``[L, n_pages, Hkv, ps, hd]``, plus ``"k_scale"``
+        / ``"v_scale"`` planes ``[L, n_pages, Hkv]`` when the pool is
+        fp8. ``H`` is a multiple of ``Hkv`` (module docstring).
     layer : static layer index (the engine's layer loop is unrolled).
     tables : ``[N, P]`` int32 page tables, rows in position order.
     qbase : ``[N]`` int32; query ``i`` of row ``n`` sits at absolute
@@ -244,10 +260,26 @@ def paged_attention(q, kv, layer, tables, qbase, *, mode=None):
         raise ValueError(
             f"unknown paged-attention mode {mode!r} (expected 'pallas',"
             " 'interpret' or 'xla')")
+    N, H, Q, hd = q.shape
+    Hkv = kv["k"].shape[2]
+    G, rem = divmod(H, Hkv)
+    if rem:
+        raise ValueError(f"{H} query heads do not divide into the "
+                         f"pool's {Hkv} KV heads")
+    if G > 1:
+        # the group's heads onto the query axis, query-major
+        q = q.reshape(N, Hkv, G, Q, hd).transpose(0, 1, 3, 2, 4) \
+             .reshape(N, Hkv, Q * G, hd)
     if mode == "xla":
-        return _xla_paged_attention(q, kv, layer, tables, qbase)
-    return _pallas_paged_attention(q, kv, layer, tables, qbase,
-                                   interpret=(mode == "interpret"))
+        out = _xla_paged_attention(q, kv, layer, tables, qbase, G)
+    else:
+        out = _pallas_paged_attention(q, kv, layer, tables, qbase,
+                                      interpret=(mode == "interpret"),
+                                      group=G)
+    if G > 1:
+        out = out.reshape(N, Hkv, Q, G, hd).transpose(0, 1, 3, 2, 4) \
+                 .reshape(N, H, Q, hd)
+    return out
 
 
 @register_op("paged_attention")

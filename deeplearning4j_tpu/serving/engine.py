@@ -1,4 +1,8 @@
-"""Continuous-batching decode engine over ``models/gpt.py`` CausalLM.
+"""Continuous-batching decode engine over ``models/gpt.py`` CausalLM
+(whose block the programs below spell out) or over a model that brings
+its own block and says what it caches (``models/lfm2_moe.py``:
+``cache_spec()``, ``prefill``, ``decode_step``; "A model's own block"
+in the class docstring).
 
 The problem with ``generate()`` as a serving path: it compiles one
 program per ``(batch, prompt, new_tokens)`` shape, runs the whole batch
@@ -409,6 +413,21 @@ class DecodeEngine:
         amortize over up to ``max_chunk`` tokens. 1 disables chunking.
     warm_start : AOT-compile the decode + prefill executables in
         ``start()`` so no request ever pays a trace.
+
+    A model's own block. A model with ``cache_spec()`` / ``prefill`` /
+    ``decode_step`` (``models/lfm2_moe.py``) is served by programs
+    built from those: the page pool is sized from what it says it
+    caches (pool layers = its attention layers, heads = its KV heads),
+    and where it names a per-slot ``state`` (conv windows), that array
+    ``[state layers, slots, ...]`` lives beside the pool: written by
+    prefill at admission (a reused slot never sees its predecessor's),
+    carried through the chunk's scan and donated across dispatches
+    like the pool. Scheduler, admission, bursts and hand-over are the
+    same. Options that key on pages alone cannot be honoured for a
+    model with such state (a page hit would need a state snapshot) and
+    are refused by name: ``prefix_cache``, ``session_capacity``,
+    ``spec_decode``, ``quantization``, ``kv_dtype``,
+    ``handoff_threshold``.
     """
 
     def __init__(self, model, params, *, slots: int = 8,
@@ -431,6 +450,27 @@ class DecodeEngine:
                  spec_decode=None):
         cfg = model.cfg
         self.model = model
+        #: what the model caches: a model that brings its own block
+        #: says so; GPT-2 (the block inlined below) has pages in every
+        #: layer, as many KV heads as query heads and no other state
+        self._own_block = hasattr(model, "cache_spec")
+        spec = (model.cache_spec() if self._own_block else
+                {"kv_layers": cfg.n_layers, "kv_heads": cfg.n_heads,
+                 "head_dim": cfg.head_dim, "state": None})
+        if spec["state"] is not None:
+            refused = {"prefix_cache": prefix_cache,
+                       "session_capacity": session_capacity > 0,
+                       "spec_decode": spec_decode is not None,
+                       "quantization": quantization is not None,
+                       "kv_dtype": kv_dtype is not None,
+                       "handoff_threshold": handoff_threshold is not None}
+            for name, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{name} cannot be honoured for "
+                        f"{type(model).__name__}: the model keeps a "
+                        "per-slot recurrent state beside its pages, and "
+                        "this option keys on pages alone")
         #: metric/trace label for this engine (``engine=<id>`` on every
         #: SERVING_* series); auto-minted process-wide when not given
         self.engine_id = (str(engine_id) if engine_id is not None
@@ -480,9 +520,17 @@ class DecodeEngine:
                 f"unknown attn_mode {attn_mode!r} (expected None, "
                 "'pallas', 'interpret' or 'xla')")
         self.pool = kv_pages.PagePool(
-            cfg.n_layers, cfg.n_heads, self.page_size, cfg.head_dim,
-            n_pages, dtype=model._cdtype, engine_id=self.engine_id,
-            device=device, kv_dtype=self.kv_dtype)
+            spec["kv_layers"], spec["kv_heads"], self.page_size,
+            spec["head_dim"], n_pages, dtype=model._cdtype,
+            engine_id=self.engine_id, device=device,
+            kv_dtype=self.kv_dtype)
+        #: the per-slot state beside the pool ``[state layers, slots,
+        #: ...]`` in the compute dtype, or None (GPT-2)
+        self._state = None
+        if spec["state"] is not None:
+            layers, *rest = spec["state"]
+            self._state = jnp.zeros((layers, self.slots, *rest),
+                                    model._cdtype, device=device)
         self.prefill_buckets = self._resolve_buckets(prefill_buckets)
         # sampling-key width follows the process PRNG impl (threefry=2,
         # rbg=4) so keydata shapes match whatever jax.config says
@@ -520,20 +568,23 @@ class DecodeEngine:
         while k <= self.max_chunk:
             self._chunks.append(k)
             k *= 2
-        core = self._build_step_core()
+        core = (self._build_model_step_core() if self._own_block
+                else self._build_step_core())
         # donate the KV tree (pools + any scale planes): the engine
         # rebinds it from every call's outputs, and without donation
         # XLA must copy the whole cache at every dispatch boundary
         # (the scan inside a chunk already aliases; donation extends
         # that across dispatches)
         self._decode_jits = {
-            k: jax.jit(self._make_chunk(core, k), donate_argnums=(1,))
+            k: jax.jit(self._make_chunk(core, k, self._own_block),
+                       donate_argnums=(1,))
             for k in self._chunks}
         self._decode_fallbacks = {
             k: _telemetry.instrument_jit("serving_decode", fn)
             for k, fn in self._decode_jits.items()}
-        self._prefill_jit = jax.jit(self._build_prefill_fn(),
-                                    donate_argnums=(1,))
+        self._prefill_jit = jax.jit(
+            self._build_model_prefill_fn() if self._own_block
+            else self._build_prefill_fn(), donate_argnums=(1,))
         self._prefill_fallback = _telemetry.instrument_jit(
             "serving_prefill", self._prefill_jit)
         # fleet replica mode: the adopt scatter that commits a prefill
@@ -638,6 +689,13 @@ class DecodeEngine:
         self.n_prefill_tokens = 0
         self.n_prefill_bucket_tokens = 0
         self.n_tokens = 0
+        #: what the expert layers did, summed over decode steps and
+        #: prefills from the small arrays the programs return (live
+        #: lanes and real prompt positions only): assignments, distinct
+        #: experts touched, layer-steps, and the hottest expert's load
+        self._expert_totals = dict.fromkeys(
+            ("expert_assignments", "experts_touched",
+             "expert_layer_steps", "expert_load_max"), 0)
         self._occupancy_sum = 0.0
         # newest finished requests (id + finish reason + timings), so
         # client logs can join against server traces via stats()
@@ -745,38 +803,74 @@ class DecodeEngine:
             x = ln(x, params["ln_f"])
             logits = self._head(x, params["tok_emb"], cd) \
                 .astype(jnp.float32)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            keys = jax.random.wrap_key_data(keydata)
-            nk = jax.vmap(jax.random.split)(keys)      # [S, 2] keys
-            safe_t = jnp.where(temps > 0, temps, 1.0)
-            sampled = jax.vmap(jax.random.categorical)(
-                nk[:, 1], logits / safe_t[:, None]).astype(jnp.int32)
-            nxt = jnp.where(temps > 0, sampled, greedy)
-            return kv, nxt, jax.random.key_data(nk[:, 0])
+            nxt, nkd = self._sample_next(logits, keydata, temps)
+            return kv, nxt, nkd
 
         return step
 
     @staticmethod
-    def _make_chunk(core, n_steps: int):
+    def _sample_next(logits, keydata, temps):
+        """Every slot's next token from its float32 logits (greedy at
+        temperature 0, else a draw from its own key) and the keys
+        advanced: the tail every decode core shares."""
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        keys = jax.random.wrap_key_data(keydata)
+        nk = jax.vmap(jax.random.split)(keys)      # [S, 2] keys
+        safe_t = jnp.where(temps > 0, temps, 1.0)
+        sampled = jax.vmap(jax.random.categorical)(
+            nk[:, 1], logits / safe_t[:, None]).astype(jnp.int32)
+        nxt = jnp.where(temps > 0, sampled, greedy)
+        return nxt, jax.random.key_data(nk[:, 0])
+
+    def _build_model_step_core(self):
+        """The decode step of a model that brings its own block: the
+        model's ``decode_step`` over the cache ``(kv tree, per-slot
+        state)``, then the shared sampling tail. Beside the tokens it
+        returns the model's small per-step counts (the expert layers'
+        assignments, distinct experts, hottest load, of live lanes)."""
+        m, ps, attn = self.model, self.page_size, self._attn_mode
+
+        def step(params, cache, tables, pos, tok, keydata, temps, active):
+            kv, state = cache
+            kv, state, logits, counts = m.decode_step(
+                params, kv, state, tables, pos, tok, active, ps, mode=attn)
+            nxt, nkd = self._sample_next(logits, keydata, temps)
+            return (kv, state), nxt, nkd, counts
+
+        return step
+
+    @staticmethod
+    def _make_chunk(core, n_steps: int, own_block: bool = False):
         """``n_steps`` decode steps fused into one lax.scan program.
         The scheduler guarantees no active request completes mid-chunk
         (chunk <= min remaining), so the slot roster (tables / active /
         temps) is loop-invariant and only the per-token state (pos /
-        tok / keys / pools) carries. A chunk of 1 is the plain step."""
+        tok / keys / pools) carries. A chunk of 1 is the plain step.
+        ``kv`` is whatever the core carries as its cache: the pool's
+        tree, or (``own_block``) the pair of it and the per-slot state;
+        such a core is also told which lanes are live and returns its
+        per-step counts, which come back stacked ``[n_steps, ...]``."""
 
         def chunk(params, kv, tables, pos, active, tok,
                   keydata, temps):
             def body(carry, _):
                 kv, pos, tok, kd = carry
-                kv, nxt, nkd = core(
-                    params, kv, tables, pos, tok, kd, temps)
+                if own_block:
+                    kv, nxt, nkd, counts = core(
+                        params, kv, tables, pos, tok, kd, temps, active)
+                else:
+                    kv, nxt, nkd = core(
+                        params, kv, tables, pos, tok, kd, temps)
+                    counts = None
                 pos = pos + active.astype(pos.dtype)
                 tok = jnp.where(active, nxt, tok)
-                return (kv, pos, tok, nkd), nxt
+                return (kv, pos, tok, nkd), (nxt, counts)
 
-            (kv, pos, tok, kd), toks = lax.scan(
+            (kv, pos, tok, kd), (toks, counts) = lax.scan(
                 body, (kv, pos, tok, keydata), None,
                 length=n_steps)
+            if own_block:
+                return kv, toks.T, pos, tok, kd, counts
             return kv, toks.T, pos, tok, kd
 
         return chunk
@@ -797,6 +891,27 @@ class DecodeEngine:
             kv = kv_pages.commit_prefill(
                 kv, ks, vs, page_row, ps, n_valid=t0)
             return kv, last.astype(jnp.float32)
+
+        return prefill
+
+    def _build_model_prefill_fn(self):
+        """Prefill of one request by a model that brings its own block:
+        its ``prefill`` gives the attention layers' K/V (committed to
+        the slot's pages as ``_build_prefill_fn`` does), the per-slot
+        state at the last REAL position (written into row ``slot`` of
+        the state array, so a reused slot starts from its own prompt),
+        the last real position's logits and the prompt's counts."""
+        m, ps, attn = self.model, self.page_size, self._attn_mode
+
+        def prefill(params, cache, prompt, page_row, t0, slot):
+            kv, state = cache
+            ks, vs, mine, last, counts = m.prefill(params, prompt, t0,
+                                                   mode=attn)
+            kv = kv_pages.commit_prefill(kv, ks, vs, page_row, ps,
+                                         n_valid=t0)
+            state = lax.dynamic_update_slice_in_dim(
+                state, mine[:, None].astype(state.dtype), slot, axis=1)
+            return (kv, state), last.astype(jnp.float32), counts
 
         return prefill
 
@@ -973,6 +1088,35 @@ class DecodeEngine:
 
         return verify
 
+    # -------------------------------------------- the cache as one tree
+    def _cache(self):
+        """What the decode and prefill programs carry and donate: the
+        pool's tree, paired with the per-slot state where the model
+        has one."""
+        if self._own_block:
+            return (self.pool.tree(), self._state)
+        return self.pool.tree()
+
+    def _rebind(self, cache) -> None:
+        if self._own_block:
+            cache, self._state = cache
+        self.pool.rebind(cache)
+
+    def _count_experts(self, counts: np.ndarray) -> Dict[str, int]:
+        """Add one program's counts ``[..., expert layers, 3]``
+        (assignments, distinct experts, hottest load per layer-step) to
+        the cumulative forms; -> the same sums as span attributes."""
+        c = counts.reshape(-1, 3).astype(np.int64)
+        # a layer-step that routed nothing (no live lane) is no work
+        c = c[c[:, 0] > 0]
+        got = {"expert_assignments": int(c[:, 0].sum()),
+               "experts_touched": int(c[:, 1].sum()),
+               "expert_layer_steps": int(len(c)),
+               "expert_load_max": int(c[:, 2].sum())}
+        for name, n in got.items():
+            self._expert_totals[name] += n
+        return got
+
     # ---------------------------------------------------------- startup
     def start(self) -> "DecodeEngine":
         with self._start_lock:
@@ -1031,12 +1175,15 @@ class DecodeEngine:
                              engine=self.engine_id,
                              adopted=self._warm.adopted):
             kv_abs = _abs(self.pool.tree())
+            cache_abs = _abs(self._cache())
+            # a model's own prefill is also told its slot
+            slot_arg = (sds((), i32),) if self._own_block else ()
             for k in self._chunks:
                 if ("decode", k) in self._warm:
                     continue
                 self._warm.compile(
                     ("decode", k), self._decode_jits[k],
-                    _abs(self._decode_params), kv_abs,
+                    _abs(self._decode_params), cache_abs,
                     sds((S, P), i32), sds((S,), i32), sds((S,), bool),
                     sds((S,), i32), sds((S, kw), u32), sds((S,), f32))
             for b in self.prefill_buckets:
@@ -1044,8 +1191,9 @@ class DecodeEngine:
                     continue
                 self._warm.compile(
                     ("prefill", b), self._prefill_jit,
-                    _abs(self.params), kv_abs, sds((1, b), i32),
-                    sds((b // self.page_size,), i32), sds((), i32))
+                    _abs(self.params), cache_abs, sds((1, b), i32),
+                    sds((b // self.page_size,), i32), sds((), i32),
+                    *slot_arg)
             for b in self.handoff_buckets:
                 if ("adopt", b) in self._warm:
                     continue
@@ -1309,6 +1457,9 @@ class DecodeEngine:
             "prefill_tokens": self.n_prefill_tokens,
             "prefill_bucket_tokens": self.n_prefill_bucket_tokens,
             "tokens": self.n_tokens,
+            **self._expert_totals,
+            "state_bytes": (int(self._state.nbytes)
+                            if self._state is not None else 0),
             "active_slots": int(self._active.sum()),
             "queued": self._queue.qsize() + len(self._waiting),
             "avg_occupancy": (self._occupancy_sum / self.n_steps
@@ -1816,10 +1967,17 @@ class DecodeEngine:
                 page_row = np.zeros((bucket // ps,), np.int32)
                 n_real = min(len(rows), bucket // ps)
                 page_row[:n_real] = rows[:n_real]
-                kvt, last = self._warm.run(
+                # a model's own prefill is told its slot (whose state
+                # row it writes) and returns its counts
+                slot = ((jnp.asarray(s, jnp.int32),) if self._own_block
+                        else ())
+                kvt, last, *counts = self._warm.run(
                     ("prefill", bucket), self._prefill_fallback,
-                    self.params, self.pool.tree(), jnp.asarray(prompt),
-                    jnp.asarray(page_row), jnp.asarray(t0, jnp.int32))
+                    self.params, self._cache(), jnp.asarray(prompt),
+                    jnp.asarray(page_row), jnp.asarray(t0, jnp.int32),
+                    *slot)
+                for c in counts:
+                    sp.set(**self._count_experts(np.asarray(c)))
             else:
                 # warm path: prefill ONLY the uncached suffix, mid-page
                 # starts included — attention reads the shared prefix
@@ -1839,7 +1997,7 @@ class DecodeEngine:
                     jnp.asarray(table), jnp.asarray(t_start, jnp.int32),
                     jnp.asarray(t0, jnp.int32))
             logits = np.asarray(last)
-        self.pool.rebind(kvt)
+        self._rebind(kvt)
         first = self._sample_first(req, logits)
         req.cache_hit_tokens = t_start
         if req._trace is not None:
@@ -2073,6 +2231,7 @@ class DecodeEngine:
             tok = jnp.asarray(self._tok)
             kd = jnp.asarray(self._keydata)
             chunks: List[Any] = []
+            expert_counts: List[Any] = []   # a model's own counts
             steps = 0
             while True:
                 k = 1
@@ -2085,12 +2244,13 @@ class DecodeEngine:
                 self.n_attended_tokens += ctx
                 with _telemetry.span("engine.dispatch", k=k, live=live,
                                      ctx_tokens=ctx):
-                    (kvt, toks, pos, tok, kd) = self._warm.run(
+                    (kvt, toks, pos, tok, kd, *counts) = self._warm.run(
                         ("decode", k), self._decode_fallbacks[k],
-                        self._decode_params, self.pool.tree(), tables,
+                        self._decode_params, self._cache(), tables,
                         pos, active, tok, kd, temps)
-                self.pool.rebind(kvt)
+                self._rebind(kvt)
                 chunks.append(toks)
+                expert_counts.extend(counts)
                 steps += k
                 self.n_dispatches += 1
                 if has_eos or steps >= min_rem \
@@ -2109,6 +2269,9 @@ class DecodeEngine:
                 self._pos = np.array(pos)
                 self._tok = np.array(tok)
                 self._keydata = np.array(kd)
+                if expert_counts:
+                    sync.set(**self._count_experts(np.concatenate(
+                        [np.asarray(c) for c in expert_counts])))
             self.n_steps += steps
             self._occupancy_sum += occupancy * steps
             _flight.record("serving_burst", engine=self.engine_id,

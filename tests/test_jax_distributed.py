@@ -276,6 +276,7 @@ def test_package_import_leaves_backend_uninitialized():
     code = (
         "import deeplearning4j_tpu.nn.conf, deeplearning4j_tpu.ops,\\\n"
         "    deeplearning4j_tpu.models.gpt, deeplearning4j_tpu.datasets,\\\n"
+        "    deeplearning4j_tpu.models.lfm2_moe,\\\n"
         "    deeplearning4j_tpu.graph, deeplearning4j_tpu.clustering,\\\n"
         "    deeplearning4j_tpu.dimensionalityreduction\n"
         "import jax._src.xla_bridge as xb\n"

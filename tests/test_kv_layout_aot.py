@@ -155,3 +155,41 @@ def test_detector_sees_the_old_write(one_chip, monkeypatch):
                         eng.pool.k)
     assert found["loop"] == 2 * LAYERS
     assert found["all"] >= found["loop"]
+
+
+# ----------------- the kernels LFM2-24B-A2B serves through, at its widths
+def _sds(shape, dtype, chip):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+@pytest.mark.parametrize("rows,k,n", [(64, 2048, 1536), (1024, 2048, 1536),
+                                      (64, 1536, 2048)],
+                         ids=["decode-up", "prefill-up", "decode-down"])
+def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, k, n):
+    """16 slots x 4 experts a token (or a bucket of 256) over 64
+    experts of 2048 x 1536: the chip's compiler takes the blocks."""
+    from deeplearning4j_tpu.ops.grouped_matmul_pallas import grouped_matmul
+
+    bf16 = jnp.bfloat16
+    hlo = jax.jit(lambda a, b, c: grouped_matmul(a, b, c, mode="pallas")) \
+        .lower(_sds((rows, k), bf16, one_chip),
+               _sds((64, k, n), bf16, one_chip),
+               _sds((64,), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "moe_experts" in hlo
+    # no copy of the 64 experts' matrices on the way in
+    assert not re.search(r"bf16\[64,%d,%d\][^\n]* copy\(" % (k, n), hlo)
+
+
+def test_grouped_query_paged_kernel_compiles_at_published_widths(one_chip):
+    """32 query heads over pools of 8 KV heads of 64: the group of 4
+    rides the query axis, the grid is (slots, KV heads, pages)."""
+    from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = _sds((2, 1 + 16 * 64, 8, 16, 64), bf16, one_chip)
+    hlo = jax.jit(lambda q, k, v, t, b: paged_attention(
+        q, {"k": k, "v": v}, 1, t, b, mode="pallas")) \
+        .lower(_sds((16, 32, 1, 64), bf16, one_chip), pool, pool,
+               _sds((16, 64), i32, one_chip), _sds((16,), i32, one_chip)) \
+        .compile().as_text()
+    assert "tpu_custom_call" in hlo and "paged_attention" in hlo
