@@ -684,8 +684,10 @@ class DecodeEngine:
         #: (a decode step: what each live slot holds; a verify dispatch
         #: or a suffix prefill: the lane's context once, its queries
         #: share the read), and prompt tokens prefilled against the
-        #: padded buckets they ran in
+        #: padded buckets they ran in; and the pages those positions
+        #: occupy, call by call: what the kernel's walk has to visit
         self.n_attended_tokens = 0
+        self.n_attended_pages = 0
         self.n_prefill_tokens = 0
         self.n_prefill_bucket_tokens = 0
         self.n_tokens = 0
@@ -1437,6 +1439,11 @@ class DecodeEngine:
             return False
         return self._sessions.release(session_id, self.pool)
 
+    def _pages_held(self, positions) -> int:
+        """Pages the given counts of positions occupy, summed: what the
+        paged kernel's calls visit (``ctx_pages`` beside ``ctx_tokens``)."""
+        return int((-(-np.asarray(positions) // self.page_size)).sum())
+
     def stats(self) -> Dict[str, Any]:
         return {
             "engine_id": self.engine_id,
@@ -1454,6 +1461,7 @@ class DecodeEngine:
             "decode_steps": self.n_steps,
             "dispatches": self.n_dispatches,
             "attended_tokens": self.n_attended_tokens,
+            "attended_pages": self.n_attended_pages,
             "prefill_tokens": self.n_prefill_tokens,
             "prefill_bucket_tokens": self.n_prefill_bucket_tokens,
             "tokens": self.n_tokens,
@@ -1985,7 +1993,9 @@ class DecodeEngine:
                 # kernel a layer, whose queries share one read of the
                 # t0 positions the slot then holds
                 self.n_attended_tokens += t0
-                sp.set(ctx_tokens=t0)
+                pages = self._pages_held(t0)
+                self.n_attended_pages += pages
+                sp.set(ctx_tokens=t0, ctx_pages=pages)
                 suffix = np.zeros((bucket,), np.int32)
                 suffix[:sl] = req.prompt[t_start:]
                 table = np.zeros((self.pages_per_slot,), np.int32)
@@ -2101,15 +2111,18 @@ class DecodeEngine:
         # a lane scores its n_draft + 1 positions in the one dispatch:
         # one call of the paged kernel a layer, whose queries share one
         # read of the pos + n_draft + 1 positions the lane then holds
-        ctx = int((self._pos[active_idx].astype(np.int64)
-                   + n_draft[active_idx] + 1).sum())
+        held = self._pos[active_idx].astype(np.int64) \
+            + n_draft[active_idx] + 1
+        ctx, pages = int(held.sum()), self._pages_held(held)
         self.n_attended_tokens += ctx
+        self.n_attended_pages += pages
         with _telemetry.span(
                 "engine.burst", metric=_telemetry.SERVING_VERIFY_SECONDS,
                 engine=self.engine_id) as burst:
             tables, active, temps = self._dev_slot_state()
             with _telemetry.span("engine.dispatch", k=1, live=lanes,
-                                 ctx_tokens=ctx, verify=K):
+                                 ctx_tokens=ctx, ctx_pages=pages,
+                                 verify=K):
                 (kvt, out, adv, pos, tok, kd) = self._warm.run(
                     ("verify", K), self._verify_fallback,
                     self._decode_params, self.pool.tree(), tables,
@@ -2221,7 +2234,8 @@ class DecodeEngine:
         free_slots = not self._active.all()
         live = int(len(active_idx))
         occupancy = float(live) / self.slots
-        pos_sum = int(self._pos[active_idx].sum())
+        pos_live = self._pos[active_idx].astype(np.int64)
+        pos_sum = int(pos_live.sum())
         with _telemetry.span(
                 "engine.burst",
                 metric=_telemetry.SERVING_DECODE_STEP_SECONDS,
@@ -2241,9 +2255,12 @@ class DecodeEngine:
                 # positions each live slot holds by then
                 ctx = k * (pos_sum + live * steps) \
                     + live * (k * (k + 1) // 2)
+                pages = self._pages_held(
+                    pos_live[:, None] + steps + np.arange(1, k + 1))
                 self.n_attended_tokens += ctx
+                self.n_attended_pages += pages
                 with _telemetry.span("engine.dispatch", k=k, live=live,
-                                     ctx_tokens=ctx):
+                                     ctx_tokens=ctx, ctx_pages=pages):
                     (kvt, toks, pos, tok, kd, *counts) = self._warm.run(
                         ("decode", k), self._decode_fallbacks[k],
                         self._decode_params, self._cache(), tables,

@@ -268,6 +268,11 @@ def test_each_engine_boundary_is_in_the_ring_once_with_its_counts(gpt, traced):
     # steps (the first token is the prefill's), step i over P + i positions
     by_hand = sum((n - 1) * p + n * (n - 1) // 2 for p, n in WORK)
     assert ctx_sum == stats["attended_tokens"] == by_hand
+    # and the pages those positions occupy, step by step (pages of 8)
+    pages_by_hand = sum(-(-(p + i) // 8) for p, n in WORK
+                        for i in range(1, n))
+    assert sum(d["args"]["ctx_pages"] for d in _ring("engine.dispatch")) \
+        == stats["attended_pages"] == pages_by_hand
     assert stats["prefill_tokens"] == sum(p for p, _ in WORK)
     assert stats["prefill_bucket_tokens"] == sum(
         e["args"]["bucket"] for e in _ring("engine.prefill")) \
@@ -380,11 +385,15 @@ def test_a_suffix_prefill_counts_the_context_its_kernel_reads(gpt):
     assert "ctx_tokens" not in cold["args"] and cold["args"]["hit_tokens"] == 0
     assert warm["args"]["hit_tokens"] == 16         # two full pages
     assert warm["args"]["ctx_tokens"] == 21
+    assert "ctx_pages" not in cold["args"] and warm["args"]["ctx_pages"] == 3
     reuse = [a["args"]["reuse"] for a in _ring("engine.admit")]
     assert reuse[0] == "cold" and reuse[1] != "cold"
     decode = sum(d["args"]["ctx_tokens"] for d in _ring("engine.dispatch"))
     assert decode == 2 * (2 * 21 + 3)        # two steps each: 22 + 23
     assert stats["attended_tokens"] == decode + 21
+    # 22 and 23 positions lie on 3 pages of 8, for both requests
+    assert sum(d["args"]["ctx_pages"] for d in _ring("engine.dispatch")) \
+        == 2 * 2 * 3 == stats["attended_pages"] - 3
     assert stats["prefill_tokens"] == 21 + 5
 
 
@@ -429,6 +438,13 @@ def test_a_verify_burst_is_one_step_and_counts_what_its_lanes_attend(gpt):
     # whole sequence, however many drafts it scores
     whole = sum(p + n for p, n in WORK)
     assert all(0 < d["args"]["ctx_tokens"] <= whole for d in verify)
+    # pages: what the positions occupy, lane by lane, so between the
+    # positions over a page's size and that plus a page a live lane
+    assert sum(d["args"]["ctx_pages"] for d in disp) == \
+        stats["attended_pages"]
+    assert all(d["args"]["ctx_tokens"] / 8 <= d["args"]["ctx_pages"]
+               < d["args"]["ctx_tokens"] / 8 + d["args"]["live"] * d["args"]["k"]
+               for d in disp)
     assert len(verify) == stats["spec"]["verify_dispatches"]
     assert all(d["args"]["k"] == 1 and d["args"]["verify"] == 3
                for d in verify)

@@ -10,7 +10,10 @@ its boundary; they are no write's doing and not this file's subject.) The paged 
 operands row-major; a write whose update window is wider than the
 trailing ``hd`` makes XLA keep the pools in another layout and copy
 each whole pool, every layer, to bridge the two (PERF.md section 6,
-PR 27: 72 copies of 379 MB a decode step). The control case compiles
+PR 27: 72 copies of 379 MB a decode step). The kernel's grid is (KV
+head blocks, live visits): a visit holds every KV head of eight pages
+of one sequence, and the visits are as many as the pages the
+sequences hold (PR 29); the last tests compile it at the cells' shapes. The control case compiles
 the same program around that old write and must find those copies —
 it proves the search can see the fault.
 
@@ -180,16 +183,45 @@ def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, k, n):
     assert not re.search(r"bf16\[64,%d,%d\][^\n]* copy\(" % (k, n), hlo)
 
 
-def test_grouped_query_paged_kernel_compiles_at_published_widths(one_chip):
-    """32 query heads over pools of 8 KV heads of 64: the group of 4
-    rides the query axis, the grid is (slots, KV heads, pages)."""
+#: the pools of the two serving cells (BENCHMARK.json): layers, pages,
+#: KV heads, page size, head width; and the query heads over them
+GPT2_LARGE = ((36, 1 + 4 * 64, 20, 16, 64), 20)
+LFM2_24B = ((2, 1 + 16 * 64, 8, 16, 64), 32)
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.float8_e4m3fn],
+                         ids=["bf16", "fp8"])
+@pytest.mark.parametrize("cell,slots,queries", [
+    (GPT2_LARGE, 4, 1), (LFM2_24B, 16, 1),      # the cells' decode steps
+    (GPT2_LARGE, 4, 4),                         # a verify call, k = 3
+    (GPT2_LARGE, 1, 256), (LFM2_24B, 1, 256),   # a suffix-prefill bucket
+    (GPT2_LARGE, 1, 1024)],
+    ids=["gpt2-decode", "lfm2-decode", "gpt2-verify", "gpt2-suffix256",
+         "lfm2-suffix256", "gpt2-suffix1024"])
+def test_paged_kernel_compiles_at_published_widths(one_chip, cell, slots,
+                                                   queries, kv_dtype):
+    """The chip's compiler takes the walk at the shapes the cells run:
+    every KV head of eight pages a visit, the visits as many as the
+    pages held (a dynamic grid), few rows a head on the VPU and many on
+    the MXU with fewer heads a visit, the group of 4 on the query axis;
+    and the pools go in as they rest, with no copy of one."""
     from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
 
-    bf16, i32 = jnp.bfloat16, jnp.int32
-    pool = _sds((2, 1 + 16 * 64, 8, 16, 64), bf16, one_chip)
-    hlo = jax.jit(lambda q, k, v, t, b: paged_attention(
-        q, {"k": k, "v": v}, 1, t, b, mode="pallas")) \
-        .lower(_sds((16, 32, 1, 64), bf16, one_chip), pool, pool,
-               _sds((16, 64), i32, one_chip), _sds((16,), i32, one_chip)) \
-        .compile().as_text()
+    from jax.experimental.layout import Format, Layout
+
+    (L, n_pages, Hkv, ps, hd), H = cell
+    bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    # row-major, as the serving programs hold the pools (above)
+    pool = _sds((L, n_pages, Hkv, ps, hd), kv_dtype,
+                Format(Layout(tuple(range(5))), one_chip))
+    kv = {"k": pool, "v": pool}
+    if kv_dtype != bf16:
+        kv.update(k_scale=_sds((L, n_pages, Hkv), f32, one_chip),
+                  v_scale=_sds((L, n_pages, Hkv), f32, one_chip))
+    hlo = jax.jit(lambda q, kv, t, b: paged_attention(
+        q, kv, 1, t, b, mode="pallas")) \
+        .lower(_sds((slots, H, queries, hd), bf16, one_chip), kv,
+               _sds((slots, 64), i32, one_chip),
+               _sds((slots,), i32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo and "paged_attention" in hlo
+    assert pool_copies(hlo, pool)["all"] == 0

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.models.gpt import CausalLM
 from deeplearning4j_tpu.models.transformer import tiny_config
 from deeplearning4j_tpu.nn import precision
+from deeplearning4j_tpu.ops import paged_attention_pallas as pa
 from deeplearning4j_tpu.ops.paged_attention_pallas import (
     paged_attention, paged_attention_mode,
 )
@@ -183,6 +184,144 @@ class TestKernelGolden:
         expect = ("pallas" if jax.default_backend() == "tpu"
                   else "xla")
         assert paged_attention_mode() == expect
+
+
+# ------------------------------------------------------- the live walk
+def _np_ref_grouped(q, kp, vp, tables, qbase, group):
+    """``_np_ref`` with ``group`` query heads a KV head."""
+    return _np_ref(q, np.repeat(kp, group, axis=1),
+                   np.repeat(vp, group, axis=1), tables, qbase)
+
+
+class TestLiveWalk:
+    """What a walk over live pages only, ``B`` table slots and every KV
+    head a visit, can get wrong. Every case points every table slot
+    past a lane's last live page (the page of its last query) at a page
+    filled with NaN: a finite output that equals the references proves
+    that nothing past that page was read."""
+
+    PS, HD, HKV = 4, 8, 2
+    B = pa._PAGES_A_VISIT                 # table slots a visit
+    VISIT = B * PS                        # positions a visit
+
+    def _case(self, qbase, P, *, Q=1, G=1, fp8=False, evicted=(),
+              seed=0):
+        rng = np.random.default_rng(seed)
+        ps, hd, Hkv, N = self.PS, self.HD, self.HKV, len(qbase)
+        nan_page = 1 + N * P
+        kv = _mk_kv(rng, 2, nan_page + 1, Hkv, ps, hd, fp8=fp8)
+        kv = {n: a.at[:, nan_page].set(jnp.nan) for n, a in kv.items()}
+        assert all(bool(jnp.isnan(a[:, nan_page].astype(jnp.float32))
+                        .all()) for a in kv.values())
+        qbase = np.asarray(qbase, np.int32)
+        last = np.minimum((qbase + Q - 1) // ps, P - 1)
+        live = np.arange(P)[None, :] <= last[:, None]
+        own = 1 + rng.permutation(N * P).reshape(N, P)
+        own[list(evicted)] = 0            # an evicted lane: null table
+        tables = np.where(live, own, nan_page).astype(np.int32)
+        clean = np.where(live, own, 0).astype(np.int32)
+        q = jnp.asarray(rng.standard_normal((N, Hkv * G, Q, hd)),
+                        jnp.float32)
+        ker = np.asarray(paged_attention(
+            q, kv, 1, jnp.asarray(tables), jnp.asarray(qbase),
+            mode="interpret"))
+        xla = np.asarray(paged_attention(
+            q, kv, 1, jnp.asarray(clean), jnp.asarray(qbase), mode="xla"))
+        assert np.isfinite(ker).all()
+        np.testing.assert_allclose(ker, xla, atol=2e-5, rtol=2e-5)
+        if fp8:
+            return
+        # rows past the table's end (a padded suffix) see what the
+        # table holds: the dense reference is for rows inside it
+        ref = _np_ref_grouped(np.asarray(q), np.asarray(kv["k"][1]),
+                              np.asarray(kv["v"][1]), clean, qbase, G)
+        inside = (qbase[:, None] + np.arange(Q)[None, :]) < P * ps
+        np.testing.assert_allclose(
+            ker.transpose(0, 2, 1, 3)[inside],
+            ref.transpose(0, 2, 1, 3)[inside], atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("fp8", [False, True], ids=["f32", "fp8"])
+    def test_one_position_beside_a_full_table(self, fp8):
+        P = 3 * self.B
+        self._case([0, P * self.PS - 1, 5, self.VISIT + 1], P, fp8=fp8)
+
+    @pytest.mark.parametrize("edge", [
+        "page-1", "page", "page+1", "visit-1", "visit", "visit+1",
+        "2visit-1", "2visit", "table-1"])
+    @pytest.mark.parametrize("Q", [1, 4], ids=["decode", "verify"])
+    def test_qbase_at_every_edge(self, edge, Q):
+        """The last query on the last position of a page or a visit,
+        on the first of the next, and one further."""
+        P = 2 * self.B + 3
+        at = {"page": self.PS, "visit": self.VISIT,
+              "2visit": 2 * self.VISIT, "table": P * self.PS}
+        name, _, d = edge.partition("-" if "-" in edge else "+")
+        pos = at[name] + {"": 0, "1": -1 if "-" in edge else 1}[d]
+        # ``pos`` is where the LAST query sits
+        self._case([pos - (Q - 1), 2], P, Q=Q, seed=pos)
+
+    @pytest.mark.parametrize("where", [0, 1, 2])
+    def test_an_evicted_lane_beside_live_ones(self, where):
+        """``engine._evict``: position 0 and an all-null table."""
+        qbase = [self.VISIT + 3, 2 * self.VISIT, 9]
+        qbase[where] = 0
+        self._case(qbase, 2 * self.B + 1, evicted=[where], seed=where)
+
+    @pytest.mark.parametrize("P", [1, 3, pa._PAGES_A_VISIT + 1,
+                                   2 * pa._PAGES_A_VISIT + 5])
+    def test_table_width_not_a_multiple_of_a_visit(self, P):
+        top = P * self.PS - 1
+        self._case([top, top // 2, 0], P, seed=P)
+
+    @pytest.mark.parametrize("fp8", [False, True], ids=["f32", "fp8"])
+    @pytest.mark.parametrize("Q", [1, 4, 8],
+                             ids=["decode", "verify", "suffix"])
+    @pytest.mark.parametrize("G", [1, 4])
+    def test_groups_and_query_widths(self, G, Q, fp8):
+        """1 to 32 rows a KV head: both the few-rows and the many-rows
+        form of the visit, queries straddling a visit's edge, and (the
+        last lane) a padded suffix running past the table's end."""
+        P = 2 * self.B + 2
+        self._case([self.VISIT - 2, 3, P * self.PS - 3], P, Q=Q, G=G,
+                   fp8=fp8, seed=10 * G + Q)
+
+    def test_the_schedule_covers_the_pages_held_and_nothing_else(self):
+        ps, B, P = self.PS, self.B, 2 * self.B + 3
+        qbase = np.asarray([0, ps, B * ps - 1, B * ps, P * ps - 1,
+                            P * ps + 40], np.int32)
+        tables = np.arange(qbase.size * P, dtype=np.int32) \
+            .reshape(qbase.size, P) + 1
+        for Q in (1, 5):
+            lane, visit, pages, last, total = (np.asarray(a) for a in (
+                pa._live_visits(jnp.asarray(tables), jnp.asarray(qbase),
+                                Q, ps, B)))
+            want_last = np.minimum((qbase + Q - 1) // ps, P - 1)
+            np.testing.assert_array_equal(last, want_last)
+            steps = [(n, j) for n in range(qbase.size)
+                     for j in range(want_last[n] // B + 1)]
+            assert total == len(steps) <= qbase.size * -(-P // B)
+            assert list(zip(lane[:total], visit[:total])) == steps
+            for g, (n, j) in enumerate(steps):
+                slots = np.minimum(j * B + np.arange(B), want_last[n])
+                np.testing.assert_array_equal(
+                    pages[g * B:(g + 1) * B], tables[n, slots])
+            # steps past the total (never run) still name pages held
+            assert set(pages[total * B:]) <= set(
+                tables[-1, :want_last[-1] + 1])
+
+    @pytest.mark.parametrize("Hkv,rows,fp8,vpu,whole", [
+        (20, 1, False, True, True),       # gpt2-large decode
+        (8, 4, False, True, True),        # lfm2-24b-a2b decode
+        (20, 4, True, True, True),        # a verify call, fp8 pools
+        (20, 256, False, False, False),   # a suffix-prefill bucket
+        (20, 1024, False, False, False),
+        (64, 4, False, True, False)])
+    def test_heads_a_visit_fit_the_budget(self, Hkv, rows, fp8, vpu,
+                                          whole):
+        h = pa._heads_a_visit(Hkv, rows, 64, 16, 8, 1 if fp8 else 2,
+                              vpu, fp8)
+        assert Hkv % h == 0 and (h == Hkv) == whole
+        assert vpu == (rows <= pa._VPU_ROWS)
 
 
 # ------------------------------------------------------- fp8 numerics
