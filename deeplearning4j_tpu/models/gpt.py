@@ -26,9 +26,123 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.models.transformer import TransformerConfig
+from deeplearning4j_tpu.nn.precision import (int8_matmul, is_int8,
+                                             quantize_int8)
+
+
+def _paged():
+    """The page writers and the paged attention, imported where a paged
+    pass is traced: a trainer that imports this file does not pay for
+    the serving package and Pallas (1.2 s of the training cell's
+    ``setup_s``)."""
+    from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
+    from deeplearning4j_tpu.serving import kv_pages
+
+    return kv_pages, paged_attention
+
+
+def _attention(q, k, v, mask):
+    """Masked softmax attention over ``[n, H, t, hd]`` heads."""
+    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
+    logits = jnp.einsum("nhqd,nhkd->nhqk", q, k) * scale
+    neg = jnp.asarray(jnp.finfo(logits.dtype).min, logits.dtype)
+    w = jax.nn.softmax(jnp.where(mask, logits, neg), axis=-1)
+    return jnp.einsum("nhqk,nhkd->nhqd", w, v)
+
+
+class _Causal:
+    """A fresh sequence ``[n, t]``: each position attends those before
+    it; every layer's K/V ``[n, H, t, hd]`` is kept for a prefill."""
+
+    def __init__(self, cfg, n, t):
+        self.cfg, self.n, self.t = cfg, n, t
+        self.mask = jnp.tril(jnp.ones((t, t), bool))[None, None]
+        self.ks, self.vs = [], []
+
+    def attend(self, li, q, k, v):
+        c, n, t = self.cfg, self.n, self.t
+        q, k, v = (y.reshape(n, t, c.n_heads, c.head_dim)
+                   .transpose(0, 2, 1, 3) for y in (q, k, v))
+        self.ks.append(k)
+        self.vs.append(v)
+        ctx = _attention(q, k, v, self.mask)
+        return ctx.transpose(0, 2, 1, 3).reshape(n, t, c.d_model)
+
+
+class _DenseCache:
+    """One token a row at the shared position ``pos``, K/V in the dense
+    cache ``[L, N, H, max_len, hd]`` of ``generate()``."""
+
+    def __init__(self, cfg, ck, cv, pos):
+        self.cfg, self.ck, self.cv, self.pos = cfg, ck, cv, pos
+        self.mask = (jnp.arange(cfg.max_len) <= pos)[None, None, None, :]
+
+    def attend(self, li, q, k, v):
+        c, n = self.cfg, q.shape[0]
+        q, k, v = (y.reshape(n, c.n_heads, 1, c.head_dim)
+                   for y in (q, k, v))
+        at = (li, 0, 0, self.pos, 0)
+        self.ck = lax.dynamic_update_slice(self.ck, k[None], at)
+        self.cv = lax.dynamic_update_slice(self.cv, v[None], at)
+        ctx = _attention(q, self.ck[li], self.cv[li], self.mask)
+        return ctx.reshape(n, c.d_model)
+
+
+class _Paged:
+    """One token a slot: K/V appended to the slot's page, attention
+    through the page tables."""
+
+    def __init__(self, cfg, kv, tables, pos, page_size, mode):
+        self.cfg, self.kv, self.tables, self.pos = cfg, kv, tables, pos
+        self.page = tables[jnp.arange(pos.shape[0]), pos // page_size]
+        self.off = pos % page_size
+        self.mode = mode
+        self.pages, self.attention = _paged()
+
+    def attend(self, li, q, k, v):
+        c, S = self.cfg, q.shape[0]
+        q, k, v = (y.reshape(S, c.n_heads, 1, c.head_dim)
+                   for y in (q, k, v))
+        self.kv = self.pages.append_token(self.kv, li, self.page, self.off,
+                                          k[:, :, 0], v[:, :, 0])
+        ctx = self.attention(q, self.kv, li, self.tables, self.pos,
+                             mode=self.mode)
+        return ctx.reshape(S, c.d_model)
+
+
+class _PagedRows:
+    """``W`` consecutive positions a slot from ``pos``: each row's K/V
+    goes to its own (page, offset) before the rows attend through the
+    slot's whole table (row ``i`` at ``pos + i``, causal by the
+    kernel's flat-position mask). Rows that are not ``real`` write to
+    the null page and, in an fp8 pool, to no page's scale."""
+
+    def __init__(self, cfg, kv, tables, pos, real, page_size, mode):
+        self.cfg, self.kv, self.tables, self.pos = cfg, kv, tables, pos
+        self.real, self.mode = real, mode
+        P, W = tables.shape[1], real.shape[1]
+        self.posw = pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
+        chunk = jnp.minimum(self.posw // page_size, P - 1)
+        self.page = jnp.where(
+            real, jnp.take_along_axis(tables, chunk, axis=1), 0)
+        self.off = self.posw % page_size
+        self.seg = jnp.where(real, chunk, P)
+        self.pages, self.attention = _paged()
+
+    def attend(self, li, q, k, v):
+        c, (S, W) = self.cfg, self.real.shape
+        q, k, v = (y.reshape(S, W, c.n_heads, c.head_dim)
+                   for y in (q, k, v))
+        self.kv = self.pages.append_spec(
+            self.kv, li, self.page, self.off, k, v, chunk=self.seg,
+            real=self.real, tables=self.tables)
+        ctx = self.attention(q.transpose(0, 2, 1, 3), self.kv, li,
+                             self.tables, self.pos, mode=self.mode)
+        return ctx.transpose(0, 2, 1, 3).reshape(S, W, c.d_model)
 
 
 class CausalLM:
@@ -82,57 +196,77 @@ class CausalLM:
         return ((x - m) * lax.rsqrt(v + 1e-5) * p["g"].astype(x.dtype)
                 + p["b"].astype(x.dtype))
 
-    def _heads(self, y, n, t):
-        cfg = self.cfg
-        return y.reshape(n, t, cfg.n_heads, cfg.head_dim) \
-                .transpose(0, 2, 1, 3)
+    def _embed(self, params, tok, at):
+        """Token rows plus the position rows ``pos_emb[at]``; a
+        quantized ``tok_emb`` carries one scale a row."""
+        cd, w = self._cdtype, params["tok_emb"]
+        if is_int8(w):
+            rows = w["q"][tok].astype(cd) * w["s"][tok][..., None].astype(cd)
+        else:
+            rows = w.astype(cd)[tok]
+        return rows + params["pos_emb"].astype(cd)[at]
+
+    def _head(self, x, params):
+        """Tied LM head ``x @ tok_emb.T`` (per-row scales become
+        per-output-column scales of the transpose)."""
+        cd, w = self._cdtype, params["tok_emb"]
+        if is_int8(w):
+            return (x @ w["q"].astype(cd).T) * w["s"].astype(cd)[None, :]
+        return x @ w.astype(cd).T
+
+    def _block(self, li, lp, x, cache, bias_first=False, drop_key=None):
+        """THE decoder layer (pre-LN): ``cache.attend`` is all that
+        differs between the callers. The two adds after each output
+        product are kept in the order each caller has always had them:
+        ``forward`` adds the bias to the product (dropout acts on that
+        sum) and the sum to the stream (``bias_first``); the cached
+        passes add the product to the stream, then the bias. bf16
+        rounds the two differently, and the greedy-identity tests and
+        the measured programs are pinned to each."""
+        cd = self._cdtype
+
+        def joined(x, y, b, key=None):
+            if not bias_first:
+                return x + y + b
+            y = y + b
+            if key is not None:
+                _, sub = jax.random.split(key)
+                keep = 1.0 - self.cfg.dropout
+                y = y * jax.random.bernoulli(sub, keep, y.shape) / keep
+            return x + y
+
+        h = self._ln(x, lp["ln1"])
+        qkv = int8_matmul(h, lp["wqkv"], cd) + lp["bqkv"].astype(cd)
+        ctx = cache.attend(li, *jnp.split(qkv, 3, axis=-1))
+        x = joined(x, int8_matmul(ctx, lp["wo"], cd), lp["bo"].astype(cd),
+                   drop_key)
+        h = self._ln(x, lp["ln2"])
+        mid = jax.nn.gelu(int8_matmul(h, lp["w1"], cd) + lp["b1"].astype(cd))
+        return joined(x, int8_matmul(mid, lp["w2"], cd), lp["b2"].astype(cd))
+
+    def _run(self, params, x, cache, bias_first=False, drop_keys=None):
+        for li, lp in enumerate(params["layers"]):
+            x = self._block(li, lp, x, cache, bias_first,
+                            None if drop_keys is None else drop_keys[li])
+        return self._ln(x, params["ln_f"])
 
     # -- training forward ----------------------------------------------
     def forward(self, params, ids, train=False, rng=None,
                 return_kv=False):
         """ids [N,T] -> logits [N,T,V] (causal). With return_kv, also
         returns the per-layer K/V stacks [L,N,H,T,hd] (the parallel
-        prefill path of generate())."""
+        prefill path of generate() and of the serving engine)."""
         cfg = self.cfg
-        cd = self._cdtype
         n, t = ids.shape
-        x = params["tok_emb"].astype(cd)[ids] \
-            + params["pos_emb"].astype(cd)[None, :t]
-        causal = jnp.tril(jnp.ones((t, t), bool))[None, None]
-        scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, cd))
-        keys = (jax.random.split(rng, cfg.n_layers)
-                if (train and rng is not None) else [None] * cfg.n_layers)
-        all_k, all_v = [], []
-        for lp, k in zip(params["layers"], keys):
-            h = self._ln(x, lp["ln1"])
-            qkv = h @ lp["wqkv"].astype(cd) + lp["bqkv"].astype(cd)
-            q, kk, v = (self._heads(y, n, t)
-                        for y in jnp.split(qkv, 3, axis=-1))
-            if return_kv:
-                all_k.append(kk)
-                all_v.append(v)
-            logits = jnp.einsum("nhqd,nhkd->nhqk", q, kk) * scale
-            neg = jnp.asarray(jnp.finfo(logits.dtype).min, logits.dtype)
-            logits = jnp.where(causal, logits, neg)
-            w = jax.nn.softmax(logits, axis=-1)
-            ctx = jnp.einsum("nhqk,nhkd->nhqd", w, v)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(n, t, cfg.d_model)
-            att = ctx @ lp["wo"].astype(cd) + lp["bo"].astype(cd)
-            if train and k is not None and cfg.dropout > 0:
-                k, sub = jax.random.split(k)
-                keep = 1.0 - cfg.dropout
-                att = att * jax.random.bernoulli(sub, keep,
-                                                 att.shape) / keep
-            x = x + att
-            h = self._ln(x, lp["ln2"])
-            mid = jax.nn.gelu(h @ lp["w1"].astype(cd)
-                              + lp["b1"].astype(cd))
-            out = mid @ lp["w2"].astype(cd) + lp["b2"].astype(cd)
-            x = x + out
-        x = self._ln(x, params["ln_f"])
-        logits = x @ params["tok_emb"].astype(cd).T
+        x = self._embed(params, ids, np.s_[None, :t])
+        cache = _Causal(cfg, n, t)
+        drop = train and rng is not None and cfg.dropout > 0
+        x = self._run(params, x, cache, bias_first=True,
+                      drop_keys=(jax.random.split(rng, cfg.n_layers)
+                                 if drop else None))
+        logits = self._head(x, params)
         if return_kv:
-            return logits, jnp.stack(all_k), jnp.stack(all_v)
+            return logits, jnp.stack(cache.ks), jnp.stack(cache.vs)
         return logits
 
     def lm_loss(self, params, ids, train=True, rng=None):
@@ -161,39 +295,70 @@ class CausalLM:
 
         return step
 
+    # -- what a serving engine asks of a model (docs/SERVING.md) ---------
+    def cache_spec(self) -> Dict[str, Any]:
+        """K/V pages in every layer, as many KV heads as query heads,
+        no per-slot state beside them."""
+        c = self.cfg
+        return {"kv_layers": c.n_layers, "kv_heads": c.n_heads,
+                "head_dim": c.head_dim, "state": None}
+
+    def prefill(self, params, prompt, t0, mode=None):
+        """A padded prompt ``[1, B]`` of ``t0`` real tokens -> ``(ks,
+        vs, None, last, None)``: every layer's K/V ``[L, 1, H, B, hd]``
+        and the logits of position ``t0 - 1``. Positions >= t0 are
+        padding no real position can see, so both are exact."""
+        logits, ks, vs = self.forward(params, prompt, return_kv=True)
+        last = lax.dynamic_index_in_dim(logits[0], t0 - 1, axis=0,
+                                        keepdims=False)
+        return ks, vs, None, last, None
+
+    def decode_step(self, params, kv, state, tables, pos, tok, active,
+                    page_size, mode=None):
+        """One token a slot: ``tok [S]`` at ``pos [S]`` -> ``(kv, None,
+        logits [S, V] float32, None)``. ``_decode_one`` with the K/V in
+        pages; a slot that is not ``active`` has an all-null table, so
+        its write lands on the null page."""
+        cache = _Paged(self.cfg, kv, tables, pos, page_size, mode)
+        x = self._run(params, self._embed(params, tok, pos), cache)
+        return (cache.kv, None,
+                self._head(x, params).astype(jnp.float32), None)
+
+    def paged_rows(self, params, kv, tables, pos, toks, real, page_size,
+                   mode=None):
+        """``W`` consecutive positions a lane: ``toks [S, W]`` from
+        ``pos [S]`` -> ``(kv, logits [S, W, V] float32)``. Each row's
+        K/V is written before the rows are attended (row ``i`` sees
+        rows ``< i`` of this call); rows that are not ``real`` write to
+        the null page. A speculative verify (``W`` = drafts + 1) and a
+        suffix prefill behind cached pages (``S`` = 1) are this."""
+        cache = _PagedRows(self.cfg, kv, tables, pos, real, page_size, mode)
+        at = jnp.minimum(cache.posw, self.cfg.max_len - 1)
+        x = self._run(params, self._embed(params, toks, at), cache)
+        return cache.kv, self._head(x, params).astype(jnp.float32)
+
+    def quantize_decode_params(self, params):
+        """int8 weight-only tree for the decode step: every 2-D matmul
+        weight gets per-output-channel scales; tok_emb is per-ROW
+        scaled so the same tensor serves the embedding gather (rows)
+        and the tied LM head (rows become output channels of x@W.T).
+        Biases, norms, positions stay float."""
+        def q(w, axis):
+            wq = quantize_int8(w, axis=axis)
+            return {"q": wq["q"], "s": wq["s"]}   # drop static axis key
+
+        mats = ("wqkv", "wo", "w1", "w2")
+        return dict(params, tok_emb=q(params["tok_emb"], 0), layers=[
+            {k: q(w, 1) if k in mats else w for k, w in lp.items()}
+            for lp in params["layers"]])
+
     # -- KV-cache generation --------------------------------------------
     def _decode_one(self, params, ck, cv, pos, tok):
         """One decode step. tok [N] int32 at position ``pos``; ck/cv
         [L,N,H,max_len,hd]. Returns (logits [N,V], new ck, cv)."""
-        cfg = self.cfg
-        cd = self._cdtype
-        n = tok.shape[0]
-        x = params["tok_emb"].astype(cd)[tok] \
-            + params["pos_emb"].astype(cd)[pos]
-        scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, cd))
-        valid = (jnp.arange(cfg.max_len) <= pos)[None, None, None, :]
-        for li, lp in enumerate(params["layers"]):
-            h = self._ln(x, lp["ln1"])
-            qkv = h @ lp["wqkv"].astype(cd) + lp["bqkv"].astype(cd)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            hs = lambda y: y.reshape(n, cfg.n_heads, 1, cfg.head_dim)
-            q, k, v = hs(q), hs(k), hs(v)
-            ck = lax.dynamic_update_slice(ck, k[None], (li, 0, 0, pos, 0))
-            cv = lax.dynamic_update_slice(cv, v[None], (li, 0, 0, pos, 0))
-            logits = jnp.einsum("nhqd,nhkd->nhqk", q, ck[li]) * scale
-            neg = jnp.asarray(jnp.finfo(logits.dtype).min, logits.dtype)
-            logits = jnp.where(valid, logits, neg)
-            w = jax.nn.softmax(logits, axis=-1)
-            ctx = jnp.einsum("nhqk,nhkd->nhqd", w, cv[li])
-            ctx = ctx.reshape(n, cfg.d_model)
-            x = x + ctx @ lp["wo"].astype(cd) + lp["bo"].astype(cd)
-            h = self._ln(x, lp["ln2"])
-            x = x + jax.nn.gelu(
-                h @ lp["w1"].astype(cd) + lp["b1"].astype(cd)) \
-                @ lp["w2"].astype(cd) + lp["b2"].astype(cd)
-        x = self._ln(x, params["ln_f"])
-        return (x @ params["tok_emb"].astype(cd).T).astype(jnp.float32), \
-            ck, cv
+        cache = _DenseCache(self.cfg, ck, cv, pos)
+        x = self._run(params, self._embed(params, tok, pos), cache)
+        return self._head(x, params).astype(jnp.float32), cache.ck, cache.cv
 
     def generate(self, params, prompt_ids, max_new_tokens: int,
                  temperature: float = 0.0,

@@ -1,8 +1,8 @@
-"""Continuous-batching decode engine over ``models/gpt.py`` CausalLM
-(whose block the programs below spell out) or over a model that brings
-its own block and says what it caches (``models/lfm2_moe.py``:
-``cache_spec()``, ``prefill``, ``decode_step``; "A model's own block"
-in the class docstring).
+"""Continuous-batching decode engine over any model that brings
+``cache_spec()`` / ``prefill`` / ``decode_step`` (docs/SERVING.md,
+"What a served model brings"): ``models/gpt.py`` CausalLM,
+``models/lfm2_moe.py``. This file schedules; it holds no model math
+and reads no parameter by name.
 
 The problem with ``generate()`` as a serving path: it compiles one
 program per ``(batch, prompt, new_tokens)`` shape, runs the whole batch
@@ -323,21 +323,6 @@ class _WarmPool:
 
 
 # --------------------------------------------------------- the engine
-def prefill_forward(model, params, prompt, t0):
-    """The prefill math every serving prefill shares: one batched
-    forward over the padded ``[1, B]`` prompt (positions >= t0 are
-    causally invisible) returning the per-layer K/V stacks and the
-    last REAL position's logits slice. The engine's prefill program
-    and the fleet's disaggregated lane BOTH call this, so lane-served
-    and engine-served prompts are bit-identical by construction — the
-    token-identity gate rests on there being exactly one copy of this
-    function."""
-    logits, ks, vs = model.forward(params, prompt, return_kv=True)
-    last = lax.dynamic_index_in_dim(logits[0], t0 - 1, axis=0,
-                                    keepdims=False)
-    return ks, vs, last
-
-
 def device_sds(shape, dtype, device=None) -> jax.ShapeDtypeStruct:
     """Abstract value for AOT lowering, pinned to ``device`` when one
     is given — lowering from unpinned abstracts compiles for the
@@ -351,11 +336,11 @@ def device_sds(shape, dtype, device=None) -> jax.ShapeDtypeStruct:
 
 
 class DecodeEngine:
-    """Continuous-batching generation server over a CausalLM.
+    """Continuous-batching generation server over a served model.
 
     Parameters
     ----------
-    model, params : the CausalLM and its parameter tree.
+    model, params : the model and its parameter tree.
     slots : static decode-batch width (requests in flight per step).
     page_size : KV-cache page length in positions.
     max_context : per-request position budget (prompt + generated);
@@ -414,20 +399,14 @@ class DecodeEngine:
     warm_start : AOT-compile the decode + prefill executables in
         ``start()`` so no request ever pays a trace.
 
-    A model's own block. A model with ``cache_spec()`` / ``prefill`` /
-    ``decode_step`` (``models/lfm2_moe.py``) is served by programs
-    built from those: the page pool is sized from what it says it
-    caches (pool layers = its attention layers, heads = its KV heads),
-    and where it names a per-slot ``state`` (conv windows), that array
-    ``[state layers, slots, ...]`` lives beside the pool: written by
-    prefill at admission (a reused slot never sees its predecessor's),
-    carried through the chunk's scan and donated across dispatches
-    like the pool. Scheduler, admission, bursts and hand-over are the
-    same. Options that key on pages alone cannot be honoured for a
-    model with such state (a page hit would need a state snapshot) and
-    are refused by name: ``prefix_cache``, ``session_capacity``,
-    ``spec_decode``, ``quantization``, ``kv_dtype``,
-    ``handoff_threshold``.
+    What a model has to bring, and which option needs which of its
+    methods, is docs/SERVING.md, "What a served model brings". Where
+    its ``cache_spec()`` names a per-slot ``state`` (conv windows),
+    that array ``[state layers, slots, ...]`` lives beside the pool:
+    written by prefill at admission (a reused slot never sees its
+    predecessor's), carried through the chunk's scan and donated
+    across dispatches like the pool; options that key on pages alone
+    are then refused by name.
     """
 
     def __init__(self, model, params, *, slots: int = 8,
@@ -450,13 +429,11 @@ class DecodeEngine:
                  spec_decode=None):
         cfg = model.cfg
         self.model = model
-        #: what the model caches: a model that brings its own block
-        #: says so; GPT-2 (the block inlined below) has pages in every
-        #: layer, as many KV heads as query heads and no other state
-        self._own_block = hasattr(model, "cache_spec")
-        spec = (model.cache_spec() if self._own_block else
-                {"kv_layers": cfg.n_layers, "kv_heads": cfg.n_heads,
-                 "head_dim": cfg.head_dim, "state": None})
+        #: what the model caches (docs/SERVING.md, "What a served
+        #: model brings"); which options it can be given follows from
+        #: that and from the methods it has, decided here and nowhere
+        #: else
+        spec = model.cache_spec()
         if spec["state"] is not None:
             refused = {"prefix_cache": prefix_cache,
                        "session_capacity": session_capacity > 0,
@@ -471,6 +448,16 @@ class DecodeEngine:
                         f"{type(model).__name__}: the model keeps a "
                         "per-slot recurrent state beside its pages, and "
                         "this option keys on pages alone")
+        needs = {"prefix_cache": (prefix_cache, "paged_rows"),
+                 "session_capacity": (session_capacity > 0, "paged_rows"),
+                 "spec_decode": (spec_decode is not None, "paged_rows"),
+                 "quantization": (quantization is not None,
+                                  "quantize_decode_params")}
+        for name, (asked, method) in needs.items():
+            if asked and not hasattr(model, method):
+                raise ValueError(
+                    f"{name} cannot be honoured for "
+                    f"{type(model).__name__}: it brings no {method}")
         #: metric/trace label for this engine (``engine=<id>`` on every
         #: SERVING_* series); auto-minted process-wide when not given
         self.engine_id = (str(engine_id) if engine_id is not None
@@ -506,7 +493,7 @@ class DecodeEngine:
         if quantization not in (None, "int8"):
             raise ValueError(f"unknown quantization {quantization!r} "
                              "(expected None or 'int8')")
-        self._decode_params = (self._quantize_decode_params(self.params)
+        self._decode_params = (model.quantize_decode_params(self.params)
                                if quantization == "int8" else self.params)
         #: canonical kv_dtype (None = pool in the compute dtype) and
         #: the attention implementation, both resolved ONCE here and
@@ -525,7 +512,7 @@ class DecodeEngine:
             engine_id=self.engine_id, device=device,
             kv_dtype=self.kv_dtype)
         #: the per-slot state beside the pool ``[state layers, slots,
-        #: ...]`` in the compute dtype, or None (GPT-2)
+        #: ...]`` in the compute dtype, or None where the model has none
         self._state = None
         if spec["state"] is not None:
             layers, *rest = spec["state"]
@@ -568,23 +555,20 @@ class DecodeEngine:
         while k <= self.max_chunk:
             self._chunks.append(k)
             k *= 2
-        core = (self._build_model_step_core() if self._own_block
-                else self._build_step_core())
-        # donate the KV tree (pools + any scale planes): the engine
-        # rebinds it from every call's outputs, and without donation
-        # XLA must copy the whole cache at every dispatch boundary
-        # (the scan inside a chunk already aliases; donation extends
-        # that across dispatches)
+        core = self._build_step_core()
+        # donate the cache (pools, any scale planes, any per-slot
+        # state): the engine rebinds it from every call's outputs, and
+        # without donation XLA must copy the whole cache at every
+        # dispatch boundary (the scan inside a chunk already aliases;
+        # donation extends that across dispatches)
         self._decode_jits = {
-            k: jax.jit(self._make_chunk(core, k, self._own_block),
-                       donate_argnums=(1,))
+            k: jax.jit(self._make_chunk(core, k), donate_argnums=(1,))
             for k in self._chunks}
         self._decode_fallbacks = {
             k: _telemetry.instrument_jit("serving_decode", fn)
             for k, fn in self._decode_jits.items()}
-        self._prefill_jit = jax.jit(
-            self._build_model_prefill_fn() if self._own_block
-            else self._build_prefill_fn(), donate_argnums=(1,))
+        self._prefill_jit = jax.jit(self._build_prefill_fn(),
+                                    donate_argnums=(1,))
         self._prefill_fallback = _telemetry.instrument_jit(
             "serving_prefill", self._prefill_jit)
         # fleet replica mode: the adopt scatter that commits a prefill
@@ -720,96 +704,7 @@ class DecodeEngine:
                     f"page_size {ps}")
         return out
 
-    def _quantize_decode_params(self, params):
-        """int8 weight-only tree for the decode step: every 2-D matmul
-        weight gets per-output-channel scales; tok_emb is per-ROW
-        scaled so the same tensor serves the embedding gather (rows)
-        and the tied LM head (rows become output channels of x@W.T).
-        Biases, norms, positions stay float."""
-        def q(w, axis):
-            wq = quantize_int8(w, axis=axis)
-            return {"q": wq["q"], "s": wq["s"]}   # drop static axis key
-
-        out = {"tok_emb": q(params["tok_emb"], 0),
-               "pos_emb": params["pos_emb"],
-               "ln_f": params["ln_f"],
-               "layers": []}
-        for lp in params["layers"]:
-            out["layers"].append({
-                "ln1": lp["ln1"], "ln2": lp["ln2"],
-                "wqkv": q(lp["wqkv"], 1), "bqkv": lp["bqkv"],
-                "wo": q(lp["wo"], 1), "bo": lp["bo"],
-                "w1": q(lp["w1"], 1), "b1": lp["b1"],
-                "w2": q(lp["w2"], 1), "b2": lp["b2"],
-            })
-        return jax.device_put(out)
-
     # --------------------------------------------------- jitted programs
-    @staticmethod
-    def _rows(w, idx, cd):
-        """Embedding-row gather, quantization-aware (per-row scales)."""
-        if isinstance(w, dict):
-            return w["q"][idx].astype(cd) * w["s"][idx][:, None].astype(cd)
-        return w.astype(cd)[idx]
-
-    @staticmethod
-    def _head(x, w, cd):
-        """Tied LM head ``x @ tok_emb.T`` (per-row scales become
-        per-output-column scales of the transpose)."""
-        if isinstance(w, dict):
-            return (x @ w["q"].astype(cd).T) * w["s"].astype(cd)[None, :]
-        return x @ w.astype(cd).T
-
-    def _build_step_core(self):
-        """One fixed-shape decode step for all S slots. Mirrors
-        ``CausalLM._decode_one`` op-for-op (same attention math, same
-        residual association, same masking value) so greedy outputs
-        are token-identical to the solo path — the only difference is
-        that K/V live in paged pools instead of a dense cache. The
-        attention itself (page gather + masked softmax + weighted sum)
-        dispatches through ops/paged_attention_pallas.py: in "xla"
-        mode that is verbatim the einsum pair this core used to
-        inline; on TPU the fused kernel walks the page table without
-        materializing the gathered pages or the logits tensor."""
-        cfg = self.model.cfg
-        cd = self.model._cdtype
-        S, ps = self.slots, self.page_size
-        ln = self.model._ln
-        attn = self._attn_mode
-
-        def step(params, kv, tables, pos, tok, keydata, temps):
-            x = self._rows(params["tok_emb"], tok, cd) \
-                + params["pos_emb"].astype(cd)[pos]
-            # inactive/evicted slots carry all-null tables, so their
-            # writes land on the null page by construction
-            page = tables[jnp.arange(S), pos // ps]
-            off = pos % ps
-            for li, lp in enumerate(params["layers"]):
-                h = ln(x, lp["ln1"])
-                qkv = int8_matmul(h, lp["wqkv"], cd) \
-                    + lp["bqkv"].astype(cd)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                hs = lambda y: y.reshape(S, cfg.n_heads, 1, cfg.head_dim)
-                q, k, v = hs(q), hs(k), hs(v)
-                kv = kv_pages.append_token(
-                    kv, li, page, off, k[:, :, 0], v[:, :, 0])
-                ctx = paged_attention(q, kv, li, tables, pos, mode=attn)
-                ctx = ctx.reshape(S, cfg.d_model)
-                x = x + int8_matmul(ctx, lp["wo"], cd) \
-                    + lp["bo"].astype(cd)
-                h = ln(x, lp["ln2"])
-                x = x + int8_matmul(
-                    jax.nn.gelu(int8_matmul(h, lp["w1"], cd)
-                                + lp["b1"].astype(cd)),
-                    lp["w2"], cd) + lp["b2"].astype(cd)
-            x = ln(x, params["ln_f"])
-            logits = self._head(x, params["tok_emb"], cd) \
-                .astype(jnp.float32)
-            nxt, nkd = self._sample_next(logits, keydata, temps)
-            return kv, nxt, nkd
-
-        return step
-
     @staticmethod
     def _sample_next(logits, keydata, temps):
         """Every slot's next token from its float32 logits (greedy at
@@ -824,12 +719,13 @@ class DecodeEngine:
         nxt = jnp.where(temps > 0, sampled, greedy)
         return nxt, jax.random.key_data(nk[:, 0])
 
-    def _build_model_step_core(self):
-        """The decode step of a model that brings its own block: the
-        model's ``decode_step`` over the cache ``(kv tree, per-slot
-        state)``, then the shared sampling tail. Beside the tokens it
-        returns the model's small per-step counts (the expert layers'
-        assignments, distinct experts, hottest load, of live lanes)."""
+    def _build_step_core(self):
+        """One fixed-shape decode step for all S slots: the model's
+        ``decode_step`` over the cache ``(kv tree, per-slot state)``,
+        then the shared sampling tail. Beside the tokens it returns
+        the model's small per-step counts (an expert model's
+        assignments, distinct experts, hottest load, of live lanes;
+        None where the model counts nothing)."""
         m, ps, attn = self.model, self.page_size, self._attn_mode
 
         def step(params, cache, tables, pos, tok, keydata, temps, active):
@@ -842,137 +738,84 @@ class DecodeEngine:
         return step
 
     @staticmethod
-    def _make_chunk(core, n_steps: int, own_block: bool = False):
+    def _make_chunk(core, n_steps: int):
         """``n_steps`` decode steps fused into one lax.scan program.
         The scheduler guarantees no active request completes mid-chunk
         (chunk <= min remaining), so the slot roster (tables / active /
         temps) is loop-invariant and only the per-token state (pos /
-        tok / keys / pools) carries. A chunk of 1 is the plain step.
-        ``kv`` is whatever the core carries as its cache: the pool's
-        tree, or (``own_block``) the pair of it and the per-slot state;
-        such a core is also told which lanes are live and returns its
-        per-step counts, which come back stacked ``[n_steps, ...]``."""
+        tok / keys / cache) carries. A chunk of 1 is the plain step.
+        The model's per-step counts come back stacked ``[n_steps,
+        ...]``."""
 
-        def chunk(params, kv, tables, pos, active, tok,
+        def chunk(params, cache, tables, pos, active, tok,
                   keydata, temps):
             def body(carry, _):
-                kv, pos, tok, kd = carry
-                if own_block:
-                    kv, nxt, nkd, counts = core(
-                        params, kv, tables, pos, tok, kd, temps, active)
-                else:
-                    kv, nxt, nkd = core(
-                        params, kv, tables, pos, tok, kd, temps)
-                    counts = None
+                cache, pos, tok, kd = carry
+                cache, nxt, nkd, counts = core(
+                    params, cache, tables, pos, tok, kd, temps, active)
                 pos = pos + active.astype(pos.dtype)
                 tok = jnp.where(active, nxt, tok)
-                return (kv, pos, tok, nkd), (nxt, counts)
+                return (cache, pos, tok, nkd), (nxt, counts)
 
-            (kv, pos, tok, kd), (toks, counts) = lax.scan(
-                body, (kv, pos, tok, keydata), None,
+            (cache, pos, tok, kd), (toks, counts) = lax.scan(
+                body, (cache, pos, tok, keydata), None,
                 length=n_steps)
-            if own_block:
-                return kv, toks.T, pos, tok, kd, counts
-            return kv, toks.T, pos, tok, kd
+            return cache, toks.T, pos, tok, kd, counts
 
         return chunk
 
     def _build_prefill_fn(self):
-        """Parallel prefill of one request: batched forward over the
-        padded prompt writes every position's K/V into the slot's
-        pages; returns the last REAL position's logits (the first
-        generated token's distribution). Positions >= t0 see padding
-        but are causally invisible to positions < t0, so the committed
-        K/V and returned logits are exact."""
-        m, ps = self.model, self.page_size
-
-        def prefill(params, kv, prompt, page_row, t0):
-            ks, vs, last = prefill_forward(m, params, prompt, t0)
-            # t0 bounds the REAL positions: an fp8 pool's page scales
-            # are minted from them only, never from padding garbage
-            kv = kv_pages.commit_prefill(
-                kv, ks, vs, page_row, ps, n_valid=t0)
-            return kv, last.astype(jnp.float32)
-
-        return prefill
-
-    def _build_model_prefill_fn(self):
-        """Prefill of one request by a model that brings its own block:
-        its ``prefill`` gives the attention layers' K/V (committed to
-        the slot's pages as ``_build_prefill_fn`` does), the per-slot
-        state at the last REAL position (written into row ``slot`` of
-        the state array, so a reused slot starts from its own prompt),
-        the last real position's logits and the prompt's counts."""
+        """Parallel prefill of one request: the model's ``prefill``
+        over the padded prompt gives every caching layer's K/V, written
+        into the slot's pages, the per-slot state at the last REAL
+        position (written into row ``slot`` of the state array, so a
+        reused slot starts from its own prompt), the last real
+        position's logits (the first generated token's distribution)
+        and the prompt's counts. Positions >= t0 see padding but are
+        causally invisible to positions < t0, so the committed K/V and
+        returned logits are exact."""
         m, ps, attn = self.model, self.page_size, self._attn_mode
 
         def prefill(params, cache, prompt, page_row, t0, slot):
             kv, state = cache
             ks, vs, mine, last, counts = m.prefill(params, prompt, t0,
                                                    mode=attn)
+            # t0 bounds the REAL positions: an fp8 pool's page scales
+            # are minted from them only, never from padding garbage
             kv = kv_pages.commit_prefill(kv, ks, vs, page_row, ps,
                                          n_valid=t0)
-            state = lax.dynamic_update_slice_in_dim(
-                state, mine[:, None].astype(state.dtype), slot, axis=1)
+            if state is not None:
+                state = lax.dynamic_update_slice_in_dim(
+                    state, mine[:, None].astype(state.dtype), slot, axis=1)
             return (kv, state), last.astype(jnp.float32), counts
 
         return prefill
 
     def _build_prefix_prefill_fn(self):
-        """SUFFIX prefill for a warm-prefix admission: forward over the
-        padded new tokens (bucket width ``B``) at absolute positions
-        ``t_start..``, attending through the slot's WHOLE page table —
-        the cached prefix pages are read in place, and only the suffix
-        positions' K/V are computed and scattered (positions past the
-        real prompt write to the null page). ``t_start`` may sit
-        mid-page (copy-on-write divergence, session resume), which the
-        per-position (page, offset) scatter handles for free. The
-        attention dispatches through the SAME paged_attention op as
-        the decode core (queries at consecutive positions ``t_start +
-        i``), so warm greedy outputs stay token-identical to a cold
-        prefill."""
-        cfg = self.model.cfg
-        cd = self.model._cdtype
-        P, ps = self.pages_per_slot, self.page_size
-        ln = self.model._ln
-        attn = self._attn_mode
+        """SUFFIX prefill for a warm-prefix admission: the model's
+        ``paged_rows`` with one lane, over the padded new tokens
+        (bucket width ``B``) at absolute positions ``t_start..``,
+        attending through the slot's WHOLE page table — the cached
+        prefix pages are read in place, and only the suffix positions'
+        K/V are computed and written (positions past the real prompt
+        write to the null page). ``t_start`` may sit mid-page
+        (copy-on-write divergence, session resume), which the
+        per-position (page, offset) write handles for free. The
+        attention is the decode step's own paged_attention, so warm
+        greedy outputs stay token-identical to a cold prefill."""
+        m, ps, attn = self.model, self.page_size, self._attn_mode
 
-        def prefill(params, kv, tokens, table, t_start, t0):
+        def prefill(params, cache, tokens, table, t_start, t0):
+            kv, state = cache
             B = tokens.shape[0]
-            pos = t_start + jnp.arange(B, dtype=jnp.int32)
-            x = params["tok_emb"].astype(cd)[tokens] \
-                + params["pos_emb"].astype(cd)[
-                    jnp.minimum(pos, cfg.max_len - 1)]
-            real = pos < t0
-            chunk = jnp.minimum(pos // ps, P - 1)
-            page = jnp.where(real, table[chunk], 0)
-            off = pos % ps
-            # fp8 scale segments: padded lanes land in trash segment P
-            seg = jnp.where(real, chunk, P)
-            qbase = jnp.reshape(t_start, (1,)).astype(jnp.int32)
-            for li, lp in enumerate(params["layers"]):
-                h = ln(x, lp["ln1"])
-                qkv = h @ lp["wqkv"].astype(cd) + lp["bqkv"].astype(cd)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                hs = lambda y: y.reshape(B, cfg.n_heads, cfg.head_dim)
-                q, k, v = hs(q), hs(k), hs(v)
-                kv = kv_pages.append_suffix(
-                    kv, li, page, off, k, v, chunk=seg, real=real,
-                    table=table)
-                qq = q.transpose(1, 0, 2)[None]        # [1, H, B, hd]
-                ctx = paged_attention(qq, kv, li, table[None], qbase,
-                                      mode=attn)
-                ctx = ctx[0].transpose(1, 0, 2).reshape(B, cfg.d_model)
-                x = x + ctx @ lp["wo"].astype(cd) + lp["bo"].astype(cd)
-                h = ln(x, lp["ln2"])
-                x = x + jax.nn.gelu(
-                    h @ lp["w1"].astype(cd) + lp["b1"].astype(cd)) \
-                    @ lp["w2"].astype(cd) + lp["b2"].astype(cd)
-            x = ln(x, params["ln_f"])
-            logits = (x @ params["tok_emb"].astype(cd).T) \
-                .astype(jnp.float32)
+            real = (t_start + jnp.arange(B, dtype=jnp.int32)) < t0
+            kv, logits = m.paged_rows(
+                params, kv, table[None],
+                jnp.reshape(t_start, (1,)).astype(jnp.int32),
+                tokens[None], real[None], ps, mode=attn)
             last = lax.dynamic_index_in_dim(
-                logits, t0 - 1 - t_start, axis=0, keepdims=False)
-            return kv, last
+                logits[0], t0 - 1 - t_start, axis=0, keepdims=False)
+            return (kv, state), last
 
         return prefill
 
@@ -981,20 +824,15 @@ class DecodeEngine:
         (computed on the lane's own executable stream) into this
         engine's pages. One scatter program per handoff bucket — the
         decode replica pays a page write, never the bucket-padded
-        prefill forward itself.
-
-        The float program's signature is exactly the pre-fp8 one; the
-        fp8 variant takes the true prompt length ``t0`` as one extra
-        traced scalar so the minted page scales ignore the padded
-        tail."""
+        prefill forward itself. ``t0``, the true prompt length, keeps
+        an fp8 pool's minted page scales off the padded tail; a float
+        pool does not read it."""
         ps = self.page_size
-        if not self.kv_dtype:
-            def adopt(kv, ks, vs, page_row):
-                return kv_pages.handoff_commit(kv, ks, vs, page_row, ps)
-        else:
-            def adopt(kv, ks, vs, page_row, t0):
-                return kv_pages.handoff_commit(kv, ks, vs, page_row,
-                                               ps, n_valid=t0)
+
+        def adopt(cache, ks, vs, page_row, t0):
+            kv, state = cache
+            return kv_pages.handoff_commit(kv, ks, vs, page_row, ps,
+                                           n_valid=t0), state
 
         return adopt
 
@@ -1002,16 +840,10 @@ class DecodeEngine:
         """Speculative VERIFY: one fixed-shape dispatch scores the
         pending token plus ``K`` draft tokens per slot — ``W = K + 1``
         consecutive positions ``pos[s]..pos[s]+K`` — through the
-        target model, then accepts the longest draft prefix the target
-        agrees with (spec_decode.accept_tokens). The multi-position
-        machinery is the prefix-prefill suffix path batched over
-        slots: per-lane (page, offset) scatter (kv_pages.append_spec),
-        attention through the slot's whole page table with the SAME
-        paged_attention op the decode core uses (query ``i`` of row
-        ``s`` at absolute position ``pos[s] + i``, causal by the
-        kernel's flat-position mask), DECODE params — the int8 weight
+        model's ``paged_rows`` with the DECODE params (the int8 weight
         read this whole feature exists to amortize happens ONCE for
-        all W positions.
+        all W positions), then accepts the longest draft prefix the
+        target agrees with (spec_decode.accept_tokens).
 
         Rollback is positional only: lanes past the accepted prefix
         wrote K/V at positions ``>= new_pos``, which the flat-position
@@ -1019,66 +851,20 @@ class DecodeEngine:
         (kv_pages.spec_rewind). Greedy rows are token-identical to the
         decode core's by row independence — the same batched-vs-single
         argument the prefix-prefill identity gate already rests on."""
-        cfg = self.model.cfg
-        cd = self.model._cdtype
-        S, ps, P = self.slots, self.page_size, self.pages_per_slot
-        ln = self.model._ln
-        attn = self._attn_mode
-        K = self._spec.k
-        W = K + 1
+        m, ps, attn = self.model, self.page_size, self._attn_mode
+        W = self._spec.k + 1
 
-        def emb_rows(w, idx):
-            # 2-D token-index variant of self._rows (per-row scales)
-            if isinstance(w, dict):
-                return w["q"][idx].astype(cd) \
-                    * w["s"][idx][..., None].astype(cd)
-            return w.astype(cd)[idx]
-
-        def verify(params, kv, tables, pos, active, tok, drafts,
+        def verify(params, cache, tables, pos, active, tok, drafts,
                    n_draft, keydata, temps):
+            kv, state = cache
             toks = jnp.concatenate([tok[:, None], drafts], axis=1)
-            posw = pos[:, None] \
-                + jnp.arange(W, dtype=jnp.int32)[None, :]   # [S, W]
-            x = emb_rows(params["tok_emb"], toks) \
-                + params["pos_emb"].astype(cd)[
-                    jnp.minimum(posw, cfg.max_len - 1)]
             # lane 0 is the pending token (always real while the slot
             # is live); lane i >= 1 is draft i, real up to n_draft.
             # Padded/inactive lanes write to the null page.
             real = (jnp.arange(W, dtype=jnp.int32)[None, :]
                     <= n_draft[:, None]) & active[:, None]
-            chunk = jnp.minimum(posw // ps, P - 1)
-            page = jnp.where(
-                real, jnp.take_along_axis(tables, chunk, axis=1), 0)
-            off = posw % ps
-            seg = jnp.where(real, chunk, P)
-            for li, lp in enumerate(params["layers"]):
-                h = ln(x, lp["ln1"])
-                qkv = int8_matmul(h, lp["wqkv"], cd) \
-                    + lp["bqkv"].astype(cd)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                hs = lambda y: y.reshape(S, W, cfg.n_heads,
-                                         cfg.head_dim)
-                q, k, v = hs(q), hs(k), hs(v)
-                # write BEFORE attending: draft i's scoring must see
-                # the K/V of drafts 1..i-1 written this dispatch
-                kv = kv_pages.append_spec(
-                    kv, li, page, off, k, v, chunk=seg, real=real,
-                    tables=tables)
-                ctx = paged_attention(q.transpose(0, 2, 1, 3), kv, li,
-                                      tables, pos, mode=attn)
-                ctx = ctx.transpose(0, 2, 1, 3) \
-                    .reshape(S, W, cfg.d_model)
-                x = x + int8_matmul(ctx, lp["wo"], cd) \
-                    + lp["bo"].astype(cd)
-                h = ln(x, lp["ln2"])
-                x = x + int8_matmul(
-                    jax.nn.gelu(int8_matmul(h, lp["w1"], cd)
-                                + lp["b1"].astype(cd)),
-                    lp["w2"], cd) + lp["b2"].astype(cd)
-            x = ln(x, params["ln_f"])
-            logits = self._head(x, params["tok_emb"], cd) \
-                .astype(jnp.float32)
+            kv, logits = m.paged_rows(params, kv, tables, pos, toks, real,
+                                      ps, mode=attn)
             out, n_acc, nkd = accept_tokens(logits, drafts, n_draft,
                                             keydata, temps)
             adv = jnp.where(active, n_acc, 0)
@@ -1086,23 +872,20 @@ class DecodeEngine:
             corr = jnp.take_along_axis(
                 out, (n_acc - 1)[:, None], axis=1)[:, 0]
             new_tok = jnp.where(active, corr, tok)
-            return kv, out, adv, new_pos, new_tok, nkd
+            return (kv, state), out, adv, new_pos, new_tok, nkd
 
         return verify
 
     # -------------------------------------------- the cache as one tree
     def _cache(self):
-        """What the decode and prefill programs carry and donate: the
-        pool's tree, paired with the per-slot state where the model
-        has one."""
-        if self._own_block:
-            return (self.pool.tree(), self._state)
-        return self.pool.tree()
+        """What every program of the model carries and donates: the
+        pool's tree paired with the per-slot state (None where the
+        model has none)."""
+        return (self.pool.tree(), self._state)
 
     def _rebind(self, cache) -> None:
-        if self._own_block:
-            cache, self._state = cache
-        self.pool.rebind(cache)
+        kv, self._state = cache
+        self.pool.rebind(kv)
 
     def _count_experts(self, counts: np.ndarray) -> Dict[str, int]:
         """Add one program's counts ``[..., expert layers, 3]``
@@ -1170,16 +953,12 @@ class DecodeEngine:
         i32, u32, f32 = jnp.int32, jnp.uint32, jnp.float32
         sds, _abs = self._sds, self._abstract
         cd = self.model._cdtype
-        cfg = self.model.cfg
         with _telemetry.span("serving_aot_warmup",
                              buckets=len(self.prefill_buckets),
                              chunks=len(self._chunks),
                              engine=self.engine_id,
                              adopted=self._warm.adopted):
-            kv_abs = _abs(self.pool.tree())
             cache_abs = _abs(self._cache())
-            # a model's own prefill is also told its slot
-            slot_arg = (sds((), i32),) if self._own_block else ()
             for k in self._chunks:
                 if ("decode", k) in self._warm:
                     continue
@@ -1195,23 +974,23 @@ class DecodeEngine:
                     ("prefill", b), self._prefill_jit,
                     _abs(self.params), cache_abs, sds((1, b), i32),
                     sds((b // self.page_size,), i32), sds((), i32),
-                    *slot_arg)
+                    sds((), i32))
             for b in self.handoff_buckets:
                 if ("adopt", b) in self._warm:
                     continue
-                kv_sds = sds((cfg.n_layers, 1, cfg.n_heads, b,
-                              cfg.head_dim), cd)
-                extra = ((sds((), i32),) if self.kv_dtype else ())
+                # the lane's K/V stacks, one bucket of the pool's layers
+                L, _, H, _, hd = self.pool.k.shape
+                kv_sds = sds((L, 1, H, b, hd), cd)
                 self._warm.compile(
                     ("adopt", b), self._adopt_jit,
-                    kv_abs, kv_sds, kv_sds,
-                    sds((b // self.page_size,), i32), *extra)
+                    cache_abs, kv_sds, kv_sds,
+                    sds((b // self.page_size,), i32), sds((), i32))
             if self._spec is not None:
                 K = self._spec.k
                 if ("verify", K) not in self._warm:
                     self._warm.compile(
                         ("verify", K), self._verify_jit,
-                        _abs(self._decode_params), kv_abs,
+                        _abs(self._decode_params), cache_abs,
                         sds((S, P), i32), sds((S,), i32),
                         sds((S,), bool), sds((S,), i32),
                         sds((S, K), i32), sds((S,), i32),
@@ -1220,13 +999,14 @@ class DecodeEngine:
                 if ("cow_copy", 0) not in self._warm:
                     self._warm.compile(
                         ("cow_copy", 0), self._copy_jit,
-                        kv_abs, sds((), i32), sds((), i32))
+                        _abs(self.pool.tree()), sds((), i32),
+                        sds((), i32))
                 for b in self.prefill_buckets:
                     if ("prefix_prefill", b) in self._warm:
                         continue
                     self._warm.compile(
                         ("prefix_prefill", b), self._prefix_prefill_jit,
-                        _abs(self.params), kv_abs, sds((b,), i32),
+                        _abs(self.params), cache_abs, sds((b,), i32),
                         sds((P,), i32), sds((), i32), sds((), i32))
 
     # ----------------------------------------------------------- client
@@ -1963,29 +1743,26 @@ class DecodeEngine:
                 page_row = np.zeros((bucket // ps,), np.int32)
                 n_real = min(len(rows), bucket // ps)
                 page_row[:n_real] = rows[:n_real]
-                extra = ((jnp.asarray(t0, jnp.int32),)
-                         if self.kv_dtype else ())
                 kvt = self._warm.run(
                     ("adopt", bucket), self._adopt_fallback,
-                    self.pool.tree(), ks, vs, jnp.asarray(page_row),
-                    *extra)
+                    self._cache(), ks, vs, jnp.asarray(page_row),
+                    jnp.asarray(t0, jnp.int32))
             elif t_start == 0:
                 prompt = np.zeros((1, bucket), np.int32)
                 prompt[0, :t0] = req.prompt
                 page_row = np.zeros((bucket // ps,), np.int32)
                 n_real = min(len(rows), bucket // ps)
                 page_row[:n_real] = rows[:n_real]
-                # a model's own prefill is told its slot (whose state
-                # row it writes) and returns its counts
-                slot = ((jnp.asarray(s, jnp.int32),) if self._own_block
-                        else ())
-                kvt, last, *counts = self._warm.run(
+                # the prefill is told its slot (whose state row it
+                # writes, where there is a state) and returns the
+                # model's counts
+                kvt, last, counts = self._warm.run(
                     ("prefill", bucket), self._prefill_fallback,
                     self.params, self._cache(), jnp.asarray(prompt),
                     jnp.asarray(page_row), jnp.asarray(t0, jnp.int32),
-                    *slot)
-                for c in counts:
-                    sp.set(**self._count_experts(np.asarray(c)))
+                    jnp.asarray(s, jnp.int32))
+                if counts is not None:
+                    sp.set(**self._count_experts(np.asarray(counts)))
             else:
                 # warm path: prefill ONLY the uncached suffix, mid-page
                 # starts included — attention reads the shared prefix
@@ -2003,7 +1780,7 @@ class DecodeEngine:
                 kvt, last = self._warm.run(
                     ("prefix_prefill", bucket),
                     self._prefix_prefill_fallback, self.params,
-                    self.pool.tree(), jnp.asarray(suffix),
+                    self._cache(), jnp.asarray(suffix),
                     jnp.asarray(table), jnp.asarray(t_start, jnp.int32),
                     jnp.asarray(t0, jnp.int32))
             logits = np.asarray(last)
@@ -2125,12 +1902,12 @@ class DecodeEngine:
                                  verify=K):
                 (kvt, out, adv, pos, tok, kd) = self._warm.run(
                     ("verify", K), self._verify_fallback,
-                    self._decode_params, self.pool.tree(), tables,
+                    self._decode_params, self._cache(), tables,
                     jnp.asarray(self._pos), active,
                     jnp.asarray(self._tok), jnp.asarray(drafts),
                     jnp.asarray(n_draft), jnp.asarray(self._keydata),
                     temps)
-            self.pool.rebind(kvt)
+            self._rebind(kvt)
             self.n_dispatches += 1
             self.n_verify_dispatches += 1
             # ONE host sync for the whole burst (np.array copies: _admit
@@ -2261,13 +2038,14 @@ class DecodeEngine:
                 self.n_attended_pages += pages
                 with _telemetry.span("engine.dispatch", k=k, live=live,
                                      ctx_tokens=ctx, ctx_pages=pages):
-                    (kvt, toks, pos, tok, kd, *counts) = self._warm.run(
+                    (kvt, toks, pos, tok, kd, counts) = self._warm.run(
                         ("decode", k), self._decode_fallbacks[k],
                         self._decode_params, self._cache(), tables,
                         pos, active, tok, kd, temps)
                 self._rebind(kvt)
                 chunks.append(toks)
-                expert_counts.extend(counts)
+                if counts is not None:
+                    expert_counts.append(counts)
                 steps += k
                 self.n_dispatches += 1
                 if has_eos or steps >= min_rem \

@@ -72,7 +72,6 @@ from deeplearning4j_tpu.profiler import flight_recorder as _flight
 from deeplearning4j_tpu.profiler import telemetry as _telemetry
 from deeplearning4j_tpu.serving.engine import (
     CapacityRejected, DecodeEngine, ServingRequest, device_sds,
-    prefill_forward,
 )
 
 #: process-wide fleet ordinals — the ``fleet=<id>`` label on the
@@ -288,10 +287,10 @@ class _PrefillLane:
         m = self.model
 
         def lane_prefill(params, prompt, t0):
-            # the ONE shared prefill math (engine.prefill_forward):
-            # lane-served prompts are bit-identical to engine-served
-            # ones by construction, not by parallel maintenance
-            ks, vs, last = prefill_forward(m, params, prompt, t0)
+            # the model's ONE prefill, the engine's own: lane-served
+            # prompts are bit-identical to engine-served ones by
+            # construction, not by parallel maintenance
+            ks, vs, _, last, _ = m.prefill(params, prompt, t0)
             return ks, vs, last.astype(jnp.float32)
 
         return lane_prefill

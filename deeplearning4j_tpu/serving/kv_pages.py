@@ -391,56 +391,14 @@ def append_token(kv, layer: int, page_idx, offset, k, v):
     return out
 
 
-def append_suffix(kv, layer: int, page_idx, offset, k, v, *,
-                  chunk=None, real=None, table=None):
-    """Write a PREFIX-PREFILL suffix's K/V: one lane per suffix
-    position, consecutive positions, padded lanes pointing at the null
-    page. Identical to :func:`append_token` for float pools.
-
-    fp8 pools need page-granular scales over lanes that SHARE pages:
-    ``chunk`` ([B], this lane's table row, or P for padded lanes),
-    ``real`` ([B], lane < true prompt length) and ``table`` ([P], the
-    slot's page table) drive a segment-max absmax per touched page. A
-    page whose offset-0 lane is in this batch mints a fresh scale
-    (exact over every lane it receives here; decode continues it
-    frozen); a page entered mid-way (the resume boundary page, already
-    committed by the prefix-cache hit) keeps its stored scale.
-    """
-    if not _is_fp8(kv):
-        return append_token(kv, layer, page_idx, offset, k, v)
-    P = table.shape[0]
-    seg = chunk  # [B]; padded lanes carry the trash segment P
-    started = jax.ops.segment_max(
-        jnp.where(real & (offset == 0), 1, 0), seg,
-        num_segments=P + 1)[:P] > 0                       # [P]
-    out = dict(kv)
-
-    def one(pool, scales, x):
-        xf = x.astype(jnp.float32)                       # [B, H, hd]
-        am = jnp.where(real[:, None],
-                       jnp.max(jnp.abs(xf), axis=-1), 0.0)
-        am_pg = jax.ops.segment_max(am, seg,
-                                    num_segments=P + 1)[:P]  # [P, H]
-        cur = scales[layer, table]
-        sc_pg = jnp.where(started[:, None],
-                          _precision.fp8_scale(am_pg), cur)
-        sc = jnp.where(real[:, None],
-                       sc_pg[jnp.minimum(chunk, P - 1)], 1.0)
-        q = _precision.quantize_fp8(xf, sc[..., None])
-        return (_write_rows(pool, layer, page_idx, offset, q),
-                scales.at[layer, table].set(sc_pg))
-
-    out["k"], out["k_scale"] = one(kv["k"], kv["k_scale"], k)
-    out["v"], out["v_scale"] = one(kv["v"], kv["v_scale"], v)
-    return out
-
-
 def append_spec(kv, layer: int, page_idx, offset, k, v, *,
                 chunk=None, real=None, tables=None):
-    """Write one VERIFY dispatch's K/V: ``W = k_drafts + 1`` lanes per
-    slot at consecutive positions (``[S, W]`` index arrays, ``[S, W,
-    H, hd]`` values), padded/inactive lanes pointing at the null page.
-    The batched, multi-slot sibling of :func:`append_suffix`.
+    """Write ``W`` consecutive positions' K/V per slot (``[S, W]``
+    index arrays, ``[S, W, H, hd]`` values), padded/inactive lanes
+    pointing at the null page: a VERIFY dispatch's ``W = k_drafts + 1``
+    lanes a slot, or (``S = 1``) the suffix a warm-prefix prefill
+    computes behind its cached pages. :func:`append_token` for float
+    pools.
 
     Rewind contract (speculative decoding): lanes past the accepted
     prefix wrote K/V that the engine's position rollback
@@ -451,10 +409,12 @@ def append_spec(kv, layer: int, page_idx, offset, k, v, *,
 
     fp8 scale composition with that rollback: ``chunk`` ([S, W], the
     lane's table row, or P for padded lanes), ``real`` ([S, W]) and
-    ``tables`` ([S, P]) drive a per-(slot, page) segment-max absmax,
-    exactly :func:`append_suffix` per slot. A page whose offset-0 lane
-    is real in this batch mints a fresh scale; others keep their
-    stored scale. A scale minted partly from later-REJECTED lanes
+    ``tables`` ([S, P]) drive a per-(slot, page) segment-max absmax.
+    A page whose offset-0 lane is real in this batch mints a fresh
+    scale (exact over every lane it receives here; decode continues it
+    frozen); a page entered mid-way (a resume's boundary page, already
+    committed by the prefix-cache hit) keeps its stored scale. A scale
+    minted partly from later-REJECTED lanes
     merely over-covers the values that replace them (bounded
     quantization error, the same ±448 clip bound as
     :func:`append_token`'s one-token mint) — and when the rollback
@@ -562,6 +522,6 @@ def pages_needed(total_positions: int, page_size: int) -> int:
 
 
 __all__ = ["PagePool", "commit_prefill", "append_token",
-           "append_suffix", "append_spec", "spec_rewind",
+           "append_spec", "spec_rewind",
            "gather_pages", "copy_page", "handoff_commit",
            "pages_needed"]

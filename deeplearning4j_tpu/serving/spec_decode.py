@@ -11,8 +11,9 @@ WEIGHT READ. That is what draft–verify buys:
   programs — or any object with a ``propose`` method, e.g. a
   distilled checkpoint);
 - ONE fixed-shape **verify** dispatch scores all ``k+1`` positions
-  through the target model (``engine._build_verify_fn``), reusing the
-  prefix-prefill/suffix machinery: the paged-attention op already
+  through the target model's own ``paged_rows`` (docs/SERVING.md,
+  "What a served model brings"; a suffix prefill behind cached pages
+  is the same method with one lane): the paged-attention op already
   handles multi-position suffix queries against a slot's page table;
 - :func:`accept_tokens` keeps the longest draft prefix the target
   agrees with and emits one correction/bonus token on top, so every

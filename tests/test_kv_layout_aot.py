@@ -76,19 +76,20 @@ def _programs(eng):
     arguments as ``_aot_warmup`` spells them, shapes only)."""
     S, P, kw = eng.slots, eng.pages_per_slot, eng._kd_width
     i32, u32, f32 = jnp.int32, jnp.uint32, jnp.float32
-    kv, dec, par = eng.pool.tree(), eng._decode_params, eng.params
+    # the one cache tree every program carries: (pool tree, state)
+    cache, dec, par = eng._cache(), eng._decode_params, eng.params
     slot = [((S, P), i32), ((S,), i32), ((S,), bool), ((S,), i32)]
     tail = [((S, kw), u32), ((S,), f32)]
     return {
-        "chunk": (eng._decode_jits[CHUNK], [dec, kv, *slot, *tail]),
+        "chunk": (eng._decode_jits[CHUNK], [dec, cache, *slot, *tail]),
         "verify": (eng._verify_jit, [
-            dec, kv, *slot, ((S, SPEC_K), i32), ((S,), i32), *tail]),
+            dec, cache, *slot, ((S, SPEC_K), i32), ((S,), i32), *tail]),
         "suffix_prefill": (eng._prefix_prefill_jit, [
-            par, kv, ((BUCKET,), i32), ((P,), i32), ((), i32),
+            par, cache, ((BUCKET,), i32), ((P,), i32), ((), i32),
             ((), i32)]),
         "prefill": (eng._prefill_jit, [
-            par, kv, ((1, BUCKET), i32),
-            ((BUCKET // eng.page_size,), i32), ((), i32)]),
+            par, cache, ((1, BUCKET), i32),
+            ((BUCKET // eng.page_size,), i32), ((), i32), ((), i32)]),
     }
 
 
@@ -105,8 +106,10 @@ def _compiled_text(jitted, args, one_chip) -> str:
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=row_major(len(shape)))
 
+    # a (shape, dtype) pair is a leaf; the cache's pair is not
     args = jax.tree_util.tree_map(
-        pinned, args, is_leaf=lambda a: isinstance(a, tuple))
+        pinned, args, is_leaf=lambda a: isinstance(a, tuple)
+        and isinstance(a[0], tuple))
     fn = jitted.__wrapped__
     outs = jax.tree_util.tree_map(lambda a: row_major(a.ndim),
                                   jax.eval_shape(fn, *args))

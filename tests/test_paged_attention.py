@@ -434,8 +434,9 @@ class TestFp8Pages:
         np.testing.assert_allclose(np.asarray(deq), 2.0, atol=0.2)
 
     def test_append_suffix_scale_semantics(self):
-        """A page whose offset-0 lane is in the suffix batch mints a
-        fresh scale from the EXACT amax over every lane it receives; a
+        """``append_spec`` with one slot, as a suffix prefill behind
+        cached pages writes: a page whose offset-0 lane is in the
+        suffix batch mints a fresh scale from the EXACT amax over every lane it receives; a
         page entered mid-way (the resume boundary) keeps its stored
         scale; untouched pages and padded lanes change nothing."""
         _, kv = self._pool_kv()
@@ -459,11 +460,12 @@ class TestFp8Pages:
         page = np.where(real, np.asarray(table)[
             np.minimum(padded_pos // ps, P - 1)], 0)
         off = np.where(real, padded_pos % ps, 0)
-        out = kv_pages.append_suffix(
-            kv, 0, jnp.asarray(page, jnp.int32),
-            jnp.asarray(off, jnp.int32), jnp.asarray(ks),
-            jnp.asarray(ks), chunk=jnp.asarray(chunk, jnp.int32),
-            real=jnp.asarray(real), table=table)
+        out = kv_pages.append_spec(
+            kv, 0, jnp.asarray(page, jnp.int32)[None],
+            jnp.asarray(off, jnp.int32)[None], jnp.asarray(ks)[None],
+            jnp.asarray(ks)[None],
+            chunk=jnp.asarray(chunk, jnp.int32)[None],
+            real=jnp.asarray(real)[None], tables=table[None])
         # boundary page keeps its frozen scale; fresh page 3 mints the
         # exact amax over its two lanes (positions 8, 9)
         np.testing.assert_array_equal(
@@ -523,8 +525,8 @@ class TestFp8Pages:
 
 # ------------------------------------------- per-position write sites
 class TestPositionWrites:
-    """kv_pages._write_rows behind its three callers: the same values
-    in the same cells as a plain loop, whatever spells the scatter."""
+    """kv_pages._write_rows behind its callers: the same values in the
+    same cells as a plain loop, whatever spells the scatter."""
 
     L, NP, H, PS, HD, P = 3, 20, 3, 4, 8, 3
     LAYER = 1
@@ -534,10 +536,11 @@ class TestPositionWrites:
         on the null page 0, in the index shapes ``writer`` takes, with
         the fp8 segment arguments consistent with them. Every writer
         is ``rows`` page tables of ``lanes`` positions each: a decode
-        step is one position a slot, a suffix one slot's positions."""
+        step is one position a slot, a suffix prefill ``append_spec``
+        over one slot's positions."""
         P, ps = self.P, self.PS
-        rows, lanes = {"append_token": (6, 1), "append_suffix": (1, 6),
-                       "append_spec": (4, 3)}[writer]
+        rows, lanes = {"append_token": (6, 1), "append_spec": (4, 3),
+                       "append_spec_one_slot": (1, 6)}[writer]
         tables = rng.permutation(np.arange(1, self.NP))[:rows * P] \
             .reshape(rows, P)
         cells = np.stack([rng.permutation(P * ps)[:lanes]
@@ -550,17 +553,13 @@ class TestPositionWrites:
         idx = lambda a: jnp.asarray(a, jnp.int32)
         if writer == "append_token":
             return idx(page[:, 0]), idx(off[:, 0]), real[:, 0], {}
-        if writer == "append_suffix":
-            return (idx(page[0]), idx(off[0]), real[0],
-                    dict(chunk=idx(seg[0]), real=jnp.asarray(real[0]),
-                         table=idx(tables[0])))
         return (idx(page), idx(off), real,
                 dict(chunk=idx(seg), real=jnp.asarray(real),
                      tables=idx(tables)))
 
     @pytest.mark.parametrize("fp8", [False, True], ids=["bf16", "fp8"])
     @pytest.mark.parametrize(
-        "writer", ["append_token", "append_suffix", "append_spec"])
+        "writer", ["append_token", "append_spec_one_slot", "append_spec"])
     def test_matches_numpy_loop(self, writer, fp8):
         rng = np.random.default_rng(7)
         kv = _mk_kv(rng, self.L, self.NP, self.H, self.PS, self.HD,
@@ -570,7 +569,7 @@ class TestPositionWrites:
         page, off, real, extra = self._lanes(rng, writer)
         x = {n: rng.standard_normal(page.shape + (self.H, self.HD))
              .astype(np.float32) * 3 for n in ("k", "v")}
-        out = getattr(kv_pages, writer)(
+        out = getattr(kv_pages, writer.removesuffix("_one_slot"))(
             kv, self.LAYER, page, off, jnp.asarray(x["k"]),
             jnp.asarray(x["v"]), **extra)
         for n in ("k", "v"):

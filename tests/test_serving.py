@@ -532,3 +532,108 @@ class TestServingTelemetry:
             in _DASHBOARD_HTML
         assert "dl4j_tpu_serving_request_latency_seconds" \
             in _DASHBOARD_HTML
+
+
+# ------------------------------------- the engine names no model
+class _ToyLM:
+    """What a served model brings (docs/SERVING.md) and nothing else:
+    one attention layer with names of its own, no norm, no MLP, no
+    ``paged_rows``, no ``quantize_decode_params``."""
+
+    class cfg:
+        max_len = 32
+
+    V, D, H, MAX = 17, 16, 2, cfg.max_len
+    _cdtype = jnp.float32
+
+    def weights(self, key):
+        names = ("book", "where", "ask", "key", "val", "back")
+        shapes = [(self.V, self.D), (self.MAX, self.D)] + 4 * [(self.D,) * 2]
+        return {n: jax.random.normal(k, s, jnp.float32) * 0.5
+                for n, k, s in zip(names, jax.random.split(key, 6), shapes)}
+
+    def cache_spec(self):
+        return {"kv_layers": 1, "kv_heads": self.H,
+                "head_dim": self.D // self.H, "state": None}
+
+    def _qkv(self, w, x):               # [..., t, D] -> 3 x [..., H, t, hd]
+        return (jnp.swapaxes((x @ w[n]).reshape(
+            *x.shape[:-1], self.H, self.D // self.H), -2, -3)
+            for n in ("ask", "key", "val"))
+
+    def dense(self, w, ids):
+        """ids [t] -> (logits [t, V], k, v [H, t, hd]), recomputing
+        everything: the rollout the engine is held to."""
+        t = ids.shape[0]
+        x = w["book"][ids] + w["where"][:t]
+        q, k, v = self._qkv(w, x)
+        s = jnp.einsum("hqd,hkd->hqk", q, k) / (self.D // self.H) ** 0.5
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+        ctx = jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, -1), v)
+        x = x + jnp.swapaxes(ctx, 0, 1).reshape(t, self.D) @ w["back"]
+        return x @ w["book"].T, k, v
+
+    def prefill(self, w, prompt, t0, mode=None):
+        logits, k, v = self.dense(w, prompt[0])
+        return k[None, None], v[None, None], None, logits[t0 - 1], None
+
+    def decode_step(self, w, kv, state, tables, pos, tok, active,
+                    page_size, mode=None):
+        from deeplearning4j_tpu.ops.paged_attention_pallas import \
+            paged_attention
+        from deeplearning4j_tpu.serving import kv_pages
+
+        S = tok.shape[0]
+        x = w["book"][tok] + w["where"][pos]
+        q, k, v = self._qkv(w, x[:, None])            # [S, H, 1, hd]
+        kv = kv_pages.append_token(
+            kv, 0, tables[jnp.arange(S), pos // page_size],
+            pos % page_size, k[:, :, 0], v[:, :, 0])
+        ctx = paged_attention(q, kv, 0, tables, pos, mode=mode)
+        x = x + ctx.reshape(S, self.D) @ w["back"]
+        return kv, None, x @ w["book"].T, None
+
+
+class TestServedModelProtocol:
+    def test_a_model_of_its_own_is_served_token_identically(self):
+        """``cache_spec`` / ``prefill`` / ``decode_step`` are all the
+        engine asks: a model it has never heard of, requests joining
+        and leaving beside each other, each greedy continuation equal
+        to the model's own recompute-everything rollout."""
+        toy = _ToyLM()
+        w = toy.weights(jax.random.key(3))
+        dense = jax.jit(toy.dense)
+
+        def rollout(prompt, new):
+            ids = list(prompt)
+            for _ in range(new):
+                # padded to one width: one trace; causal, so exact
+                pad = np.zeros(toy.MAX, np.int32)
+                pad[:len(ids)] = ids
+                logits, _, _ = dense(w, jnp.asarray(pad))
+                ids.append(int(np.argmax(np.asarray(logits[len(ids) - 1]))))
+            return np.asarray(ids[len(prompt):], np.int32)
+
+        rng = np.random.default_rng(5)
+        work = [(rng.integers(0, toy.V, n).astype(np.int32), new)
+                for n, new in [(3, 9), (11, 5), (6, 14), (1, 7), (9, 12)]]
+        with DecodeEngine(toy, w, slots=2, page_size=4,
+                          max_chunk=4) as eng:
+            reqs = [eng.submit(p, new) for p, new in work]
+            got = [r.result(timeout=120) for r in reqs]
+            assert eng._warm.misses == 0
+        for (p, new), out in zip(work, got):
+            np.testing.assert_array_equal(out, rollout(p, new))
+
+    @pytest.mark.parametrize("option,method", [
+        ({"prefix_cache": True}, "paged_rows"),
+        ({"session_capacity": 2}, "paged_rows"),
+        ({"spec_decode": 2}, "paged_rows"),
+        ({"quantization": "int8"}, "quantize_decode_params")])
+    def test_options_follow_from_what_the_model_brings(self, option,
+                                                       method):
+        toy = _ToyLM()
+        with pytest.raises(ValueError,
+                           match=f"{next(iter(option))}.*{method}"):
+            DecodeEngine(toy, toy.weights(jax.random.key(3)), slots=2,
+                         page_size=4, warm_start=False, **option)

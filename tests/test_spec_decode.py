@@ -6,7 +6,7 @@ sessions resume (with forced rejected-token rewind), prefix-cache CoW
 sharers, per-seed determinism, warm-pool zero-miss / zero-compile
 contracts, and off-mode inertness (spec_decode=None builds nothing)."""
 
-import hashlib
+import re
 import time
 from concurrent.futures import ThreadPoolExecutor
 
@@ -518,21 +518,29 @@ class TestSpecOffMode:
                                                           params):
         """Turning speculation on must not change the plain decode /
         prefill executables at all — same warm-pool keys plus exactly
-        the ("verify", k) addition, and HLO-digest-identical programs
-        for every shared key."""
-        def digests(eng):
-            return {k: hashlib.sha256(
-                ex.as_text().encode()).hexdigest()
-                for k, ex in eng._warm._exec.items()}
+        the ("verify", k) addition, and the same compiled HLO for every
+        shared key. Compared is what a program computes: the header's
+        source-position tables and each instruction's ``metadata`` (a
+        file, line and COLUMN per Python frame, this test's own call
+        sites among them) say where it was traced from, and differ
+        between any two engines built on different lines."""
+        def computes(ex):
+            text = re.sub(r', metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}',
+                          "", ex.as_text())
+            return re.sub(
+                r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)"
+                r"\n(?:.+\n)*\n", "", text, flags=re.M)
 
         kw = dict(slots=2, page_size=PS, max_chunk=4,
                   prefill_buckets=[8, 16])
         off = DecodeEngine(model, params, **kw)
         on = DecodeEngine(model, params, spec_decode=4, **kw)
         with off, on:
-            d_off, d_on = digests(off), digests(on)
-        extra = set(d_on) - set(d_off)
+            p_off = {k: computes(ex) for k, ex in off._warm._exec.items()}
+            p_on = {k: computes(ex) for k, ex in on._warm._exec.items()}
+        extra = set(p_on) - set(p_off)
         assert extra == {("verify", 4)}
-        for k in d_off:
-            assert d_on[k] == d_off[k], \
+        for k, text in p_off.items():
+            assert "ENTRY" in text and "metadata=" not in text
+            assert p_on[k] == text, \
                 f"{k} recompiled differently with spec on"
