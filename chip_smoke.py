@@ -277,9 +277,8 @@ def phase_kernels(cfg, *, slots: int = 8, page_size: int = 16,
     from deeplearning4j_tpu.ops.fused_update_pallas import (
         adam_segment_update,
     )
-    from deeplearning4j_tpu.ops.paged_attention_pallas import (
-        _xla_paged_attention, paged_attention,
-    )
+    from deeplearning4j_tpu.ops.paged_attention_pallas import \
+        paged_attention
 
     H, hd = cfg.n_heads, cfg.head_dim
     P = cfg.max_len // page_size
@@ -303,7 +302,7 @@ def phase_kernels(cfg, *, slots: int = 8, page_size: int = 16,
     # -- paged attention: decode (N=slots, Q=1) + prefix-prefill (N=1)
     def pool(store_dtype, with_scales):
         ks = jax.random.split(jax.random.key(0), 4)
-        shape = (layers, n_pages, H, page_size, hd)
+        shape = (layers, n_pages, page_size, H * hd)
         kv = {"k": jax.random.normal(ks[0], shape).astype(store_dtype),
               "v": jax.random.normal(ks[1], shape).astype(store_dtype)}
         if with_scales:
@@ -313,7 +312,7 @@ def phase_kernels(cfg, *, slots: int = 8, page_size: int = 16,
         return kv
 
     def paged_case(name, kv, n, q_len, qbase, tol):
-        q = jax.random.normal(jax.random.key(7), (n, H, q_len, hd)) \
+        q = jax.random.normal(jax.random.key(7), (n, q_len, H, hd)) \
             .astype(jnp.bfloat16)
         tables = jnp.asarray(
             1 + (np.arange(n * P).reshape(n, P) * 7) % (n_pages - 1),
@@ -321,8 +320,8 @@ def phase_kernels(cfg, *, slots: int = 8, page_size: int = 16,
         qbase = jnp.asarray(qbase, jnp.int32)
         got = jax.jit(lambda q, kv, t, b: paged_attention(
             q, kv, layer, t, b, mode="pallas"))(q, kv, tables, qbase)
-        ref = jax.jit(lambda q, kv, t, b: _xla_paged_attention(
-            q, kv, layer, t, b))(q, kv, tables, qbase)
+        ref = jax.jit(lambda q, kv, t, b: paged_attention(
+            q, kv, layer, t, b, mode="xla"))(q, kv, tables, qbase)
         check(name, got, ref, tol)
 
     kv = pool(jnp.bfloat16, False)

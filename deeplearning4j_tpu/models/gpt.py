@@ -104,12 +104,12 @@ class _Paged:
         self.pages, self.attention = _paged()
 
     def attend(self, li, q, k, v):
+        # a projected row IS a page row: every head side by side
         c, S = self.cfg, q.shape[0]
-        q, k, v = (y.reshape(S, c.n_heads, 1, c.head_dim)
-                   for y in (q, k, v))
         self.kv = self.pages.append_token(self.kv, li, self.page, self.off,
-                                          k[:, :, 0], v[:, :, 0])
-        ctx = self.attention(q, self.kv, li, self.tables, self.pos,
+                                          k, v)
+        ctx = self.attention(q.reshape(S, 1, c.n_heads, c.head_dim),
+                             self.kv, li, self.tables, self.pos,
                              mode=self.mode)
         return ctx.reshape(S, c.d_model)
 
@@ -135,14 +135,13 @@ class _PagedRows:
 
     def attend(self, li, q, k, v):
         c, (S, W) = self.cfg, self.real.shape
-        q, k, v = (y.reshape(S, W, c.n_heads, c.head_dim)
-                   for y in (q, k, v))
         self.kv = self.pages.append_spec(
             self.kv, li, self.page, self.off, k, v, chunk=self.seg,
             real=self.real, tables=self.tables)
-        ctx = self.attention(q.transpose(0, 2, 1, 3), self.kv, li,
-                             self.tables, self.pos, mode=self.mode)
-        return ctx.transpose(0, 2, 1, 3).reshape(S, W, c.d_model)
+        ctx = self.attention(q.reshape(S, W, c.n_heads, c.head_dim),
+                             self.kv, li, self.tables, self.pos,
+                             mode=self.mode)
+        return ctx.reshape(S, W, c.d_model)
 
 
 class CausalLM:

@@ -174,11 +174,13 @@ class _Paged:
 
     def attend(self, ai, q, k, v, pos):
         S, _, H, hd = q.shape
-        self.kv = kv_pages.append_token(self.kv, ai, self.page, self.off,
-                                        k[:, 0], v[:, 0])
-        ctx = paged_attention(q.transpose(0, 2, 1, 3), self.kv, ai,
-                              self.tables, self.pos, mode=self.mode)
-        return ctx.transpose(0, 2, 1, 3).reshape(S, 1, H * hd)
+        # a position's KV heads side by side are a page row
+        self.kv = kv_pages.append_token(
+            self.kv, ai, self.page, self.off, k.reshape(S, -1),
+            v.reshape(S, -1))
+        ctx = paged_attention(q, self.kv, ai, self.tables, self.pos,
+                              mode=self.mode)
+        return ctx.reshape(S, 1, H * hd)
 
 
 class Lfm2MoeLM:

@@ -8,8 +8,8 @@ roofline registry (profiler/programs.py) classifies ``serving_decode``
 as memory-bound: every decode step streams the whole paged KV cache
 through the einsum pair
 
-    logits = einsum("nhqd,nphod->nhqpo", q, gather(kpool, tables))
-    ctx    = einsum("nhqpo,nphod->nhqd", softmax(logits), gather(vpool))
+    logits = einsum("nqhd,npohd->nhqpo", q, gather(kpool, tables))
+    ctx    = einsum("nhqpo,npohd->nqhd", softmax(logits), gather(vpool))
 
 materializing (a) the gathered page copies and (b) the full
 ``[n,h,q,p,o]`` logits tensor in HBM between the two contractions.
@@ -21,20 +21,24 @@ a running max ``m``, running sum ``l`` and context accumulator carried
 in VMEM scratch across the sequence's visits. The logits tensor never
 exists; pages are read once.
 
-Layout contract (kv_pages.py): pools are ``[L, n_pages, Hkv, ps, hd]``
-with page 0 the never-read null page; ``tables`` rows are page ids in
-position order, so flat position ``p*ps + o`` of sequence ``n`` lives
-at ``pool[layer, tables[n, p], :, o]`` and the causal mask is a plain
-``flat <= qpos``. Queries are ``[N, H, Q, hd]`` where query ``i`` of
-sequence ``n`` sits at absolute position ``qbase[n] + i`` — Q=1 with
-per-slot positions for the decode step, N=1 with consecutive suffix
-positions for the prefix-prefill program, Q=k+1 with per-slot positions
-for the speculative verify call.
+Layout contract (kv_pages.py): pools are ``[L, n_pages, ps, Hkv *
+hd]`` with page 0 the never-read null page: a page is ``ps`` rows, a
+row one position's K (or V) with every KV head side by side, head
+``h`` on lanes ``[h * hd, (h + 1) * hd)`` — the projection's own output
+row, and the shape the device keeps row-major at rest, so no program
+copies a pool to hand it over (PERF.md section 6, PR 31). ``tables``
+rows are page ids in position order, so flat position ``p*ps + o`` of
+sequence ``n`` lives at ``pool[layer, tables[n, p], o]`` and the
+causal mask is a plain ``flat <= qpos``. Queries are ``[N, Q, H, hd]``
+where query ``i`` of sequence ``n`` sits at absolute position
+``qbase[n] + i`` — Q=1 with per-slot positions for the decode step,
+N=1 with consecutive suffix positions for the prefix-prefill program,
+Q=k+1 with per-slot positions for the speculative verify call.
 
 The walk (PR 29; PERF.md section 6). A VISIT is one grid step: ``B``
 (8) consecutive table slots of one sequence, every KV head of each
-page in one block ``(1, 1, Hkv, ps, hd)`` — the slab lies contiguous in
-the pool as it rests — as ``B`` K and ``B`` V operands over the same
+page in one block ``(1, 1, ps, Hkv * hd)`` — the page as it lies in the
+pool, no padding — as ``B`` K and ``B`` V operands over the same
 pool array, each with its own index map. The grid is ``(KV head
 blocks, visits)`` and its visit axis is DYNAMIC: ``_live_visits``
 computes, from the scalars the call already has, the pages each
@@ -53,33 +57,46 @@ grid static, ``(N, P / B)``, clamped the index maps and skipped the
 body past the last live page: on the chip its dead steps still cost
 2 us each (GPT-2-large's shapes), 65 of a call's 76 us.
 
-Inside a visit the form follows the static shape, not a switch. Few
-query rows a KV head (``Q * G <= 5``: decode, verify): every head at
-once on the VPU, scores as a broadcast multiply of ``[Hkv, B * ps,
-hd]`` by the query row and a lane reduction, the context as a multiply
-by the weights and a sublane reduction — ``Hkv x B`` products with M <=
-5 on the MXU are latency-bound (the old kernel was 5,120 of them).
-Many rows (a suffix prefill's bucket): the ``dot_general`` pair a head,
-in a loop over the visit's heads, and fewer heads a visit where the
-f32 scratch, the double-buffered pages and a head's scores would pass
-the VMEM budget (``_heads_a_visit``; the head blocks are the grid's
-outer axis). K, V, scores, max, sum and accumulator are f32 in both.
+Inside a visit the heads lie along the lanes, and both products run
+on the MXU a LANE TILE at a time (``_lane_tile``): 128 lanes of whole
+heads (two of 64), a head of whole tiles, or — a row that is neither,
+toy sizes on a CPU — the row. A tile's heads are STACKED on the query
+axis: stacked row ``i`` is query row ``i % rows`` with every lane but
+those of head ``i // rows`` zeroed, so ONE product of the stacked rows
+``[hpt * rows, 128]`` with the tile's keys ``[B * ps, 128]``,
+contracted over the tile's lanes, scores every head of the tile, and
+one product of the weights with the tile's values gives each stacked
+row its head's context on its head's lanes; the other lanes of a row
+are dropped at the end. Both products are batched over the block's
+tiles, and the online softmax between them works on ``[tiles, hpt *
+rows, B * ps]``, a vreg or two a tile at decode. bf16 pages under bf16
+queries go to the MXU as they are stored (their products are exact in
+the f32 it accumulates in; no page is converted), the f32 weights as
+two bf16 halves over one pass of the values; f32 or fp8 pools are
+worked on in f32. Scores, max, sum and accumulator are f32 always. One
+form for one query row a head and for a suffix prefill's bucket: with
+many rows, fewer heads a visit where the scores, the weights and the
+scratch would pass the VMEM budget (``_heads_a_visit``; the head
+blocks are the grid's outer axis, whole lane tiles each). (PR 29's
+kernel kept the heads on a leading axis, scored few rows on the VPU —
+a lane reduction a head and key, an ``exp`` over vregs one lane full —
+and many rows a head at a time on the MXU; the stacked form is 1.9x
+and 3.2x faster at the two cells' decode shapes, PERF.md section 6,
+PR 31.)
 
 Grouped-query attention: the pools hold ``Hkv`` heads and the queries
 ``H = Hkv * G``; query head ``h`` reads KV head ``h // G``. The ``G``
-query heads of a group ride the kernel's QUERY axis (``[N, Hkv, Q * G,
-hd]``, row ``i`` = query ``i // G`` of head ``i % G`` of the group), so
-a page is read once a group; row ``i`` is masked at position ``qbase +
-i // G``. ``G = 1`` is no longer the PR 8 kernel instruction for
-instruction: its one head and one page a grid step are now every head
-and eight pages, and its M = 1 products are VPU reductions (float-
-equivalent, another order of summation).
+query heads of a group ride the kernel's QUERY axis (``[N, Q * G, Hkv
+* hd]``, row ``i`` = query ``i // G`` of head ``i % G`` of each
+group), so a page is read once a group; row ``i`` is masked at
+position ``qbase + i // G``. With ``G = 1`` a decode step's query is
+the projection's row as it stands.
 
 fp8 KV (``kv_dtype="fp8_e4m3"``): the pools store float8_e4m3fn with
 per-page-per-head fp32 scale planes ``[L, n_pages, H]`` beside them;
 the kernel dequantizes each page block in VMEM (one multiply by its
-heads' scales per block) so HBM traffic stays fp8 — the other half of the
-bytes/step reduction.
+heads' scales, each spread over its head's lanes) so HBM traffic stays
+fp8 — the other half of the bytes/step reduction.
 
 Dispatch (``paged_attention_mode()``), mirroring
 ``DL4J_TPU_FUSED_UPDATE``:
@@ -88,12 +105,11 @@ Dispatch (``paged_attention_mode()``), mirroring
   same kernel through the Pallas interpreter (CPU-testable path; what
   the CI token-identity gate runs).
 - ``xla``       — everything else (CPU/GPU, or
-  ``DL4J_TPU_PAGED_ATTN=xla``): the exact einsum pair above, verbatim
-  — the serving engine built in this mode is program-for-program
-  identical to the pre-kernel engine.
+  ``DL4J_TPU_PAGED_ATTN=xla``): the einsum pair above over the page
+  rows — the pre-kernel engine's program.
 
-Numerics: the xla path IS the reference (bit-identical to the decode
-core it replaced). The kernel is float-equivalent but not
+Numerics: the xla path IS the reference (the decode core it
+replaced). The kernel is float-equivalent but not
 bit-identical (online softmax reduces in a different order, f32
 accumulation); the greedy TOKEN-identity gate at f32 — the same
 contract the engine already holds against ``generate()`` — is what
@@ -128,73 +144,89 @@ def paged_attention_mode() -> str:
 
 # ------------------------------------------------------- xla reference
 def _xla_paged_attention(q, kv, layer, tables, qbase, group=1):
-    """The exact einsum pair from the pre-kernel decode core /
-    prefix-prefill program (serving/engine.py PR 8-9 lineage). This is
-    the dispatch target when the kernel is off, so it must stay
-    op-for-op what those programs inlined — the engine's greedy
-    bit-identity to ``CausalLM.generate()`` rests on it."""
-    N, H, Q, hd = q.shape
-    ck = kv["k"][layer][tables]           # [N, P, H, ps, hd]
-    cv = kv["v"][layer][tables]
-    if "k_scale" in kv:
-        cd = q.dtype
-        ck = ck.astype(cd) * kv["k_scale"][layer][tables][
-            ..., None, None].astype(cd)
-        cv = cv.astype(cd) * kv["v_scale"][layer][tables][
-            ..., None, None].astype(cd)
-    P, ps = ck.shape[1], ck.shape[3]
+    """The einsum pair of the pre-kernel decode core / prefix-prefill
+    program (serving/engine.py PR 8-9 lineage) over page rows: ``q`` is
+    ``[N, rows, Hkv, hd]`` (row ``i`` = query ``i // group``). This is
+    the dispatch target when the kernel is off and the tests'
+    reference — the engine's greedy identity to
+    ``CausalLM.generate()`` rests on it."""
+    N, Q, H, hd = q.shape
+
+    def pages(name):                      # [N, P, ps, H, hd]
+        c = kv[name][layer][tables]
+        c = c.reshape(*c.shape[:3], H, hd)
+        if name + "_scale" in kv:
+            sc = kv[name + "_scale"][layer][tables]        # [N, P, H]
+            c = c.astype(q.dtype) \
+                * sc[:, :, None, :, None].astype(q.dtype)
+        return c
+
+    ck, cv = pages("k"), pages("v")
+    P, ps = ck.shape[1], ck.shape[2]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, q.dtype))
     qi = jnp.arange(Q, dtype=jnp.int32)
     if group > 1:
         qi = qi // group
     qpos = qbase[:, None] + qi[None, :]
     # page-major contraction: (p, o) together are the flat key axis
-    logits = jnp.einsum("nhqd,nphod->nhqpo", q, ck) \
+    logits = jnp.einsum("nqhd,npohd->nhqpo", q, ck) \
         .reshape(N, H, Q, P * ps) * scale
     neg = jnp.asarray(jnp.finfo(logits.dtype).min, logits.dtype)
     valid = (jnp.arange(P * ps)[None, None, None, :]
              <= qpos[:, None, :, None])
     logits = jnp.where(valid, logits, neg)
     w = jax.nn.softmax(logits, axis=-1).reshape(N, H, Q, P, ps)
-    return jnp.einsum("nhqpo,nphod->nhqd", w, cv)
+    return jnp.einsum("nhqpo,npohd->nqhd", w, cv)
 
 
 # -------------------------------------------------------------- kernel
 #: page-table slots a visit (one grid step) brings into VMEM
 _PAGES_A_VISIT = 8
-#: at most this many query rows a KV head (``Q * G``) are scored on the
-#: VPU (broadcast multiply + lane reduction), whose time grows with the
-#: rows; more go to the MXU, whose time does not. On a v5e the two
-#: cross between 5 and 8 rows at both cells' widths (PERF.md, PR 29)
-_VPU_ROWS = 5
 #: what a visit's blocks, scratch and temporaries may take of the
 #: scoped VMEM (16 MiB on a v5e): sets the KV heads a visit
 _VMEM_BUDGET = 8 << 20
 
 
-def _heads_a_visit(Hkv, rows, hd, ps, B, itemsize, vpu, fp8):
-    """Most KV heads (a divisor of ``Hkv``) whose visit fits the VMEM
-    budget: double-buffered K and V pages (and, fp8, their scales, a
-    tile a head), the query and output blocks,
-    the f32 scratch, and the f32 working set (every head's keys and
-    values on the VPU path, one head's and its scores and weights on
-    the MXU path). Minor dimensions count as the tiles they occupy
-    (``hd`` to 128 lanes)."""
-    lanes = -(-hd // 128) * 128
+def _lane_tile(W, hd):
+    """Lanes the kernel works on at once: a 128-lane tile of whole
+    heads (two of 64), a head that is whole tiles, or — a row that is
+    neither, toy sizes — the row."""
+    if W % 128 == 0 and 128 % hd == 0:
+        return 128
+    return hd if hd % 128 == 0 else W
+
+
+def _heads_a_visit(Hkv, rows, hd, ps, B, itemsize, q_itemsize, fp8):
+    """Most KV heads (a divisor of ``Hkv`` whose lanes are whole lane
+    tiles) whose visit fits the VMEM budget: double-buffered K and V
+    pages (and, fp8, their scales, a tile a lane tile), the query and
+    output blocks, the f32 scratch, and the working set (the stacked
+    queries, the scores, the weights and their two halves, the
+    products; the keys and values too where they are not fed to the
+    MXU as stored). Rows count as the sublane tiles they occupy; lanes
+    are real, a page row has no padding."""
+    tw = _lane_tile(Hkv * hd, hd)
+    Rp = -(-(tw // hd) * rows // 8) * 8      # stacked query rows a tile
     r8 = -(-rows // 8) * 8
-    T = B * ps
-    pages = 2 * 2 * B * max(ps, 32 // itemsize) * lanes * itemsize
-    if fp8:
-        pages += 2 * 2 * B * 8 * 128 * 4
-    qo = 2 * 2 * r8 * lanes * itemsize
-    scratch = r8 * (lanes + 2 * 128) * 4
-    kv = 2 * T * lanes * 4                   # a head's f32 K and V
-    head = pages + qo + scratch + (kv if vpu else 0)
-    once = 0 if vpu else kv + 2 * r8 * max(T, 128) * 4
-    for h in range(Hkv, 0, -1):
-        if Hkv % h == 0 and h * head + once <= _VMEM_BUDGET:
-            return h
-    return 1
+    T = max(B * ps, 128)
+    as_stored = itemsize == 2 and q_itemsize == 2
+
+    def visit(h):
+        W = h * hd
+        tiles = W // tw
+        pages = 2 * 2 * B * max(ps, 32 // itemsize) * W * itemsize
+        if fp8:
+            pages += 2 * 2 * B * tiles * 8 * 128 * 4
+        qo = 2 * 2 * r8 * W * q_itemsize
+        scratch = tiles * Rp * (tw + 2 * 128) * 4
+        work = tiles * Rp * (4 * T + 3 * tw) * 4
+        if not as_stored:
+            work += 2 * B * ps * W * 4
+        return pages + qo + scratch + work
+
+    fits = [h for h in range(Hkv, 0, -1)
+            if Hkv % h == 0 and (h * hd) % tw == 0]
+    return next((h for h in fits if visit(h) <= _VMEM_BUDGET), fits[-1])
 
 
 def _live_visits(tables, qbase, n_queries, ps, B):
@@ -223,16 +255,27 @@ def _live_visits(tables, qbase, n_queries, ps, B):
 
 
 def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
-            q_ref, *rest, page_size, sm_scale, fp8, group, pages, vpu):
+            q_ref, *rest, page_size, head_dim, sm_scale, fp8, group,
+            pages):
     """One visit (grid step ``g``) of the online-softmax walk: ``pages``
     table slots of sequence ``lane[g]``, every KV head of the block at
-    once. Scratch (m, l, acc) persists across a sequence's consecutive
+    once, the heads along the lanes. The block's ``W`` lanes are
+    ``tiles`` lane tiles of ``hpt`` whole heads each (``_lane_tile``).
+    A tile's heads are STACKED on the query axis: stacked row ``i`` of
+    a tile is query row ``i % rows`` with every lane but those of head
+    ``i // rows`` zeroed, so one product of the stacked rows with the
+    tile's keys, contracted over the tile's lanes, scores every head of
+    the tile, and one product of the weights with the tile's values
+    gives each stacked row its head's context on its head's lanes.
+    Both are batched over the tiles. Scratch (``m``, ``l`` ``[tiles,
+    Rp, 1]``, ``acc`` ``[tiles, Rp, tw]``, ``Rp`` the stacked rows in
+    whole sublane tiles) persists across a sequence's consecutive
     visits; initialized at its first, finalized into the output block
     at its last."""
     from jax.experimental import pallas as pl
 
     del layer_ref, page_ref          # read by the index maps
-    B, ps = pages, page_size
+    B, ps, hd = pages, page_size, head_dim
     k_refs, v_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
     ks_refs = vs_refs = (None,) * B
     if fp8:
@@ -241,107 +284,124 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
     g = pl.program_id(1)
     n, j = lane_ref[g], visit_ref[g]
     qbase, last = qbase_ref[n], last_ref[n]
-    heads, rows = q_ref.shape[1], q_ref.shape[2]
-    T = B * ps
-    # a key at flat position j*T + t is admitted by query row i iff it
-    # is <= qbase[n] + i // G (causal) and lies on a page the sequence
-    # holds (a slot past the last live page names that page again)
-    held = (last + 1) * ps
+    rows = q_ref.shape[1]
+    tiles, Rp, tw = acc_ref.shape
+    hpt, T = tw // hd, B * ps
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    # bf16 queries over bf16 pages go to the MXU as they are stored:
+    # their products are exact in the f32 it accumulates in
+    as_stored = q_ref.dtype == bf16 and k_refs[0].dtype == bf16
+    mm = bf16 if as_stored else f32
+    # Mosaic refuses bf16 operands under a caller's "highest"
+    precision = lax.Precision.DEFAULT if as_stored else None
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full(m_ref.shape, _MASK_MIN, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, _MASK_MIN, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
-    def visit_of(refs, scales, h):
-        """The visit's ``B`` pages end to end in f32 (``ps`` is whole
-        sublane tiles): ``[heads, T, hd]``, or head ``h``'s ``[T, hd]``."""
+    stack = lambda f: jnp.stack([f(c) for c in range(tiles)])
+    sub = lax.broadcasted_iota(jnp.int32, (1, Rp, 1), 1)
+    head_of_lane = lax.broadcasted_iota(jnp.int32, (1, 1, tw), 2) // hd
+
+    def tile_of(refs, scales, c):
+        """Lane tile ``c`` of the visit's ``B`` pages end to end
+        (``ps`` is whole sublane tiles): ``[T, tw]``; an fp8 page in
+        f32, times its heads' scales, each on its head's lanes."""
         out = []
         for ref, scale in zip(refs, scales):
-            x = ref[0, 0, h].astype(jnp.float32)
+            x = ref[0, 0, :, c * tw:(c + 1) * tw].astype(mm)
             if fp8:
-                x = x * scale[0, 0, h]
+                sc = scale[0, 0, c]                        # [1, hpt]
+                lanes = sc[:, 0:1]
+                for h in range(1, hpt):
+                    lanes = jnp.where(head_of_lane[0] == h,
+                                      sc[:, h:h + 1], lanes)
+                x = x * lanes
             out.append(x)
-        return out[0] if B == 1 else jnp.concatenate(out, axis=-2)
+        return out[0] if B == 1 else jnp.concatenate(out, axis=0)
 
-    def fold(at, s, valid, axis, context):
-        """The online-softmax update of the state at ``at`` with the
-        visit's scores ``s`` (keys along ``axis``)."""
-        s = jnp.where(valid, s, _MASK_MIN)
-        m_prev = m_ref[at]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=axis, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit zero for masked keys: an all-masked row would
-        # otherwise contribute exp(MASK_MIN - MASK_MIN) == 1 a key
-        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_ref[at] = alpha * l_ref[at] \
-            + jnp.sum(pexp, axis=axis, keepdims=True)
-        acc_ref[at] = acc_ref[at] * alpha + context(pexp)
-        m_ref[at] = m_new
-
-    if vpu:
-        # few rows a head: products with so small an M keep the MXU
-        # waiting. Every head at once on the VPU: scores as a multiply
-        # by the query row and a lane reduction, the context as a
-        # multiply by the weights and a sublane reduction
-        q = q_ref[0].astype(jnp.float32)                # [heads, rows, hd]
-        k = visit_of(k_refs, ks_refs, slice(None))      # [heads, T, hd]
-        v = visit_of(v_refs, vs_refs, slice(None))
-        pos = j * T + lax.broadcasted_iota(jnp.int32, (1, T, 1), 1)
-        for r in range(rows):
-            row = (slice(None), slice(r, r + 1), slice(None))
-            s = jnp.sum(k * q[row], axis=-1, keepdims=True) * sm_scale
-            valid = pos <= jnp.minimum(qbase + r // group, held - 1)
-            fold(row, s, valid, 1,                      # s [heads, T, 1]
-                 lambda p: jnp.sum(p * v, axis=1, keepdims=True))
+    # the stacked queries [tiles, Rp, tw] (laid out in f32: its masks
+    # are whole sublane tiles)
+    q = stack(lambda c: q_ref[0, :, c * tw:(c + 1) * tw].astype(f32))
+    if rows % 8 == 0:
+        q = jnp.concatenate([q] * hpt, axis=1)
     else:
-        def head(h, carry):
-            q = q_ref[0, h].astype(jnp.float32)         # [rows, hd]
-            k = visit_of(k_refs, ks_refs, h)            # [T, hd]
-            v = visit_of(v_refs, vs_refs, h)
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            # 2-D iotas per the TPU rule
-            qi = lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            pos = j * T + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if group > 1:
-                qi = qi // group    # row i is query i // G of its head
-            valid = (pos <= qbase + qi) & (pos < held)
-            fold(h, s, valid, -1,                       # s [rows, T]
-                 lambda p: lax.dot_general(
-                     p, v, (((1,), (0,)), ((), ())),
-                     preferred_element_type=jnp.float32))
-            return carry
+        q, each = jnp.zeros((tiles, Rp, tw), f32), q
+        for i in range(hpt * rows):
+            q = jnp.where(sub == i, each[:, i % rows:i % rows + 1], q)
+    q = jnp.where(head_of_lane == sub // rows, q, 0.0).astype(mm)
+    k = stack(lambda c: tile_of(k_refs, ks_refs, c))    # [tiles, T, tw]
+    v = stack(lambda c: tile_of(v_refs, vs_refs, c))
 
-        lax.fori_loop(0, heads, head, 0)
+    s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                        precision=precision,
+                        preferred_element_type=f32) * sm_scale
+    # a key at flat position j*T + t is admitted by stacked row i
+    # (query (i % rows) // G of its head) iff it is <= qbase[n] + that
+    # (causal) and lies on a page the sequence holds (a slot past the
+    # last live page names that page again)
+    pos = j * T + lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
+    valid = (pos <= qbase + (sub % rows) // group) \
+        & (pos < (last + 1) * ps)                       # [1, Rp, T]
+    s = jnp.where(valid, s, _MASK_MIN)                  # [tiles, Rp, T]
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # explicit zero for masked keys: an all-masked row would
+    # otherwise contribute exp(MASK_MIN - MASK_MIN) == 1 a key
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    weigh = lambda w: lax.dot_general(
+        w, v, (((2,), (1,)), ((0,), (0,))), precision=precision,
+        preferred_element_type=f32)
+    if as_stored:
+        # the f32 weights as two bf16 halves (16 bits of mantissa
+        # between them), one product over the values as stored
+        hi = p.astype(bf16).astype(f32)
+        both = weigh(jnp.concatenate([hi, p - hi], axis=1).astype(bf16))
+        ctx = both[:, :Rp] + both[:, Rp:]
+    else:
+        ctx = weigh(p)
+    acc_ref[...] = acc_ref[...] * alpha + ctx
 
     @pl.when(j == last // B)
     def _finish():
         # every query admits flat position 0 (qpos >= 0 always), so
         # l >= exp(0) == 1 at the end of the walk: safe division
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        full = acc_ref[...] / l_ref[...]
+        out = full[:, :rows]
+        for h in range(1, hpt):
+            out = jnp.where(head_of_lane == h,
+                            full[:, h * rows:(h + 1) * rows], out)
+        for c in range(tiles):
+            o_ref[0, :, c * tw:(c + 1) * tw] = out[c].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "group"))
 def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
                             group=1):
-    """``layer`` is a traced ``[1]`` array and the function is jitted
-    so that the layers of a program share ONE trace and one lowering of
-    the kernel: traced per layer, its body cost a served model's
-    set-up seconds at every start, warm cache or not (PERF.md, PR 29)."""
+    """``q`` is ``[N, rows, Hkv, hd]`` (row ``i`` = query ``i //
+    group``). ``layer`` is a traced ``[1]`` array and the function is
+    jitted so that the layers of a program share ONE trace and one
+    lowering of the kernel: traced per layer, its body cost a served
+    model's set-up seconds at every start, warm cache or not (PERF.md,
+    PR 29)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    N, H, rows, hd = q.shape           # H KV heads, rows = Q * G
+    N, rows, H, hd = q.shape           # H KV heads, rows = Q * G
     P = tables.shape[1]
-    ps = kv["k"].shape[3]
+    ps = kv["k"].shape[2]
     fp8 = "k_scale" in kv
     B = min(_PAGES_A_VISIT, P)
-    vpu = rows <= _VPU_ROWS
-    Hh = _heads_a_visit(H, rows, hd, ps, B, kv["k"].dtype.itemsize, vpu,
-                        fp8)
+    Hh = _heads_a_visit(H, rows, hd, ps, B, kv["k"].dtype.itemsize,
+                        q.dtype.itemsize, fp8)
+    tw = _lane_tile(H * hd, hd)
+    W, tiles, hpt = Hh * hd, Hh * hd // tw, tw // hd
+    Rp = -(-hpt * rows // 8) * 8
     qbase = qbase.astype(jnp.int32)
     lane, visit, pages, last, total = _live_visits(
         tables.astype(jnp.int32), qbase, rows // group, ps, B)
@@ -353,42 +413,47 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
     # (index_map args: grid indices, then the prefetched scalar refs)
     def page_map(i):
         return lambda h, g, ly, ln, vi, pg, *_: (ly[0], pg[g * B + i],
+                                                 0, h)
+
+    def scale_map(i):
+        return lambda h, g, ly, ln, vi, pg, *_: (ly[0], pg[g * B + i],
                                                  h, 0, 0)
 
-    q_spec = pl.BlockSpec((1, Hh, rows, hd),
-                          lambda h, g, ly, ln, *_: (ln[g], h, 0, 0))
-    kv_specs = [pl.BlockSpec((1, 1, Hh, ps, hd), page_map(i))
+    q_spec = pl.BlockSpec((1, rows, W),
+                          lambda h, g, ly, ln, *_: (ln[g], 0, h))
+    kv_specs = [pl.BlockSpec((1, 1, ps, W), page_map(i))
                 for i in range(B)]
     in_specs = [q_spec] + kv_specs + kv_specs
-    args = [q] + [kv["k"]] * B + [kv["v"]] * B
+    args = [q.reshape(N, rows, H * hd)] + [kv["k"]] * B + [kv["v"]] * B
     if fp8:
-        # one scale per (layer, page, head): viewed as [L, n_pages, H,
-        # 1, 1] so the block's last two dims are the array's (Mosaic
-        # refuses a (1, 1) block over the [n_pages, H] plane itself)
-        sc_specs = [pl.BlockSpec((1, 1, Hh, 1, 1), page_map(i))
+        # one scale per (layer, page, head), viewed a lane tile's heads
+        # a row ``[L, n_pages, H / hpt, 1, hpt]`` so that a block's last
+        # two dims are the array's
+        sc_specs = [pl.BlockSpec((1, 1, tiles, 1, hpt), scale_map(i))
                     for i in range(B)]
         in_specs += sc_specs + sc_specs
-        args += [kv["k_scale"][..., None, None]] * B \
-            + [kv["v_scale"][..., None, None]] * B
+        view = lambda sc: sc.reshape(*sc.shape[:2], H // hpt, 1, hpt)
+        args += [view(kv["k_scale"])] * B + [view(kv["v_scale"])] * B
     kernel = functools.partial(
-        _kernel, page_size=ps,
+        _kernel, page_size=ps, head_dim=hd,
         sm_scale=float(1.0 / np.sqrt(np.float32(hd))), fp8=fp8,
-        group=group, pages=B, vpu=vpu)
-    return pl.pallas_call(
+        group=group, pages=B)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=grid,
             in_specs=in_specs,
             out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((Hh, rows, 1), jnp.float32),
-                            pltpu.VMEM((Hh, rows, 1), jnp.float32),
-                            pltpu.VMEM((Hh, rows, hd), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((N, H, rows, hd), q.dtype),
+            scratch_shapes=[pltpu.VMEM((tiles, Rp, 1), jnp.float32),
+                            pltpu.VMEM((tiles, Rp, 1), jnp.float32),
+                            pltpu.VMEM((tiles, Rp, tw), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((N, rows, H * hd), q.dtype),
         interpret=interpret,
         # what a device trace calls the kernel (PERF.md section 3)
         name="paged_attention",
     )(layer, lane, visit, pages, qbase, last, *args)
+    return out.reshape(N, rows, H, hd)
 
 
 # ------------------------------------------------------------ dispatch
@@ -397,34 +462,40 @@ def paged_attention(q, kv, layer, tables, qbase, *, mode=None):
 
     Parameters
     ----------
-    q : ``[N, H, Q, hd]`` queries in the compute dtype.
+    q : ``[N, Q, H, hd]`` queries in the compute dtype: a position's
+        projected row, split into its heads.
     kv : the page-pool tree (``kv_pages.PagePool.tree()``): ``"k"`` /
-        ``"v"`` pools ``[L, n_pages, Hkv, ps, hd]``, plus ``"k_scale"``
-        / ``"v_scale"`` planes ``[L, n_pages, Hkv]`` when the pool is
-        fp8. ``H`` is a multiple of ``Hkv`` (module docstring).
-    layer : static layer index (the engine's layer loop is unrolled).
+        ``"v"`` pools ``[L, n_pages, ps, Hkv * hd]``, plus
+        ``"k_scale"`` / ``"v_scale"`` planes ``[L, n_pages, Hkv]`` when
+        the pool is fp8. ``H`` is a multiple of ``Hkv`` (module
+        docstring).
+    layer : the pool's layer, a Python int. The kernel takes it as a
+        prefetched scalar, so a program's layers share one trace of it.
     tables : ``[N, P]`` int32 page tables, rows in position order.
     qbase : ``[N]`` int32; query ``i`` of row ``n`` sits at absolute
         position ``qbase[n] + i``.
     mode : overrides :func:`paged_attention_mode` (tests/benches).
 
-    Returns ``[N, H, Q, hd]`` context in ``q.dtype``.
+    Returns ``[N, Q, H, hd]`` context in ``q.dtype``.
     """
     mode = mode or paged_attention_mode()
     if mode not in ("xla", "pallas", "interpret"):
         raise ValueError(
             f"unknown paged-attention mode {mode!r} (expected 'pallas',"
             " 'interpret' or 'xla')")
-    N, H, Q, hd = q.shape
-    Hkv = kv["k"].shape[2]
-    G, rem = divmod(H, Hkv)
-    if rem:
-        raise ValueError(f"{H} query heads do not divide into the "
-                         f"pool's {Hkv} KV heads")
+    N, Q, H, hd = q.shape
+    W = kv["k"].shape[3]
+    Hkv = W // hd
+    if W % hd or not Hkv or H % Hkv:
+        raise ValueError(
+            f"{H} query heads of width {hd} do not divide into the "
+            f"pool's rows of {W}")
+    G = H // Hkv
     if G > 1:
-        # the group's heads onto the query axis, query-major
-        q = q.reshape(N, Hkv, G, Q, hd).transpose(0, 1, 3, 2, 4) \
-             .reshape(N, Hkv, Q * G, hd)
+        # the group's heads onto the query axis, query-major: row
+        # ``i`` is query ``i // G`` of head ``i % G`` of its group
+        q = q.reshape(N, Q, Hkv, G, hd).transpose(0, 1, 3, 2, 4) \
+             .reshape(N, Q * G, Hkv, hd)
     if mode == "xla":
         out = _xla_paged_attention(q, kv, layer, tables, qbase, G)
     else:
@@ -432,8 +503,8 @@ def paged_attention(q, kv, layer, tables, qbase, *, mode=None):
             q, kv, jnp.full((1,), layer, jnp.int32), tables, qbase,
             interpret=(mode == "interpret"), group=G)
     if G > 1:
-        out = out.reshape(N, Hkv, Q, G, hd).transpose(0, 1, 3, 2, 4) \
-                 .reshape(N, H, Q, hd)
+        out = out.reshape(N, Q, G, Hkv, hd).transpose(0, 1, 3, 2, 4) \
+                 .reshape(N, Q, H, hd)
     return out
 
 

@@ -975,12 +975,13 @@ class DecodeEngine:
                     _abs(self.params), cache_abs, sds((1, b), i32),
                     sds((b // self.page_size,), i32), sds((), i32),
                     sds((), i32))
+            spec = self.model.cache_spec()
             for b in self.handoff_buckets:
                 if ("adopt", b) in self._warm:
                     continue
                 # the lane's K/V stacks, one bucket of the pool's layers
-                L, _, H, _, hd = self.pool.k.shape
-                kv_sds = sds((L, 1, H, b, hd), cd)
+                kv_sds = sds((spec["kv_layers"], 1, spec["kv_heads"], b,
+                              spec["head_dim"]), cd)
                 self._warm.compile(
                     ("adopt", b), self._adopt_jit,
                     cache_abs, kv_sds, kv_sds,

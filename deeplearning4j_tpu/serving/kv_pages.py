@@ -7,7 +7,9 @@ allocation and a fresh executable. The serving engine instead owns ONE
 pool of fixed-size pages shared by every slot:
 
 - ``kv tree``: ``{"k", "v"}`` pools of shape
-  ``[L, n_pages, H, page_size, hd]``, allocated once at engine
+  ``[L, n_pages, page_size, H * hd]`` (a page is ``page_size`` rows,
+  a row one position's K or V, every KV head side by side: the
+  projection's own output row), allocated once at engine
   startup, plus — when the pool is fp8-quantized — ``"k_scale"`` /
   ``"v_scale"`` per-page-per-head fp32 scale planes ``[L, n_pages,
   H]`` stored beside them. Page 0 is the NULL page — a scratch target
@@ -48,29 +50,31 @@ garbage, exactly like their page contents). Scale planes initialize
 to ONES so the zero-filled pools round-trip exactly and no division
 ever sees zero.
 
-Layout contract (PR 27): a write into the pools keeps them in the
-paged kernel's layout. The Mosaic kernel
-(ops/paged_attention_pallas.py) takes the pools row-major,
-``{4,3,2,1,0}``. A scatter whose update window is ``[H, hd]`` with the
-indexed ``page_size`` dimension BETWEEN the two — what
-``pool.at[layer, page, :, offset].set(x)`` spells — is given the
-layout ``{4,2,3,1,0}`` by XLA's layout assignment, and with it the
-whole decode loop's carry; XLA then copies each whole pool, every
-layer of every step, to hand the kernel its operand (72 copies of 379
-MB a step at GPT-2-large, 72.5% of device time). So a per-position
-write keeps its update window to the trailing ``hd`` (every other
-index spelled out: ``_write_rows``, the one such site) or is a
-``lax.dynamic_update_slice``; whole-page writes (``commit_prefill``,
-``copy_page``: window ``[H, ps, hd]``, nothing indexed inside it) are
-row-major as they stand. ``tests/test_kv_layout_aot.py`` compiles the
-serving programs for a described v5e and fails on any pool-shaped
-``copy``; it needs no chip. What is left is at the programs' boundary,
-not in any write: at rest a v5e gives this shape (``hd`` 64, half a
-lane tile) the layout ``{1,4,3,2,0}``, so each program re-lays both
-pools out once at entry and once at exit. Pinning them row-major
-there (``jax.experimental.layout``) compiles copy-free, but an
+Layout contract (PR 27, PR 31): the pools rest in the layout the
+programs use, so no program copies one. A page is ``[page_size, H *
+hd]``: with the minor dimension whole lane tiles (1,280 lanes at
+GPT-2-large, 512 at LFM2-24B-A2B) the device keeps the array
+row-major at rest, which is also how the Mosaic kernel
+(ops/paged_attention_pallas.py) and the writes here take it. (The
+page this file had before, ``[H, page_size, hd]`` with ``hd`` 64 half
+a lane tile, rested with the PAGE dimension minor-most, and every
+program re-laid both pools out at entry and at exit: four copies of
+379 MB a dispatch, 26% of the GPT-2 serving window; PERF.md section 6,
+PR 31. A row narrower than 128 lanes still rests otherwise: toy sizes
+only.) Inside a program a write must keep that layout too: a scatter
+whose update window has an INDEXED dimension between its own is given
+another layout by XLA, and with it the whole decode loop's carry (72
+copies of a pool a step, PR 27). So a per-position write indexes
+``[layer, page, offset]`` and its update window is the trailing row,
+one contiguous ``H * hd`` run (``_write_rows``, the one such site);
+whole-page writes (``commit_prefill``, ``copy_page``: window
+``[page_size, H * hd]``, nothing indexed inside it) are row-major as
+they stand. Nothing is pinned (``jax.experimental.layout``): an
 executable loaded back from the persistent compilation cache comes
-without its pinned layouts (jax 0.9.0; PERF.md section 6, PR 27).
+without its pinned layouts (jax 0.9.0). ``tests/test_kv_layout_aot.py``
+compiles the serving programs for a described v5e with the layouts the
+arrays have at rest and fails on any pool-shaped ``copy``; it needs no
+chip.
 
 The jax functions here are pure and shape-static, so the engine's one
 decode executable serves every mix of request lengths.
@@ -124,7 +128,9 @@ class PagePool:
                  else jnp.dtype(dtype))
         #: ``kv_dtype=`` label value on every SERVING_KV_* series
         self.dtype_label = self.kv_dtype or jnp.dtype(dtype).name
-        shape = (n_layers, n_pages, n_heads, page_size, head_dim)
+        # a page row is every KV head side by side, head ``h`` on
+        # lanes ``[h * hd, (h + 1) * hd)`` (module docstring)
+        shape = (n_layers, n_pages, page_size, n_heads * head_dim)
         # allocated where they live (device=None is the default
         # device): a pool must never pass through another chip's HBM
         self.k = jnp.zeros(shape, store, device=device)
@@ -303,7 +309,9 @@ def commit_prefill(kv, ks, vs, page_row, page_size: int, n_valid=None):
 
     ``ks``/``vs``: ``[L, 1, H, B, hd]`` from the parallel-prefill
     forward over the padded prompt (bucket width ``B``, a multiple of
-    ``page_size``). ``page_row``: ``[B // page_size]`` page ids — real
+    ``page_size``), laid into page rows ``[L, B // page_size,
+    page_size, H * hd]`` here. ``page_row``: ``[B // page_size]`` page
+    ids — real
     pages for chunks the slot owns, null page 0 for the padded tail
     (garbage written there is never read: positions beyond the true
     prompt length stay masked until the decode loop overwrites them).
@@ -316,52 +324,57 @@ def commit_prefill(kv, ks, vs, page_row, page_size: int, n_valid=None):
     """
     L, one, H, B, hd = ks.shape
     pb = B // page_size
-    ck = ks[:, 0].reshape(L, H, pb, page_size, hd).transpose(0, 2, 1, 3, 4)
-    cv = vs[:, 0].reshape(L, H, pb, page_size, hd).transpose(0, 2, 1, 3, 4)
+    # [L, 1, H, B, hd] -> [L, pb, ps, H * hd]: position-major, the
+    # heads along the row
+    rows = lambda c: c[:, 0].transpose(0, 2, 1, 3).reshape(
+        L, pb, page_size, H * hd)
+    ck, cv = rows(ks), rows(vs)
     out = dict(kv)
     if not _is_fp8(kv):
         out["k"] = kv["k"].at[:, page_row].set(ck.astype(kv["k"].dtype))
         out["v"] = kv["v"].at[:, page_row].set(cv.astype(kv["v"].dtype))
         return out
 
-    def amax(c):  # [L, pb, H, ps, hd] -> [L, pb, H]
-        a = jnp.abs(c.astype(jnp.float32))
+    def one(c):  # -> quantized rows, scales [L, pb, H]
+        ch = _by_head(c.astype(jnp.float32), H)   # [L, pb, ps, H, hd]
+        a = jnp.abs(ch)
         if n_valid is not None:
             flat = (jnp.arange(pb)[:, None] * page_size
                     + jnp.arange(page_size)[None, :])
-            mask = (flat < n_valid)[None, :, None, :, None]
-            a = jnp.where(mask, a, 0.0)
-        return jnp.max(a, axis=(3, 4))
+            a = jnp.where((flat < n_valid)[None, :, :, None, None], a, 0.0)
+        sc = _precision.fp8_scale(jnp.max(a, axis=(2, 4)))
+        return _precision.quantize_fp8(
+            ch, sc[:, :, None, :, None]).reshape(c.shape), sc
 
-    ksc = _precision.fp8_scale(amax(ck))
-    vsc = _precision.fp8_scale(amax(cv))
-    out["k"] = kv["k"].at[:, page_row].set(
-        _precision.quantize_fp8(ck, ksc[..., None, None]))
-    out["v"] = kv["v"].at[:, page_row].set(
-        _precision.quantize_fp8(cv, vsc[..., None, None]))
+    (qk, ksc), (qv, vsc) = one(ck), one(cv)
+    out["k"] = kv["k"].at[:, page_row].set(qk)
+    out["v"] = kv["v"].at[:, page_row].set(qv)
     out["k_scale"] = kv["k_scale"].at[:, page_row].set(ksc)
     out["v_scale"] = kv["v_scale"].at[:, page_row].set(vsc)
     return out
 
 
 def _write_rows(pool, layer: int, page_idx, offset, x):
-    """``pool[layer, page_idx[i], :, offset[i]] = x[i]`` for every lane
+    """``pool[layer, page_idx[i], offset[i]] = x[i]`` for every lane
     ``i`` of ``page_idx`` / ``offset`` (``[S]``, or ``[S, W]`` for a
-    verify dispatch; ``x`` is ``[..., H, hd]``), cast to the pool's
-    dtype. THE per-position write into a pool: the head index is
-    spelled out beside the page and the offset, so the scatter's update
-    window is the trailing ``hd`` alone and the pool keeps the
-    row-major layout the paged kernel reads (module docstring, "Layout
-    contract")."""
-    heads = jnp.arange(pool.shape[2], dtype=page_idx.dtype)
-    return pool.at[layer, page_idx[..., None], heads,
-                   offset[..., None]].set(x.astype(pool.dtype))
+    verify dispatch; ``x`` is ``[..., H * hd]``, a position's whole
+    row), cast to the pool's dtype. THE per-position write into a
+    pool: layer, page and offset are all indexed, so the scatter's
+    update window is the trailing row alone, one contiguous run, and
+    the pool keeps the row-major layout it rests in and the paged
+    kernel reads (module docstring, "Layout contract")."""
+    return pool.at[layer, page_idx, offset].set(x.astype(pool.dtype))
+
+
+def _by_head(x, heads: int):
+    """A row ``[..., H * hd]`` as its heads ``[..., H, hd]``."""
+    return x.reshape(*x.shape[:-1], heads, x.shape[-1] // heads)
 
 
 def append_token(kv, layer: int, page_idx, offset, k, v):
-    """Write one DECODE position's K/V per lane: lane ``s`` lands at
-    ``(layer, page_idx[s], :, offset[s])``. Inactive slots' page_idx
-    must already point at the null page.
+    """Write one DECODE position's K/V rows ``[S, H * hd]``, one per
+    lane: lane ``s`` lands at ``(layer, page_idx[s], offset[s])``.
+    Inactive slots' page_idx must already point at the null page.
 
     fp8 scale rule (frozen-at-page-start): a lane writing ``offset ==
     0`` is the first entry of a fresh page and mints the page's scale
@@ -379,11 +392,12 @@ def append_token(kv, layer: int, page_idx, offset, k, v):
     fresh = (offset == 0)[:, None]
 
     def one(pool, scales, x):
-        xf = x.astype(jnp.float32)                       # [S, H, hd]
+        xf = _by_head(x.astype(jnp.float32), scales.shape[-1])
         cand = _precision.fp8_scale(jnp.max(jnp.abs(xf), axis=-1))
         sc = jnp.where(fresh, cand, scales[layer, page_idx])  # [S, H]
-        q = _precision.quantize_fp8(xf, sc[..., None])
-        return (_write_rows(pool, layer, page_idx, offset, q),
+        q = _precision.quantize_fp8(xf, sc[..., None])   # [S, H, hd]
+        return (_write_rows(pool, layer, page_idx, offset,
+                            q.reshape(x.shape)),
                 scales.at[layer, page_idx].set(sc))
 
     out["k"], out["k_scale"] = one(kv["k"], kv["k_scale"], k)
@@ -394,7 +408,7 @@ def append_token(kv, layer: int, page_idx, offset, k, v):
 def append_spec(kv, layer: int, page_idx, offset, k, v, *,
                 chunk=None, real=None, tables=None):
     """Write ``W`` consecutive positions' K/V per slot (``[S, W]``
-    index arrays, ``[S, W, H, hd]`` values), padded/inactive lanes
+    index arrays, ``[S, W, H * hd]`` rows), padded/inactive lanes
     pointing at the null page: a VERIFY dispatch's ``W = k_drafts + 1``
     lanes a slot, or (``S = 1``) the suffix a warm-prefix prefill
     computes behind its cached pages. :func:`append_token` for float
@@ -439,8 +453,8 @@ def append_spec(kv, layer: int, page_idx, offset, k, v, *,
     out = dict(kv)
 
     def one(pool, scales, x):
-        xf = x.astype(jnp.float32)                     # [S, W, H, hd]
-        H = xf.shape[2]
+        H = scales.shape[-1]
+        xf = _by_head(x.astype(jnp.float32), H)        # [S, W, H, hd]
         am = jnp.where(real[..., None],
                        jnp.max(jnp.abs(xf), axis=-1), 0.0)  # [S, W, H]
         am_pg = jax.ops.segment_max(
@@ -453,7 +467,8 @@ def append_spec(kv, layer: int, page_idx, offset, k, v, *,
             sc_pg, jnp.minimum(chunk, P - 1)[..., None], axis=1)
         sc = jnp.where(real[..., None], sc, 1.0)           # [S, W, H]
         q = _precision.quantize_fp8(xf, sc[..., None])
-        return (_write_rows(pool, layer, page_idx, offset, q),
+        return (_write_rows(pool, layer, page_idx, offset,
+                            q.reshape(x.shape)),
                 scales.at[layer, tables].set(sc_pg))
 
     out["k"], out["k_scale"] = one(kv["k"], kv["k_scale"], k)
@@ -474,9 +489,9 @@ def spec_rewind(pos, n_acc):
 
 
 def gather_pages(pool, layer: int, tables) -> jnp.ndarray:
-    """Each slot's pages in page-major layout ``[S, P, H, ps, hd]``:
+    """Each slot's pages in page-major layout ``[S, P, ps, H * hd]``:
     flat position ``p*page_size + o`` of slot ``s`` lives at
-    ``[s, p, :, o]`` (table row order IS position order — what makes
+    ``[s, p, o]`` (table row order IS position order — what makes
     the position mask a plain ``<= pos``). Kept page-major so the
     attention einsums contract ``(p, o)`` directly instead of paying a
     transpose+reshape copy of the whole cache per layer per step."""

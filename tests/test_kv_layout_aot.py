@@ -2,20 +2,32 @@
 (serving/kv_pages.py module docstring, "Layout contract").
 
 The serving programs are compiled for a DESCRIBED TPU v5e (libtpu's
-compiler, no device attached) with the pools row-major at entry and
-exit, and the optimised HLO is searched for a ``copy`` whose result
-has the pool's shape. (On the chip the pools rest in the device's own
-layout for their shape, and every program holds four more copies at
-its boundary; they are no write's doing and not this file's subject.) The paged kernel takes its
-operands row-major; a write whose update window is wider than the
-trailing ``hd`` makes XLA keep the pools in another layout and copy
-each whole pool, every layer, to bridge the two (PERF.md section 6,
-PR 27: 72 copies of 379 MB a decode step). The kernel's grid is (KV
-head blocks, live visits): a visit holds every KV head of eight pages
-of one sequence, and the visits are as many as the pages the
-sequences hold (PR 29); the last tests compile it at the cells' shapes. The control case compiles
-the same program around that old write and must find those copies —
-it proves the search can see the fault.
+compiler, no device attached) and the optimised HLO is searched for a
+``copy`` whose result has the pool's shape. Two ways a pool gets
+copied, both found on the chip first (PERF.md section 6):
+
+- *Inside a program* (PR 27: 72 copies of 379 MB a decode step). The
+  paged kernel takes its operands row-major; a write whose update
+  window has an indexed dimension between its own makes XLA keep the
+  pools in another layout and copy each whole pool, every layer, to
+  bridge the two. The ``pinned`` cases hold every array row-major at
+  entry and exit and so see the writes alone; the control compiles
+  the same program around such a write and must find those copies —
+  it proves the search can see the fault.
+- *At a program's boundary* (PR 31: four copies a dispatch, 26% of the
+  GPT-2 serving window). At rest an array has the device's own layout
+  for its shape; where that is not the one the program computes in,
+  the program re-lays the pool out at entry and at exit. The
+  ``at_rest`` cases pin nothing, so the compiler gives every argument
+  and result the layout it has at rest: a page ``[page_size, H * hd]``
+  whose rows are whole lane tiles rests row-major and is never copied;
+  the control finds the four copies around the page this repo had
+  before, ``[H, page_size, hd]`` with ``hd`` half a lane tile.
+
+The kernel's grid is (KV head blocks, live visits): a visit holds every
+KV head of eight pages of one sequence, the heads along the lanes, and
+the visits are as many as the pages the sequences hold (PR 29); the
+last tests compile it at the cells' shapes.
 
 The topology is described inside a fixture, never at import (one
 process a time may load libtpu; xdist workers all import this file).
@@ -93,25 +105,29 @@ def _programs(eng):
     }
 
 
-def _compiled_text(jitted, args, one_chip) -> str:
+def _compiled_text(jitted, args, one_chip, layouts) -> str:
     """Optimised HLO of ``jitted`` (KV tree donated, as the engine
-    does) for the described chip, every array row-major in and out."""
+    does) for the described chip: ``pinned``, every array row-major in
+    and out; ``at_rest``, every array in the layout the device gives
+    its shape, as the engine's arrays are between programs."""
     from jax.experimental.layout import Format, Layout
 
-    def row_major(ndim):
+    def placed(ndim):
+        if layouts == "at_rest":
+            return one_chip
         return Format(Layout(tuple(range(ndim))), one_chip)
 
-    def pinned(a):
+    def abstract(a):
         shape, dtype = (a.shape, a.dtype) if hasattr(a, "shape") else a
         return jax.ShapeDtypeStruct(shape, dtype,
-                                    sharding=row_major(len(shape)))
+                                    sharding=placed(len(shape)))
 
     # a (shape, dtype) pair is a leaf; the cache's pair is not
     args = jax.tree_util.tree_map(
-        pinned, args, is_leaf=lambda a: isinstance(a, tuple)
+        abstract, args, is_leaf=lambda a: isinstance(a, tuple)
         and isinstance(a[0], tuple))
     fn = jitted.__wrapped__
-    outs = jax.tree_util.tree_map(lambda a: row_major(a.ndim),
+    outs = jax.tree_util.tree_map(lambda a: placed(a.ndim),
                                   jax.eval_shape(fn, *args))
     return jax.jit(fn, donate_argnums=(1,), out_shardings=outs) \
         .lower(*args).compile().as_text()
@@ -132,35 +148,139 @@ def pool_copies(hlo: str, pool) -> dict:
             "all": len(pat.findall(hlo))}
 
 
+@pytest.mark.parametrize("layouts", ["pinned", "at_rest"])
 @pytest.mark.parametrize("kv_dtype", [None, "fp8_e4m3"],
                          ids=["bf16", "fp8"])
 @pytest.mark.parametrize(
     "program", ["chunk", "verify", "suffix_prefill", "prefill"])
 def test_serving_program_never_copies_a_pool(one_chip, program,
-                                             kv_dtype):
+                                             kv_dtype, layouts):
+    """Neither a write inside the program (``pinned``) nor the
+    program's boundary (``at_rest``) copies a pool. The fp8 pools
+    (1-byte elements, tiles of 32 sublanes, pages of 16 rows) are found
+    copy-free too."""
     eng = _engine(kv_dtype)
-    assert eng.pool.k.ndim == 5
+    assert eng.pool.k.ndim == 4
     jitted, args = _programs(eng)[program]
-    hlo = _compiled_text(jitted, args, one_chip)
+    hlo = _compiled_text(jitted, args, one_chip, layouts)
     assert "tpu_custom_call" in hlo or program == "prefill"
     assert pool_copies(hlo, eng.pool.k) == {"loop": 0, "all": 0}
 
 
-def test_detector_sees_the_old_write(one_chip, monkeypatch):
-    """The control: the chunk program around the write this repo had
-    before PR 27 (update window ``[H, hd]``, the indexed ``page_size``
-    between them) holds one copy per pool per layer in its loop."""
+# ------------------------------------------- the controls: the old page
+#: the page this repo had until PR 31, at the test engine's sizes:
+#: layers, pages, KV heads, page size, head width
+OLD_POOL = (LAYERS, 1 + SLOTS * 64, 4, 16, 64)
+
+
+def _old_page_program(write):
+    """What a decode chunk did to pools of the old page ``[H, ps,
+    hd]``: every step of a loop, every layer, ``write`` puts a
+    position's K and V into both pools and a Mosaic kernel reads page
+    blocks ``(1, 1, H, ps, hd)`` of them through a prefetched layer,
+    as the paged kernel of PR 29 did. Returns the jitted program and
+    its abstract arguments."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    L, n_pages, H, ps, hd = OLD_POOL
+
+    def read(k, v, layer):
+        def kernel(layer_ref, k_ref, v_ref, o_ref):
+            o_ref[...] = k_ref[0].astype(jnp.float32) \
+                + v_ref[0].astype(jnp.float32)
+
+        page = pl.BlockSpec((1, 1, H, ps, hd),
+                            lambda i, ly: (ly[0], i + 1, 0, 0, 0))
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(2,), in_specs=[page, page],
+                out_specs=pl.BlockSpec((1, H, ps, hd),
+                                       lambda i, ly: (i, 0, 0, 0))),
+            out_shape=jax.ShapeDtypeStruct((2, H, ps, hd), jnp.float32),
+        )(jnp.full((1,), layer, jnp.int32), k, v)
+
+    def program(x, pools, page_idx, offset):
+        def step(pools, _):
+            k, v = pools
+            seen = 0.0
+            for layer in range(L):
+                k = write(k, layer, page_idx, offset, x)
+                v = write(v, layer, page_idx, offset, x)
+                seen = seen + jnp.sum(read(k, v, layer))
+            return (k, v), seen
+
+        return lax.scan(step, pools, None, length=CHUNK)
+
+    return jax.jit(program), [
+        ((SLOTS, H, hd), jnp.bfloat16), (_old_pool(), _old_pool()),
+        ((SLOTS,), jnp.int32), ((SLOTS,), jnp.int32)]
+
+
+def _write_by_head(pool, layer, page_idx, offset, x):
+    """PR 27's per-position write into the old page: the head index
+    spelled out, so the update window is the trailing ``hd``."""
+    heads = jnp.arange(pool.shape[2], dtype=page_idx.dtype)
+    return pool.at[layer, page_idx[..., None], heads,
+                   offset[..., None]].set(x.astype(pool.dtype))
+
+
+def _old_pool():
+    return jax.ShapeDtypeStruct(OLD_POOL, jnp.bfloat16)
+
+
+def test_detector_sees_the_old_write(one_chip):
+    """The control of the ``pinned`` cases: around the write this repo
+    had before PR 27 (update window ``[H, hd]``, the indexed
+    ``page_size`` between them) the loop holds one copy per pool per
+    layer; around PR 27's write it holds none."""
     def old_write(pool, layer, page_idx, offset, x):
         return pool.at[layer, page_idx, :, offset].set(
             x.astype(pool.dtype))
 
-    monkeypatch.setattr(kv_pages, "_write_rows", old_write)
-    eng = _engine()
-    jitted, args = _programs(eng)["chunk"]
-    found = pool_copies(_compiled_text(jitted, args, one_chip),
-                        eng.pool.k)
+    found = pool_copies(_compiled_text(
+        *_old_page_program(old_write), one_chip, "pinned"), _old_pool())
     assert found["loop"] == 2 * LAYERS
     assert found["all"] >= found["loop"]
+    assert pool_copies(_compiled_text(
+        *_old_page_program(_write_by_head), one_chip, "pinned"),
+        _old_pool()) == {"loop": 0, "all": 0}
+
+
+def test_detector_sees_the_old_page_at_rest(one_chip):
+    """The control of the ``at_rest`` cases: pools of the old page rest
+    with the page dimension minor-most, so the same program, its
+    writes as PR 27 left them, lays both pools out anew at entry and
+    at exit."""
+    found = pool_copies(_compiled_text(
+        *_old_page_program(_write_by_head), one_chip, "at_rest"),
+        _old_pool())
+    assert found == {"loop": 0, "all": 4}
+
+
+#: the pools of the two serving cells (BENCHMARK.json): layers, pages,
+#: page size, KV heads x head width; and the query heads over them
+GPT2_LARGE = ((36, 1 + 4 * 64, 16, 20 * 64), 20)
+LFM2_24B = ((2, 1 + 16 * 64, 16, 8 * 64), 32)
+
+
+@pytest.mark.parametrize("shape,dtype,row_major", [
+    (GPT2_LARGE[0], jnp.bfloat16, True), (LFM2_24B[0], jnp.bfloat16, True),
+    (GPT2_LARGE[0], jnp.float8_e4m3fn, True),
+    ((36, 257, 20, 16, 64), jnp.bfloat16, False),   # the old page
+    ((2, 33, 16, 32), jnp.bfloat16, False)],        # a toy row: 32 lanes
+    ids=["gpt2-large", "lfm2-24b-a2b", "gpt2-large-fp8", "old-page",
+         "narrow-row"])
+def test_how_a_pool_rests(one_chip, shape, dtype, row_major):
+    """The layout the device gives an array of a pool's shape: a page
+    whose rows are whole lane tiles rests row-major, as the kernel and
+    the writes take it."""
+    rests = jax.jit(lambda x: x).lower(jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)).compile().input_formats[0][0]
+    order = tuple(rests.layout.major_to_minor)
+    assert (order == tuple(range(len(shape)))) == row_major
 
 
 # ----------------- the kernels LFM2-24B-A2B serves through, at its widths
@@ -186,12 +306,6 @@ def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, k, n):
     assert not re.search(r"bf16\[64,%d,%d\][^\n]* copy\(" % (k, n), hlo)
 
 
-#: the pools of the two serving cells (BENCHMARK.json): layers, pages,
-#: KV heads, page size, head width; and the query heads over them
-GPT2_LARGE = ((36, 1 + 4 * 64, 20, 16, 64), 20)
-LFM2_24B = ((2, 1 + 16 * 64, 8, 16, 64), 32)
-
-
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.float8_e4m3fn],
                          ids=["bf16", "fp8"])
 @pytest.mark.parametrize("cell,slots,queries", [
@@ -204,26 +318,24 @@ LFM2_24B = ((2, 1 + 16 * 64, 8, 16, 64), 32)
 def test_paged_kernel_compiles_at_published_widths(one_chip, cell, slots,
                                                    queries, kv_dtype):
     """The chip's compiler takes the walk at the shapes the cells run:
-    every KV head of eight pages a visit, the visits as many as the
-    pages held (a dynamic grid), few rows a head on the VPU and many on
-    the MXU with fewer heads a visit, the group of 4 on the query axis;
-    and the pools go in as they rest, with no copy of one."""
+    every KV head of eight pages a visit, the heads along the lanes
+    and a lane tile's heads stacked on the query axis, the visits as
+    many as the pages held (a dynamic grid), fewer heads a visit where
+    the rows are many, the group of 4 on the query axis; and the pools
+    go in as they rest, with no copy of one."""
     from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
 
-    from jax.experimental.layout import Format, Layout
-
-    (L, n_pages, Hkv, ps, hd), H = cell
+    (L, n_pages, ps, W), H = cell
+    Hkv, hd = W // 64, 64
     bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
-    # row-major, as the serving programs hold the pools (above)
-    pool = _sds((L, n_pages, Hkv, ps, hd), kv_dtype,
-                Format(Layout(tuple(range(5))), one_chip))
+    pool = _sds((L, n_pages, ps, W), kv_dtype, one_chip)
     kv = {"k": pool, "v": pool}
     if kv_dtype != bf16:
         kv.update(k_scale=_sds((L, n_pages, Hkv), f32, one_chip),
                   v_scale=_sds((L, n_pages, Hkv), f32, one_chip))
     hlo = jax.jit(lambda q, kv, t, b: paged_attention(
         q, kv, 1, t, b, mode="pallas")) \
-        .lower(_sds((slots, H, queries, hd), bf16, one_chip), kv,
+        .lower(_sds((slots, queries, H, hd), bf16, one_chip), kv,
                _sds((slots, 64), i32, one_chip),
                _sds((slots,), i32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo and "paged_attention" in hlo

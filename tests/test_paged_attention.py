@@ -32,16 +32,19 @@ from deeplearning4j_tpu.serving import kv_pages
 
 # ------------------------------------------------------- helpers
 def _mk_kv(rng, L, n_pages, H, ps, hd, fp8=False):
-    k = rng.standard_normal((L, n_pages, H, ps, hd)).astype(np.float32)
-    v = rng.standard_normal((L, n_pages, H, ps, hd)).astype(np.float32)
-    kv = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
+    """A pool tree of pages ``[ps, H * hd]``: a row is one position,
+    head ``h`` on lanes ``[h * hd, (h + 1) * hd)``."""
+    k = rng.standard_normal((L, n_pages, ps, H, hd)).astype(np.float32)
+    v = rng.standard_normal((L, n_pages, ps, H, hd)).astype(np.float32)
+    rows = lambda x: jnp.asarray(x).reshape(L, n_pages, ps, H * hd)
+    kv = {"k": rows(k), "v": rows(v)}
     if fp8:
         out = {}
         for name, x in (("k", k), ("v", v)):
-            am = jnp.asarray(np.abs(x).max(axis=(3, 4)))
-            sc = precision.fp8_scale(am)
-            out[name] = precision.quantize_fp8(
-                jnp.asarray(x), sc[..., None, None])
+            am = jnp.asarray(np.abs(x).max(axis=(2, 4)))
+            sc = precision.fp8_scale(am)                 # [L, pages, H]
+            out[name] = rows(precision.quantize_fp8(
+                jnp.asarray(x), sc[:, :, None, :, None]))
             out[name + "_scale"] = sc
         kv = {"k": out["k"], "v": out["v"],
               "k_scale": out["k_scale"], "v_scale": out["v_scale"]}
@@ -49,20 +52,20 @@ def _mk_kv(rng, L, n_pages, H, ps, hd, fp8=False):
 
 
 def _np_ref(q, kp, vp, tables, qbase):
-    """Dense float32 reference over one layer's pages."""
-    N, H, Q, hd = q.shape
-    ps = kp.shape[2]
-    out = np.zeros((N, H, Q, hd), np.float32)
+    """Dense float32 reference over one layer's pages ``[n_pages, ps,
+    H * hd]``; ``q`` and the result are ``[N, Q, H, hd]``."""
+    N, Q, H, hd = q.shape
+    out = np.zeros((N, Q, H, hd), np.float32)
     for n in range(N):
-        keys = kp[tables[n]].transpose(1, 0, 2, 3).reshape(H, -1, hd)
-        vals = vp[tables[n]].transpose(1, 0, 2, 3).reshape(H, -1, hd)
+        keys = kp[tables[n]].reshape(-1, H, hd)          # [P * ps, H, hd]
+        vals = vp[tables[n]].reshape(-1, H, hd)
         for qi in range(Q):
-            valid = np.arange(keys.shape[1]) <= qbase[n] + qi
-            s = np.einsum("hd,htd->ht", q[n, :, qi], keys) / np.sqrt(hd)
+            valid = np.arange(keys.shape[0]) <= qbase[n] + qi
+            s = np.einsum("hd,thd->ht", q[n, qi], keys) / np.sqrt(hd)
             s = np.where(valid[None, :], s, -np.inf)
             w = np.exp(s - s.max(-1, keepdims=True))
             w /= w.sum(-1, keepdims=True)
-            out[n, :, qi] = np.einsum("ht,htd->hd", w, vals)
+            out[n, qi] = np.einsum("ht,thd->hd", w, vals)
     return out
 
 
@@ -85,7 +88,7 @@ class TestKernelGolden:
             1 + np.arange(N * P).reshape(N, P), jnp.int32)
         # mid-page offsets on purpose: qbase not a page multiple
         qbase = jnp.asarray([P * ps - 2, max(ps - 3, 0)], jnp.int32)
-        q = jnp.asarray(rng.standard_normal((N, H, 1, hd)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((N, 1, H, hd)), jnp.float32)
         for layer in range(L):
             ker, xla = _both(q, kv, layer, tables, qbase)
             np.testing.assert_allclose(ker, xla, atol=1e-5, rtol=1e-5)
@@ -97,7 +100,7 @@ class TestKernelGolden:
         tables = jnp.asarray(
             1 + np.arange(N * P).reshape(N, P), jnp.int32)
         qbase = jnp.asarray([1, 5, 10], jnp.int32)   # mid-page spread
-        q = jnp.asarray(rng.standard_normal((N, H, 1, hd)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((N, 1, H, hd)), jnp.float32)
         ker, xla = _both(q, kv, 0, tables, qbase)
         ref = _np_ref(np.asarray(q), np.asarray(kv["k"][0]),
                       np.asarray(kv["v"][0]), np.asarray(tables),
@@ -114,7 +117,7 @@ class TestKernelGolden:
         kv = _mk_kv(rng, L, 6, H, ps, hd)
         tables = jnp.asarray([[1, 2, 3]], jnp.int32)
         qbase = jnp.asarray([3], jnp.int32)          # mid-page start
-        q = jnp.asarray(rng.standard_normal((1, H, Q, hd)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((1, Q, H, hd)), jnp.float32)
         ker, xla = _both(q, kv, 0, tables, qbase)
         ref = _np_ref(np.asarray(q), np.asarray(kv["k"][0]),
                       np.asarray(kv["v"][0]), np.asarray(tables),
@@ -133,13 +136,13 @@ class TestKernelGolden:
         # page; slot 1 owns two pages, mid-page at position 5
         tables = jnp.asarray([[1, 0, 0], [2, 3, 0]], jnp.int32)
         qbase = jnp.asarray([2, 5], jnp.int32)
-        q = jnp.asarray(rng.standard_normal((N, H, 1, hd)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((N, 1, H, hd)), jnp.float32)
         clean_k, clean_v = np.asarray(kv["k"]), np.asarray(kv["v"])
 
         dirty_k, dirty_v = clean_k.copy(), clean_v.copy()
         dirty_k[:, 0], dirty_v[:, 0] = 1e4, -1e4     # null page garbage
-        dirty_k[:, 1, :, 3:], dirty_v[:, 1, :, 3:] = 1e4, -1e4  # > qpos
-        dirty_k[:, 3, :, 2:], dirty_v[:, 3, :, 2:] = -1e4, 1e4  # > qpos
+        dirty_k[:, 1, 3:], dirty_v[:, 1, 3:] = 1e4, -1e4    # > qpos
+        dirty_k[:, 3, 2:], dirty_v[:, 3, 2:] = -1e4, 1e4    # > qpos
         dirty = {"k": jnp.asarray(dirty_k), "v": jnp.asarray(dirty_v)}
 
         ker_c, xla_c = _both(q, kv, 0, tables, qbase)
@@ -162,7 +165,7 @@ class TestKernelGolden:
         private = jnp.asarray([[1, 2], [4, 3]], jnp.int32)
         kv2 = {"k": jnp.asarray(kc), "v": jnp.asarray(vc)}
         qbase = jnp.asarray([6, 7], jnp.int32)
-        q = jnp.asarray(rng.standard_normal((2, H, 1, hd)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((2, 1, H, hd)), jnp.float32)
         ker_s, xla_s = _both(q, kv, 0, shared, qbase)
         ker_p, xla_p = _both(q, kv2, 0, private, qbase)
         np.testing.assert_allclose(ker_s, ker_p, atol=1e-5, rtol=1e-5)
@@ -171,7 +174,7 @@ class TestKernelGolden:
     def test_bad_mode_raises(self):
         rng = np.random.default_rng(5)
         kv = _mk_kv(rng, 1, 3, 2, 4, 8)
-        q = jnp.zeros((1, 2, 1, 8), jnp.float32)
+        q = jnp.zeros((1, 1, 2, 8), jnp.float32)
         with pytest.raises(ValueError, match="paged-attention mode"):
             paged_attention(q, kv, 0, jnp.asarray([[1]], jnp.int32),
                             jnp.asarray([0], jnp.int32), mode="cuda")
@@ -189,56 +192,66 @@ class TestKernelGolden:
 # ------------------------------------------------------- the live walk
 def _np_ref_grouped(q, kp, vp, tables, qbase, group):
     """``_np_ref`` with ``group`` query heads a KV head."""
-    return _np_ref(q, np.repeat(kp, group, axis=1),
-                   np.repeat(vp, group, axis=1), tables, qbase)
+    H = q.shape[2] // group
+    wide = lambda x: np.repeat(
+        x.reshape(*x.shape[:2], H, -1), group, axis=2) \
+        .reshape(*x.shape[:2], -1)
+    return _np_ref(q, wide(kp), wide(vp), tables, qbase)
+
+
+def _walk_case(qbase, P, *, ps, hd, Hkv, Q=1, G=1, fp8=False,
+               evicted=(), seed=0):
+    """The kernel (interpreted) against ``_xla_paged_attention`` and
+    the dense reference on pools of pages ``[ps, Hkv * hd]``. Every
+    table slot past a lane's last live page (the page of its last
+    query) points at a page filled with NaN: a finite output that
+    equals the references proves that nothing past that page was
+    read."""
+    rng = np.random.default_rng(seed)
+    N = len(qbase)
+    nan_page = 1 + N * P
+    kv = _mk_kv(rng, 2, nan_page + 1, Hkv, ps, hd, fp8=fp8)
+    kv = {n: a.at[:, nan_page].set(jnp.nan) for n, a in kv.items()}
+    assert all(bool(jnp.isnan(a[:, nan_page].astype(jnp.float32))
+                    .all()) for a in kv.values())
+    qbase = np.asarray(qbase, np.int32)
+    last = np.minimum((qbase + Q - 1) // ps, P - 1)
+    live = np.arange(P)[None, :] <= last[:, None]
+    own = 1 + rng.permutation(N * P).reshape(N, P)
+    own[list(evicted)] = 0            # an evicted lane: null table
+    tables = np.where(live, own, nan_page).astype(np.int32)
+    clean = np.where(live, own, 0).astype(np.int32)
+    q = jnp.asarray(rng.standard_normal((N, Q, Hkv * G, hd)),
+                    jnp.float32)
+    ker = np.asarray(paged_attention(
+        q, kv, 1, jnp.asarray(tables), jnp.asarray(qbase),
+        mode="interpret"))
+    xla = np.asarray(paged_attention(
+        q, kv, 1, jnp.asarray(clean), jnp.asarray(qbase), mode="xla"))
+    assert np.isfinite(ker).all()
+    np.testing.assert_allclose(ker, xla, atol=2e-5, rtol=2e-5)
+    if fp8:
+        return
+    # rows past the table's end (a padded suffix) see what the
+    # table holds: the dense reference is for rows inside it
+    ref = _np_ref_grouped(np.asarray(q), np.asarray(kv["k"][1]),
+                          np.asarray(kv["v"][1]), clean, qbase, G)
+    inside = (qbase[:, None] + np.arange(Q)[None, :]) < P * ps
+    np.testing.assert_allclose(ker[inside], ref[inside],
+                               atol=1e-4, rtol=1e-4)
 
 
 class TestLiveWalk:
     """What a walk over live pages only, ``B`` table slots and every KV
-    head a visit, can get wrong. Every case points every table slot
-    past a lane's last live page (the page of its last query) at a page
-    filled with NaN: a finite output that equals the references proves
-    that nothing past that page was read."""
+    head a visit, can get wrong (``_walk_case``: every table slot past
+    a lane's last live page names a page of NaN)."""
 
     PS, HD, HKV = 4, 8, 2
     B = pa._PAGES_A_VISIT                 # table slots a visit
     VISIT = B * PS                        # positions a visit
 
-    def _case(self, qbase, P, *, Q=1, G=1, fp8=False, evicted=(),
-              seed=0):
-        rng = np.random.default_rng(seed)
-        ps, hd, Hkv, N = self.PS, self.HD, self.HKV, len(qbase)
-        nan_page = 1 + N * P
-        kv = _mk_kv(rng, 2, nan_page + 1, Hkv, ps, hd, fp8=fp8)
-        kv = {n: a.at[:, nan_page].set(jnp.nan) for n, a in kv.items()}
-        assert all(bool(jnp.isnan(a[:, nan_page].astype(jnp.float32))
-                        .all()) for a in kv.values())
-        qbase = np.asarray(qbase, np.int32)
-        last = np.minimum((qbase + Q - 1) // ps, P - 1)
-        live = np.arange(P)[None, :] <= last[:, None]
-        own = 1 + rng.permutation(N * P).reshape(N, P)
-        own[list(evicted)] = 0            # an evicted lane: null table
-        tables = np.where(live, own, nan_page).astype(np.int32)
-        clean = np.where(live, own, 0).astype(np.int32)
-        q = jnp.asarray(rng.standard_normal((N, Hkv * G, Q, hd)),
-                        jnp.float32)
-        ker = np.asarray(paged_attention(
-            q, kv, 1, jnp.asarray(tables), jnp.asarray(qbase),
-            mode="interpret"))
-        xla = np.asarray(paged_attention(
-            q, kv, 1, jnp.asarray(clean), jnp.asarray(qbase), mode="xla"))
-        assert np.isfinite(ker).all()
-        np.testing.assert_allclose(ker, xla, atol=2e-5, rtol=2e-5)
-        if fp8:
-            return
-        # rows past the table's end (a padded suffix) see what the
-        # table holds: the dense reference is for rows inside it
-        ref = _np_ref_grouped(np.asarray(q), np.asarray(kv["k"][1]),
-                              np.asarray(kv["v"][1]), clean, qbase, G)
-        inside = (qbase[:, None] + np.arange(Q)[None, :]) < P * ps
-        np.testing.assert_allclose(
-            ker.transpose(0, 2, 1, 3)[inside],
-            ref.transpose(0, 2, 1, 3)[inside], atol=1e-4, rtol=1e-4)
+    def _case(self, qbase, P, **kw):
+        _walk_case(qbase, P, ps=self.PS, hd=self.HD, Hkv=self.HKV, **kw)
 
     @pytest.mark.parametrize("fp8", [False, True], ids=["f32", "fp8"])
     def test_one_position_beside_a_full_table(self, fp8):
@@ -309,19 +322,81 @@ class TestLiveWalk:
             assert set(pages[total * B:]) <= set(
                 tables[-1, :want_last[-1] + 1])
 
-    @pytest.mark.parametrize("Hkv,rows,fp8,vpu,whole", [
-        (20, 1, False, True, True),       # gpt2-large decode
-        (8, 4, False, True, True),        # lfm2-24b-a2b decode
-        (20, 4, True, True, True),        # a verify call, fp8 pools
-        (20, 256, False, False, False),   # a suffix-prefill bucket
-        (20, 1024, False, False, False),
-        (64, 4, False, True, False)])
-    def test_heads_a_visit_fit_the_budget(self, Hkv, rows, fp8, vpu,
-                                          whole):
-        h = pa._heads_a_visit(Hkv, rows, 64, 16, 8, 1 if fp8 else 2,
-                              vpu, fp8)
-        assert Hkv % h == 0 and (h == Hkv) == whole
-        assert vpu == (rows <= pa._VPU_ROWS)
+    @pytest.mark.parametrize("Hkv,rows,fp8,whole", [
+        (20, 1, False, True),             # gpt2-large decode
+        (8, 4, False, True),              # lfm2-24b-a2b decode
+        (20, 4, True, True),              # a verify call, fp8 pools
+        (20, 256, False, False),          # a suffix-prefill bucket
+        (20, 1024, False, False),
+        (64, 64, False, False)])
+    def test_heads_a_visit_fit_the_budget(self, Hkv, rows, fp8, whole):
+        """Whole lane tiles of heads (two heads of 64), every head
+        where the visit fits."""
+        h = pa._heads_a_visit(Hkv, rows, 64, 16, 8, 1 if fp8 else 2, 2,
+                              fp8)
+        assert Hkv % h == 0 and h % 2 == 0 and (h == Hkv) == whole
+
+
+# --------------------------------------------- heads along the lanes
+class TestPageRows:
+    """A page is ``[ps, Hkv * hd]`` (PR 31): the kernel takes the heads
+    of a row by lane, a lane tile at a time. The kernel (interpreted)
+    against ``_xla_paged_attention`` at the shapes that decide how: two
+    heads of 64 a 128-lane tile as both serving cells have them, a
+    head that is a whole tile, a row that is neither (three heads of
+    64) and a toy row narrower than a tile; one query head a KV head
+    and four; one query a lane, a verify call's few, a suffix
+    prefill's bucket; fp8 pools with their scale planes. The tables
+    are wider than a visit, so every case walks several visits and
+    ends on one whose last slots are clamped to the last live page
+    (``_walk_case`` fills what lies past it with NaN)."""
+
+    PS = 16
+    B = pa._PAGES_A_VISIT
+
+    #: name -> KV heads, head width, query heads a KV head, queries
+    CASES = {
+        "g1-decode": (4, 64, 1, 1),
+        "g4-decode": (2, 64, 4, 1),
+        "g1-verify": (4, 64, 1, 4),
+        "g4-verify": (2, 64, 4, 3),
+        "g1-suffix-bucket": (4, 64, 1, 16),
+        "g4-suffix-bucket": (2, 64, 4, 8),
+        "head-a-tile": (2, 128, 1, 1),
+        "row-of-three-heads": (3, 64, 1, 2),
+        "narrow-row": (2, 8, 1, 1),
+        "narrow-row-g4-verify": (2, 8, 4, 4),
+    }
+
+    def _case(self, name, fp8=False):
+        Hkv, hd, G, Q = self.CASES[name]
+        P = self.B + 3                    # 2 visits, the last of 3 pages
+        top = P * self.PS - Q
+        lanes = [top, self.B * self.PS + 1, 5] if Q < 8 else [top - 7]
+        _walk_case(lanes, P, ps=self.PS, hd=hd, Hkv=Hkv, Q=Q, G=G,
+                   fp8=fp8, seed=len(name))
+
+    @pytest.mark.parametrize("name", list(CASES))
+    def test_kernel_matches_xla(self, name):
+        self._case(name)
+
+    @pytest.mark.parametrize("name", ["g1-decode", "g4-decode",
+                                      "g1-verify", "g1-suffix-bucket",
+                                      "narrow-row"])
+    def test_kernel_matches_xla_on_fp8_pools(self, name):
+        self._case(name, fp8=True)
+
+    def test_a_clamped_last_visit_reads_nothing_past_the_last_page(self):
+        """One lane whose last visit holds a single live page of
+        ``B``: the other ``B - 1`` slots name that page again."""
+        P = 2 * self.B
+        _walk_case([self.B * self.PS + 2], P, ps=self.PS, hd=64, Hkv=4)
+
+    @pytest.mark.parametrize("W,hd,tile", [
+        (1280, 64, 128), (512, 64, 128), (256, 128, 128),
+        (512, 256, 256), (192, 64, 192), (16, 8, 16), (128, 32, 128)])
+    def test_lane_tile(self, W, hd, tile):
+        assert pa._lane_tile(W, hd) == tile
 
 
 # ------------------------------------------------------- fp8 numerics
@@ -355,7 +430,7 @@ class TestFp8:
         kv8 = _mk_kv(rng, L, 6, H, ps, hd, fp8=True)
         tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
         qbase = jnp.asarray([5, 3], jnp.int32)
-        q = jnp.asarray(rng.standard_normal((N, H, 1, hd)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((N, 1, H, hd)), jnp.float32)
         for layer in range(L):
             ker, xla = _both(q, kv8, layer, tables, qbase)
             np.testing.assert_allclose(ker, xla, atol=1e-5, rtol=1e-5)
@@ -369,7 +444,7 @@ class TestFp8:
                      fp8=True)
         tables = jnp.asarray([[1, 2]], jnp.int32)
         qbase = jnp.asarray([6], jnp.int32)
-        q = jnp.asarray(rng.standard_normal((1, H, 1, hd)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((1, 1, H, hd)), jnp.float32)
         ref = np.asarray(paged_attention(q, kvf, 0, tables, qbase,
                                          mode="xla"))
         got = np.asarray(paged_attention(q, kv8, 0, tables, qbase,
@@ -407,9 +482,9 @@ class TestFp8Pages:
             np.asarray(out_d["k_scale"]), np.asarray(out_c["k_scale"]))
         # valid positions round-trip within the e4m3 bound
         deq = precision.dequantize_fp8(
-            out_d["k"][0, row[0], :, :, :],
-            out_d["k_scale"][0, row[0]][:, None, None], jnp.float32)
-        ref = base[0, 0, :, :ps, :].transpose(0, 1, 2)
+            out_d["k"][0, row[0]].reshape(ps, H, hd),
+            out_d["k_scale"][0, row[0]][None, :, None], jnp.float32)
+        ref = base[0, 0, :, :ps, :].transpose(1, 0, 2)   # [ps, H, hd]
         np.testing.assert_allclose(np.asarray(deq), ref, atol=0.26)
 
     def test_append_token_scale_frozen_after_page_start(self):
@@ -418,18 +493,18 @@ class TestFp8Pages:
         entries under their feet)."""
         _, kv = self._pool_kv()
         page = jnp.asarray([1], jnp.int32)
-        k0 = jnp.full((1, 2, 8), 2.0, jnp.float32)
+        k0 = jnp.full((1, 2 * 8), 2.0, jnp.float32)     # a row: H * hd
         kv = kv_pages.append_token(kv, 0, page,
                                    jnp.asarray([0], jnp.int32), k0, k0)
         minted = np.asarray(kv["k_scale"][0, 1]).copy()
-        k1 = jnp.full((1, 2, 8), 400.0, jnp.float32)   # outlier
+        k1 = jnp.full((1, 2 * 8), 400.0, jnp.float32)  # outlier
         kv = kv_pages.append_token(kv, 0, page,
                                    jnp.asarray([1], jnp.int32), k1, k1)
         np.testing.assert_array_equal(
             np.asarray(kv["k_scale"][0, 1]), minted)
         # the offset-0 entry still dequantizes to its original value
         deq = precision.dequantize_fp8(
-            kv["k"][0, 1, :, 0, :], kv["k_scale"][0, 1][:, None],
+            kv["k"][0, 1, 0].reshape(2, 8), kv["k_scale"][0, 1][:, None],
             jnp.float32)
         np.testing.assert_allclose(np.asarray(deq), 2.0, atol=0.2)
 
@@ -444,7 +519,7 @@ class TestFp8Pages:
         rng = np.random.default_rng(1)
         table = jnp.asarray([1, 2, 3], jnp.int32)
         # pre-commit page 2 positions 4..5 (the resumed boundary page)
-        pre = jnp.full((1, H, hd), 2.0, jnp.float32)
+        pre = jnp.full((1, H * hd), 2.0, jnp.float32)
         for off in (0, 1):
             kv = kv_pages.append_token(
                 kv, 0, jnp.asarray([2], jnp.int32),
@@ -460,10 +535,10 @@ class TestFp8Pages:
         page = np.where(real, np.asarray(table)[
             np.minimum(padded_pos // ps, P - 1)], 0)
         off = np.where(real, padded_pos % ps, 0)
+        rows = jnp.asarray(ks).reshape(1, B, H * hd)
         out = kv_pages.append_spec(
             kv, 0, jnp.asarray(page, jnp.int32)[None],
-            jnp.asarray(off, jnp.int32)[None], jnp.asarray(ks)[None],
-            jnp.asarray(ks)[None],
+            jnp.asarray(off, jnp.int32)[None], rows, rows,
             chunk=jnp.asarray(chunk, jnp.int32)[None],
             real=jnp.asarray(real)[None], tables=table[None])
         # boundary page keeps its frozen scale; fresh page 3 mints the
@@ -480,16 +555,16 @@ class TestFp8Pages:
             np.asarray(out["k_scale"][0, 1]), 1.0)
         # page-3 lanes round-trip within the e4m3 bound of their amax
         deq = precision.dequantize_fp8(
-            out["k"][0, 3, :, 0:2, :],
-            out["k_scale"][0, 3][:, None, None], jnp.float32)
-        ref = np.asarray(ks[2:4]).transpose(1, 0, 2)
-        bound = np.asarray(want)[:, None, None] * 448 / 16 + 1e-6
+            out["k"][0, 3, 0:2].reshape(2, H, hd),
+            out["k_scale"][0, 3][None, :, None], jnp.float32)
+        ref = np.asarray(ks[2:4])                        # [2, H, hd]
+        bound = np.asarray(want)[None, :, None] * 448 / 16 + 1e-6
         assert (np.abs(np.asarray(deq) - ref) <= bound).all()
 
     def test_copy_page_carries_scales(self):
         _, kv = self._pool_kv()
         page = jnp.asarray([1], jnp.int32)
-        k0 = jnp.full((1, 2, 8), 3.0, jnp.float32)
+        k0 = jnp.full((1, 2 * 8), 3.0, jnp.float32)
         kv = kv_pages.append_token(kv, 0, page,
                                    jnp.asarray([0], jnp.int32), k0, k0)
         out = kv_pages.copy_page(kv, jnp.asarray(1), jnp.asarray(4))
@@ -567,7 +642,7 @@ class TestPositionWrites:
         if not fp8:
             kv = {n: a.astype(jnp.bfloat16) for n, a in kv.items()}
         page, off, real, extra = self._lanes(rng, writer)
-        x = {n: rng.standard_normal(page.shape + (self.H, self.HD))
+        x = {n: rng.standard_normal(page.shape + (self.H * self.HD,))
              .astype(np.float32) * 3 for n in ("k", "v")}
         out = getattr(kv_pages, writer.removesuffix("_one_slot"))(
             kv, self.LAYER, page, off, jnp.asarray(x["k"]),
@@ -579,8 +654,10 @@ class TestPositionWrites:
                 xs = jnp.asarray(x[n][lane])
                 if fp8:   # under the scale the page ends up with
                     sc = out[n + "_scale"][self.LAYER, pg]
-                    xs = precision.quantize_fp8(xs, sc[:, None])
-                want[self.LAYER, pg, :, o] = np.asarray(
+                    xs = precision.quantize_fp8(
+                        xs.reshape(self.H, self.HD), sc[:, None]) \
+                        .reshape(-1)
+                want[self.LAYER, pg, o] = np.asarray(
                     xs.astype(kv[n].dtype).astype(jnp.float32))
             got = np.asarray(out[n].astype(jnp.float32))
             assert out[n].dtype == kv[n].dtype

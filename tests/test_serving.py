@@ -585,11 +585,13 @@ class _ToyLM:
 
         S = tok.shape[0]
         x = w["book"][tok] + w["where"][pos]
-        q, k, v = self._qkv(w, x[:, None])            # [S, H, 1, hd]
+        # a projected row is a page row: the heads side by side
+        q, k, v = (x @ w[n] for n in ("ask", "key", "val"))   # [S, D]
         kv = kv_pages.append_token(
             kv, 0, tables[jnp.arange(S), pos // page_size],
-            pos % page_size, k[:, :, 0], v[:, :, 0])
-        ctx = paged_attention(q, kv, 0, tables, pos, mode=mode)
+            pos % page_size, k, v)
+        ctx = paged_attention(q.reshape(S, 1, self.H, self.D // self.H),
+                              kv, 0, tables, pos, mode=mode)
         x = x + ctx.reshape(S, self.D) @ w["back"]
         return kv, None, x @ w["book"].T, None
 
