@@ -41,8 +41,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.ops.grouped_matmul_pallas import (grouped_matmul,
-                                                          row_tile)
+from deeplearning4j_tpu.models.decoder_ops import rms_norm, rope
+from deeplearning4j_tpu.models.routed_experts import (expert_stats,
+                                                      routed_experts)
 from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
 from deeplearning4j_tpu.serving import kv_pages
 
@@ -250,23 +251,10 @@ class Lfm2MoeLM:
 
     # -- pieces ---------------------------------------------------------
     def _rms(self, x, g):
-        xf = x.astype(jnp.float32)
-        r = lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
-                      + self.cfg.norm_eps)
-        return (xf * r).astype(x.dtype) * g
+        return rms_norm(x, g, self.cfg.norm_eps)
 
     def _rope(self, x, pos):
-        """Rotate-half RoPE of ``x [n, t, heads, hd]`` at ``pos [n, t]``,
-        in float32."""
-        hd = x.shape[-1]
-        inv = 1.0 / (self.cfg.rope_theta ** (
-            jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-        ang = pos.astype(jnp.float32)[..., None] * inv
-        cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
-        sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
-        xf = x.astype(jnp.float32)
-        rot = jnp.concatenate([-xf[..., hd // 2:], xf[..., :hd // 2]], -1)
-        return (xf * cos + rot * sin).astype(x.dtype)
+        return rope(x, pos, self.cfg.rope_theta)
 
     def _conv(self, lp, h, ci, cache):
         k = self.cfg.conv_L_cache
@@ -310,32 +298,12 @@ class Lfm2MoeLM:
         ``([m, d], stats, idx)``. ``live [m]`` (bool) marks rows that
         are real: the others go to no expert and come back zero.
         ``stats`` = int32 (assignments, distinct experts touched, the
-        most any expert got), of live rows."""
-        c = self.cfg
-        E, k = c.num_experts, c.num_experts_per_tok
-        m, d = x.shape
+        most any expert got), of live rows. Every expert is held here;
+        the product itself is ``models/routed_experts.py``'s."""
         idx, w = self.route(lp, x)
-        flat = idx.reshape(-1)
-        if live is not None:
-            flat = jnp.where(jnp.repeat(live, k), flat, E)   # no group
-        counts = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
-                         dtype=jnp.int32)
-        order = jnp.argsort(flat, stable=True)
-        a = m * k
-        pad = -a % row_tile(a)
-        rows = jnp.pad(x[order // k], ((0, pad), (0, 0)))
-        up = grouped_matmul(rows, lp["ew1"], counts, mode=mode)
-        gate = grouped_matmul(rows, lp["ew3"], counts, mode=mode)
-        mid = (jax.nn.silu(up.astype(jnp.float32))
-               * gate.astype(jnp.float32)).astype(x.dtype)
-        y = grouped_matmul(mid, lp["ew2"], counts, mode=mode)
-        back = jnp.zeros((a,), jnp.int32).at[order].set(
-            jnp.arange(a, dtype=jnp.int32))
-        y = jnp.where((flat < E)[:, None], y[back], 0).reshape(m, k, d)
-        out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
-        stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
-                           jnp.max(counts)]).astype(jnp.int32)
-        return out.astype(x.dtype), stats, idx
+        out, counts = routed_experts(x, idx, w, lp["ew1"], lp["ew3"],
+                                     lp["ew2"], live=live, mode=mode)
+        return out, expert_stats(counts), idx
 
     def _block(self, li, lp, x, pos, cache, live, mode, aux):
         c = self.cfg

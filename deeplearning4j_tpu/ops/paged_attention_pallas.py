@@ -92,6 +92,19 @@ group), so a page is read once a group; row ``i`` is masked at
 position ``qbase + i // G``. With ``G = 1`` a decode step's query is
 the projection's row as it stands.
 
+A window's ring (PR 32; ``window=``): a sliding-window layer keeps,
+for each sequence, a RING of ``R`` pages whatever the context: the
+table is ``[N, R]``, page NUMBER ``n`` (positions ``[n * ps, (n + 1) *
+ps)``) lives in table entry ``n % R``, and a query at position ``p``
+attends ``p - window + 1 .. p``. ``R * ps >= window + ps - 1``, so the
+pages a window straddles never share an entry. The walk starts at the
+page of the first admitted position instead of the sequence's first
+and is at most ``R`` pages long; a row's mask adds ``position > p -
+window``. What an earlier lap of the ring left in an entry lies, by
+this arithmetic, at positions past the query's and is masked like any
+other future position. One kernel, one layout: the window is a static
+argument, and without it the program is the one above, op for op.
+
 fp8 KV (``kv_dtype="fp8_e4m3"``): the pools store float8_e4m3fn with
 per-page-per-head fp32 scale planes ``[L, n_pages, H]`` beside them;
 the kernel dequantizes each page block in VMEM (one multiply by its
@@ -143,13 +156,29 @@ def paged_attention_mode() -> str:
 
 
 # ------------------------------------------------------- xla reference
-def _xla_paged_attention(q, kv, layer, tables, qbase, group=1):
+def _ring_positions(qbase, n_queries, P, ps):
+    """The absolute position each cell of a ring table holds, ``[N, P *
+    ps]``: entry ``r`` holds the newest page number ``<=`` the last
+    query's whose remainder by ``P`` is ``r`` (negative where the
+    sequence has not reached it; what an older lap left there reads as
+    a position past every query)."""
+    last = (qbase + n_queries - 1) // ps                        # [N]
+    r = jnp.arange(P, dtype=jnp.int32)
+    pn = last[:, None] - (last[:, None] - r[None, :]) % P       # [N, P]
+    return (pn[:, :, None] * ps
+            + jnp.arange(ps, dtype=jnp.int32)[None, None, :]) \
+        .reshape(-1, P * ps)
+
+
+def _xla_paged_attention(q, kv, layer, tables, qbase, group=1,
+                         window=None):
     """The einsum pair of the pre-kernel decode core / prefix-prefill
     program (serving/engine.py PR 8-9 lineage) over page rows: ``q`` is
     ``[N, rows, Hkv, hd]`` (row ``i`` = query ``i // group``). This is
     the dispatch target when the kernel is off and the tests'
     reference — the engine's greedy identity to
-    ``CausalLM.generate()`` rests on it."""
+    ``CausalLM.generate()`` rests on it. With ``window`` the table is
+    a ring (module docstring)."""
     N, Q, H, hd = q.shape
 
     def pages(name):                      # [N, P, ps, H, hd]
@@ -172,8 +201,13 @@ def _xla_paged_attention(q, kv, layer, tables, qbase, group=1):
     logits = jnp.einsum("nqhd,npohd->nhqpo", q, ck) \
         .reshape(N, H, Q, P * ps) * scale
     neg = jnp.asarray(jnp.finfo(logits.dtype).min, logits.dtype)
-    valid = (jnp.arange(P * ps)[None, None, None, :]
-             <= qpos[:, None, :, None])
+    if window is None:
+        valid = (jnp.arange(P * ps)[None, None, None, :]
+                 <= qpos[:, None, :, None])
+    else:
+        kpos = _ring_positions(qbase, Q // group, P, ps)[:, None, None, :]
+        valid = (kpos <= qpos[:, None, :, None]) & (kpos >= 0) \
+            & (kpos > qpos[:, None, :, None] - window)
     logits = jnp.where(valid, logits, neg)
     w = jax.nn.softmax(logits, axis=-1).reshape(N, H, Q, P, ps)
     return jnp.einsum("nhqpo,npohd->nqhd", w, cv)
@@ -229,26 +263,39 @@ def _heads_a_visit(Hkv, rows, hd, ps, B, itemsize, q_itemsize, fp8):
     return next((h for h in fits if visit(h) <= _VMEM_BUDGET), fits[-1])
 
 
-def _live_visits(tables, qbase, n_queries, ps, B):
+def _live_visits(tables, qbase, n_queries, ps, B, window=None):
     """The walk's schedule, from the scalars in hand: grid step ``g`` is
     visit ``visit[g]`` of sequence ``lane[g]`` and brings the ``B`` pool
     pages ``pages[g * B:(g + 1) * B]``; ``total`` steps cover every page
     the sequences hold and nothing else. A sequence holds the pages up
     to ``last[n]``, the page of its last query; the slots of its final
     visit past that page name it again (the kernel masks them), so no
-    table entry beyond a sequence's last live page is ever read."""
+    table entry beyond a sequence's last live page is ever read. With
+    ``window`` the table is a ring: the walk starts at ``first[n]``, the
+    page of the first position the first query's window admits,
+    ``last`` is a page NUMBER, and page number ``n`` is table entry ``n
+    % P``."""
     N, P = tables.shape
     i32 = jnp.int32
-    last = jnp.clip((qbase + n_queries - 1) // ps, 0, P - 1)       # [N]
-    visits = last // B + 1
+    if window is None:
+        last = jnp.clip((qbase + n_queries - 1) // ps, 0, P - 1)   # [N]
+        visits = last // B + 1
+    else:
+        last = jnp.maximum(qbase + n_queries - 1, 0) // ps
+        first = jnp.maximum(qbase - (window - 1), 0) // ps
+        visits = (last - first) // B + 1
     ends = jnp.cumsum(visits)
     g = jnp.arange(N * -(-P // B), dtype=i32)
     # steps past the total (never run) fall to the last sequence
     lane = jnp.minimum(jnp.sum(g[:, None] >= ends[None, :], axis=1),
                        N - 1)
     visit = g - (ends - visits)[lane]
-    slot = jnp.minimum(visit[:, None] * B + jnp.arange(B, dtype=i32),
-                       last[lane][:, None])
+    slot = visit[:, None] * B + jnp.arange(B, dtype=i32)
+    if window is not None:
+        slot = slot + first[lane][:, None]
+    slot = jnp.minimum(slot, last[lane][:, None])
+    if window is not None:
+        slot = slot % P
     pages = tables[lane[:, None], slot].reshape(-1)
     return tuple(a.astype(i32)
                  for a in (lane, visit, pages, last, ends[-1]))
@@ -256,7 +303,7 @@ def _live_visits(tables, qbase, n_queries, ps, B):
 
 def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
             q_ref, *rest, page_size, head_dim, sm_scale, fp8, group,
-            pages):
+            pages, window=None):
     """One visit (grid step ``g``) of the online-softmax walk: ``pages``
     table slots of sequence ``lane[g]``, every KV head of the block at
     once, the heads along the lanes. The block's ``W`` lanes are
@@ -343,8 +390,15 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
     # (causal) and lies on a page the sequence holds (a slot past the
     # last live page names that page again)
     pos = j * T + lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
+    if window is not None:
+        # the walk began at the page of the first position the first
+        # query's window admits; a row admits its own window only
+        first = jnp.maximum(qbase - (window - 1), 0) // ps
+        pos = pos + first * ps
     valid = (pos <= qbase + (sub % rows) // group) \
         & (pos < (last + 1) * ps)                       # [1, Rp, T]
+    if window is not None:
+        valid = valid & (pos > qbase + (sub % rows) // group - window)
     s = jnp.where(valid, s, _MASK_MIN)                  # [tiles, Rp, T]
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -367,10 +421,11 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
         ctx = weigh(p)
     acc_ref[...] = acc_ref[...] * alpha + ctx
 
-    @pl.when(j == last // B)
+    @pl.when(j == (last // B if window is None else (last - first) // B))
     def _finish():
-        # every query admits flat position 0 (qpos >= 0 always), so
-        # l >= exp(0) == 1 at the end of the walk: safe division
+        # every query admits flat position 0 (qpos >= 0 always; under
+        # a window, its own position), so l >= exp(0) == 1 at the end
+        # of the walk: safe division
         full = acc_ref[...] / l_ref[...]
         out = full[:, :rows]
         for h in range(1, hpt):
@@ -380,9 +435,10 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
             o_ref[0, :, c * tw:(c + 1) * tw] = out[c].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "group"))
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "group", "window"))
 def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
-                            group=1):
+                            group=1, window=None):
     """``q`` is ``[N, rows, Hkv, hd]`` (row ``i`` = query ``i //
     group``). ``layer`` is a traced ``[1]`` array and the function is
     jitted so that the layers of a program share ONE trace and one
@@ -404,7 +460,7 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
     Rp = -(-hpt * rows // 8) * 8
     qbase = qbase.astype(jnp.int32)
     lane, visit, pages, last, total = _live_visits(
-        tables.astype(jnp.int32), qbase, rows // group, ps, B)
+        tables.astype(jnp.int32), qbase, rows // group, ps, B, window)
     # the walk is as long as the pages held: the visit axis is dynamic
     grid = (H // Hh, total)
 
@@ -437,7 +493,7 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
     kernel = functools.partial(
         _kernel, page_size=ps, head_dim=hd,
         sm_scale=float(1.0 / np.sqrt(np.float32(hd))), fp8=fp8,
-        group=group, pages=B)
+        group=group, pages=B, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -457,7 +513,8 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
 
 
 # ------------------------------------------------------------ dispatch
-def paged_attention(q, kv, layer, tables, qbase, *, mode=None):
+def paged_attention(q, kv, layer, tables, qbase, *, mode=None,
+                    window=None):
     """Per-layer paged attention over the serving engine's KV tree.
 
     Parameters
@@ -475,6 +532,9 @@ def paged_attention(q, kv, layer, tables, qbase, *, mode=None):
     qbase : ``[N]`` int32; query ``i`` of row ``n`` sits at absolute
         position ``qbase[n] + i``.
     mode : overrides :func:`paged_attention_mode` (tests/benches).
+    window : a sliding window's width: query at position ``p`` attends
+        positions ``p - window + 1 .. p``, and ``tables`` is a RING
+        ``[N, R]`` (module docstring, "A window's ring").
 
     Returns ``[N, Q, H, hd]`` context in ``q.dtype``.
     """
@@ -496,12 +556,17 @@ def paged_attention(q, kv, layer, tables, qbase, *, mode=None):
         # ``i`` is query ``i // G`` of head ``i % G`` of its group
         q = q.reshape(N, Q, Hkv, G, hd).transpose(0, 1, 3, 2, 4) \
              .reshape(N, Q * G, Hkv, hd)
+    ps = kv["k"].shape[2]
+    if window is not None and tables.shape[1] * ps < window + Q + ps - 2:
+        raise ValueError(
+            f"a ring of {tables.shape[1]} pages of {ps} cannot hold "
+            f"{Q} queries' window of {window} from any offset")
     if mode == "xla":
-        out = _xla_paged_attention(q, kv, layer, tables, qbase, G)
+        out = _xla_paged_attention(q, kv, layer, tables, qbase, G, window)
     else:
         out = _pallas_paged_attention(
             q, kv, jnp.full((1,), layer, jnp.int32), tables, qbase,
-            interpret=(mode == "interpret"), group=G)
+            interpret=(mode == "interpret"), group=G, window=window)
     if G > 1:
         out = out.reshape(N, Q, G, Hkv, hd).transpose(0, 1, 3, 2, 4) \
                  .reshape(N, Q, H, hd)

@@ -228,6 +228,17 @@ class ServingRequest:
             raise self._error
 
 
+class _Burst:
+    """A decode burst under way: the slots it decodes for and their
+    requests, the budget to the nearest request's end, the roster and
+    the running pos / tok / keys as device arrays, and the tokens of
+    the chunks sent so far, not yet read."""
+
+    __slots__ = ("idx", "reqs", "rem", "has_eos", "pos_live", "after_end",
+                 "roster", "pos", "tok", "kd", "chunks", "counts", "steps",
+                 "gen", "span")
+
+
 # ----------------------------------------------------------- warm pool
 class _WarmPool:
     """AOT-compiled executables keyed by program name. ``run`` calls
@@ -446,8 +457,9 @@ class DecodeEngine:
                     raise ValueError(
                         f"{name} cannot be honoured for "
                         f"{type(model).__name__}: the model keeps a "
-                        "per-slot recurrent state beside its pages, and "
-                        "this option keys on pages alone")
+                        "per-slot state beside its pages (a recurrent "
+                        "state, or window layers' rings), and this "
+                        "option keys on pages alone")
         needs = {"prefix_cache": (prefix_cache, "paged_rows"),
                  "session_capacity": (session_capacity > 0, "paged_rows"),
                  "spec_decode": (spec_decode is not None, "paged_rows"),
@@ -511,13 +523,23 @@ class DecodeEngine:
             spec["head_dim"], n_pages, dtype=model._cdtype,
             engine_id=self.engine_id, device=device,
             kv_dtype=self.kv_dtype)
-        #: the per-slot state beside the pool ``[state layers, slots,
-        #: ...]`` in the compute dtype, or None where the model has none
+        #: the per-slot state beside the pool: an array ``[state
+        #: layers, slots, ...]`` in the compute dtype, or a dict of such
+        #: (a cache's second kind: window layers' rings of K and of V),
+        #: or None where the model has none
         self._state = None
         if spec["state"] is not None:
-            layers, *rest = spec["state"]
-            self._state = jnp.zeros((layers, self.slots, *rest),
-                                    model._cdtype, device=device)
+            self._state = jax.tree_util.tree_map(
+                lambda shape: jnp.zeros(
+                    (shape[0], self.slots, *shape[1:]), model._cdtype,
+                    device=device),
+                spec["state"], is_leaf=lambda x: isinstance(x, tuple))
+        #: positions a window layer attends (None: the model has none);
+        #: such a model's K/V of those layers is the state above
+        self._window = spec.get("window")
+        #: the most slots ever live at once: what of the window layers'
+        #: store was ever in use
+        self._slots_high_water = 0
         self.prefill_buckets = self._resolve_buckets(prefill_buckets)
         # sampling-key width follows the process PRNG impl (threefry=2,
         # rbg=4) so keydata shapes match whatever jax.config says
@@ -530,6 +552,16 @@ class DecodeEngine:
         # sample identically regardless of process-wide submission
         # history
         self._sample_counter = itertools.count()
+        # the keys are made KEY_BLOCK ordinals at a time and taken from
+        # the host's copy: a submit must not wait for the device, which
+        # works through its calls in order and may have a chunk of
+        # tens of milliseconds before the key's fold
+        base = self._base_key
+        self._key_block_fn = jax.jit(jax.vmap(
+            lambda n: jax.random.key_data(jax.random.fold_in(base, n))))
+        self._key_lock = threading.Lock()
+        self._key_blocks: Dict[int, np.ndarray] = {}
+        self._sample_keydata(0)
         # host-side slot state (the jitted step's small inputs)
         S, P = self.slots, self.pages_per_slot
         self._tables = np.zeros((S, P), np.int32)
@@ -544,6 +576,12 @@ class DecodeEngine:
         # device-side mirrors of the slot state that only changes on
         # join/evict (tables/active/temps): re-uploaded only when dirty
         self._dev_static = None
+        #: counts the roster's changes (a join, an eviction), so that a
+        #: burst begun ahead knows whether its roster still stands
+        self._roster_gen = 0
+        #: the burst whose first chunk went out before the last one's
+        #: tokens were handed out (``_run_ahead``), or None
+        self._ahead: Optional[_Burst] = None
         # programs: one chunk executable per power-of-two step count
         if max_chunk < 1 or (max_chunk & (max_chunk - 1)):
             raise ValueError(
@@ -681,7 +719,8 @@ class DecodeEngine:
         #: experts touched, layer-steps, and the hottest expert's load
         self._expert_totals = dict.fromkeys(
             ("expert_assignments", "experts_touched",
-             "expert_layer_steps", "expert_load_max"), 0)
+             "expert_layer_steps", "expert_load_max",
+             "expert_assignments_routed"), 0)
         self._occupancy_sum = 0.0
         # newest finished requests (id + finish reason + timings), so
         # client logs can join against server traces via stats()
@@ -785,8 +824,10 @@ class DecodeEngine:
             kv = kv_pages.commit_prefill(kv, ks, vs, page_row, ps,
                                          n_valid=t0)
             if state is not None:
-                state = lax.dynamic_update_slice_in_dim(
-                    state, mine[:, None].astype(state.dtype), slot, axis=1)
+                state = jax.tree_util.tree_map(
+                    lambda all_, one: lax.dynamic_update_slice_in_dim(
+                        all_, one[:, None].astype(all_.dtype), slot,
+                        axis=1), state, mine)
             return (kv, state), last.astype(jnp.float32), counts
 
         return prefill
@@ -888,16 +929,20 @@ class DecodeEngine:
         self.pool.rebind(kv)
 
     def _count_experts(self, counts: np.ndarray) -> Dict[str, int]:
-        """Add one program's counts ``[..., expert layers, 3]``
-        (assignments, distinct experts, hottest load per layer-step) to
-        the cumulative forms; -> the same sums as span attributes."""
-        c = counts.reshape(-1, 3).astype(np.int64)
+        """Add one program's counts ``[..., expert layers, 3 or 4]``
+        (assignments to experts held here, distinct ones touched,
+        hottest load per layer-step; a model that holds a share of its
+        experts adds the assignments routed, held or not) to the
+        cumulative forms; -> the same sums as span attributes."""
+        c = counts.reshape(-1, counts.shape[-1]).astype(np.int64)
+        routed = c[:, 3] if c.shape[1] > 3 else c[:, 0]
         # a layer-step that routed nothing (no live lane) is no work
-        c = c[c[:, 0] > 0]
+        c = c[routed > 0]
         got = {"expert_assignments": int(c[:, 0].sum()),
                "experts_touched": int(c[:, 1].sum()),
                "expert_layer_steps": int(len(c)),
-               "expert_load_max": int(c[:, 2].sum())}
+               "expert_load_max": int(c[:, 2].sum()),
+               "expert_assignments_routed": int(routed.sum())}
         for name, n in got.items():
             self._expert_totals[name] += n
         return got
@@ -1109,12 +1154,11 @@ class DecodeEngine:
         if self._dead is not None or self._stop.is_set():
             raise RuntimeError("engine has been shut down")
         rid = next(self._req_counter)
-        key = (jax.random.key(sample_seed) if sample_seed is not None
-               else jax.random.fold_in(self._base_key,
-                                       next(self._sample_counter)))
+        keydata = (np.asarray(jax.random.key_data(
+            jax.random.key(sample_seed))) if sample_seed is not None
+            else self._sample_keydata(next(self._sample_counter)))
         req = ServingRequest(rid, prompt, max_new_tokens, temperature,
-                             eos_id, np.asarray(jax.random.key_data(key)),
-                             session_id=session_id)
+                             eos_id, keydata, session_id=session_id)
         req.engine_id = self.engine_id
         req._engine = self
         req.spec_enabled = spec_decode
@@ -1130,6 +1174,27 @@ class DecodeEngine:
             max_new_tokens=int(max_new_tokens),
             engine=self.engine_id)
         return req
+
+    #: sampling keys made in one device call
+    KEY_BLOCK = 1024
+
+    def _sample_keydata(self, n: int) -> np.ndarray:
+        """The key data of this engine's ``n``-th request,
+        ``fold_in(key(seed), n)``, from the host's copy of its block of
+        ordinals. The device is asked once a block (the first at
+        construction); the block before stays, for a caller that drew
+        its ordinal just before the boundary."""
+        first = n - n % self.KEY_BLOCK
+        with self._key_lock:
+            block = self._key_blocks.get(first)
+            if block is None:
+                block = np.asarray(self._key_block_fn(jnp.arange(
+                    first, first + self.KEY_BLOCK, dtype=jnp.uint32)))
+                self._key_blocks = {
+                    f: b for f, b in self._key_blocks.items()
+                    if f == first - self.KEY_BLOCK}
+                self._key_blocks[first] = block
+        return block[n - first].copy()
 
     def _enqueue(self, req: ServingRequest) -> None:
         _flight.record("serving_submit", request_id=req.request_id,
@@ -1225,6 +1290,22 @@ class DecodeEngine:
         paged kernel's calls visit (``ctx_pages`` beside ``ctx_tokens``)."""
         return int((-(-np.asarray(positions) // self.page_size)).sum())
 
+    def _state_bytes(self) -> int:
+        return sum(int(a.nbytes)
+                   for a in jax.tree_util.tree_leaves(self._state))
+
+    def _window_store(self) -> Dict[str, int]:
+        """The cache's second kind beside the pool's numbers: the bytes
+        the window layers' per-slot rings take, and what of them the
+        most slots ever live at once used. Nothing where the model has
+        no window layer."""
+        if self._window is None:
+            return {}
+        total = self._state_bytes()
+        return {"window_bytes": total,
+                "window_high_water_bytes":
+                    total // self.slots * self._slots_high_water}
+
     def stats(self) -> Dict[str, Any]:
         return {
             "engine_id": self.engine_id,
@@ -1247,8 +1328,7 @@ class DecodeEngine:
             "prefill_bucket_tokens": self.n_prefill_bucket_tokens,
             "tokens": self.n_tokens,
             **self._expert_totals,
-            "state_bytes": (int(self._state.nbytes)
-                            if self._state is not None else 0),
+            "state_bytes": self._state_bytes(),
             "active_slots": int(self._active.sum()),
             "queued": self._queue.qsize() + len(self._waiting),
             "avg_occupancy": (self._occupancy_sum / self.n_steps
@@ -1257,7 +1337,8 @@ class DecodeEngine:
                          "allocated": self.pool.allocated,
                          "high_water": self.pool.high_water,
                          "shared": self.pool.shared_pages(),
-                         "page_bytes": self.pool.bytes_per_page()},
+                         "page_bytes": self.pool.bytes_per_page(),
+                         **self._window_store()},
             "warm_pool": {"hits": self._warm.hits,
                           "misses": self._warm.misses,
                           "adopted": self._warm.adopted},
@@ -1432,6 +1513,8 @@ class DecodeEngine:
                                    seconds=hang)
                     time.sleep(hang)
                 self._process_aborts()
+                if self._ahead is not None:
+                    self._await_join()
                 self._admit_waiting()
                 if not self._active.any():
                     try:
@@ -1452,6 +1535,7 @@ class DecodeEngine:
                 self._dead = RuntimeError("engine has been shut down")
 
     def _fail_pending(self, err: BaseException) -> None:
+        self._ahead = None
         for s in range(self.slots):
             req = self._slot_req[s]
             if req is not None:
@@ -1809,7 +1893,10 @@ class DecodeEngine:
         self._temps[s] = req.temperature
         self._keydata[s] = req._keydata
         self._active[s] = True
+        self._slots_high_water = max(self._slots_high_water,
+                                     int(self._active.sum()))
         self._dev_static = None      # roster changed: re-upload
+        self._roster_gen += 1
         if self._prefix is not None:
             # index this prompt's full pages (freshly prefilled ones
             # AND, for a session resume, committed history pages) for
@@ -1837,9 +1924,11 @@ class DecodeEngine:
         """tables/active/temps change only on join/evict: upload once
         per roster change, not once per dispatch."""
         if self._dev_static is None:
-            self._dev_static = (jnp.asarray(self._tables),
-                                jnp.asarray(self._active),
-                                jnp.asarray(self._temps))
+            # copies: a burst may be in flight when the next join or
+            # eviction writes these arrays in place
+            self._dev_static = (jnp.asarray(self._tables.copy()),
+                                jnp.asarray(self._active.copy()),
+                                jnp.asarray(self._temps.copy()))
         return self._dev_static
 
     #: dispatches chained device-to-device per burst before tokens are
@@ -1993,6 +2082,143 @@ class DecodeEngine:
                 self.n_tokens - emitted0, engine=self.engine_id)
         return True
 
+    def _open_burst(self, read: Optional["_Burst"] = None,
+                    on_device: bool = False) -> "_Burst":
+        """The roster, budget and slot state of the next decode burst.
+        ``read`` is the burst before it, whose tokens are not handed
+        out yet: the slots it brings to their budget's end are left
+        out as ``_evict`` will leave them (an all-null table row, not
+        active), so the burst can be on the device while the tokens go
+        out. The running pos / tok / keys come from the host's copies
+        or, ``on_device``, straight from ``read``'s own outputs, before
+        the host has read them (``read`` decoded for every live slot
+        then). Uploads are copies: ``_admit`` and ``_evict`` write
+        these arrays in place, and a burst may be in flight by then."""
+        emitted = self._slot_emitted
+        if read is not None:
+            emitted = emitted.copy()
+            emitted[read.idx] += read.steps
+        idx = np.flatnonzero(self._active)
+        left = np.asarray([self._slot_req[s].max_new_tokens
+                           for s in idx], np.int64) - emitted[idx]
+        going = left > 0
+        ending, idx = idx[~going], idx[going]
+        b = _Burst()
+        b.idx = idx
+        b.reqs = [self._slot_req[s] for s in idx]
+        b.rem = int(left[going].min()) if len(idx) else 0
+        b.has_eos = any(r.eos_id is not None for r in b.reqs)
+        b.after_end = bool(len(ending))
+        if len(ending):
+            tables, active, temps = (self._tables.copy(),
+                                     self._active.copy(),
+                                     self._temps.copy())
+            tables[ending], active[ending], temps[ending] = 0, False, 0.0
+            b.roster = (jnp.asarray(tables), jnp.asarray(active),
+                        jnp.asarray(temps))
+        else:
+            b.roster = self._dev_slot_state()
+        if on_device:
+            b.pos_live = (read.pos_live + read.steps)[going]
+            b.pos, b.tok, b.kd = read.pos, read.tok, read.kd
+        else:
+            b.pos_live = self._pos[idx].astype(np.int64)
+            b.pos = jnp.asarray(self._pos.copy())
+            b.tok = jnp.asarray(self._tok.copy())
+            b.kd = jnp.asarray(self._keydata.copy())
+        b.chunks, b.counts, b.steps = [], [], 0
+        b.gen = self._roster_gen
+        # entered when the scheduler takes the burst up; a chunk sent
+        # ahead of that is under it all the same, by its id
+        b.span = _telemetry.span(
+            "engine.burst", metric=_telemetry.SERVING_DECODE_STEP_SECONDS,
+            engine=self.engine_id)
+        return b
+
+    def _dispatch_chunk(self, b: "_Burst") -> None:
+        """The burst's next chunk: the largest power of two of steps
+        that cannot pass the nearest request's end. The chunk sent
+        ahead around a slot just freed is JOIN_CHUNK steps at most: the
+        caller's next request is admitted behind it (``_await_join``),
+        and the slot stands empty for as long as it runs."""
+        most = self.max_chunk
+        if b.after_end and not b.chunks:
+            most = min(most, self.JOIN_CHUNK)
+        k = 1
+        while k * 2 <= min(b.rem - b.steps, most):
+            k *= 2
+        live, steps = len(b.idx), b.steps
+        # step j of the chunk attends the pos + steps + j + 1
+        # positions each live slot holds by then
+        ctx = k * (int(b.pos_live.sum()) + live * steps) \
+            + live * (k * (k + 1) // 2)
+        held = b.pos_live[:, None] + steps + np.arange(1, k + 1)
+        pages = self._pages_held(held)
+        self.n_attended_tokens += ctx
+        self.n_attended_pages += pages
+        attrs = {}
+        if self._window is not None:
+            # what a window layer's calls had to read of it
+            attrs["ctx_window_tokens"] = int(
+                np.minimum(held, self._window).sum())
+        tables, active, temps = b.roster
+        with _telemetry.span("engine.dispatch", parent=b.span.id, k=k,
+                             live=live, ctx_tokens=ctx, ctx_pages=pages,
+                             **attrs):
+            (kvt, toks, b.pos, b.tok, b.kd, counts) = self._warm.run(
+                ("decode", k), self._decode_fallbacks[k],
+                self._decode_params, self._cache(), tables,
+                b.pos, active, b.tok, b.kd, temps)
+        self._rebind(kvt)
+        b.chunks.append(toks)
+        if counts is not None:
+            b.counts.append(counts)
+        b.steps += k
+        self.n_dispatches += 1
+
+    def _run_ahead(self, read: "_Burst",
+                   on_device: bool) -> Optional["_Burst"]:
+        """The next burst's first chunk, sent to the device BEFORE the
+        tokens of ``read`` are handed out, so that handing them out
+        (every caller's thread wakes) happens while the device works;
+        ``on_device``, before the host has even read them, so that the
+        device goes from one burst to the next with no wait between.
+        Only where the roster after ``read`` is known without its
+        tokens (no request ends on an ``eos_id``, none is being
+        cancelled) and nobody could join first (a free slot and a
+        queued request: the admission goes first, as before)."""
+        if (self._spec is not None or read.has_eos or self._aborts
+                or self._stop.is_set()):
+            return None
+        b = self._open_burst(read, on_device)
+        if not len(b.idx) or (len(b.idx) < self.slots and (
+                self._waiting or not self._queue.empty())):
+            return None
+        self._dispatch_chunk(b)
+        return b
+
+    #: steps of the chunk sent ahead around a slot just freed: long
+    #: enough for a caller's thread to wake and submit its next request
+    #: (a step is milliseconds), short enough that the slot is not
+    #: empty for long
+    JOIN_CHUNK = 2
+
+    def _await_join(self) -> None:
+        """A request has just ended and a chunk is on the device: a
+        caller that waits for each reply sends its next request now, so
+        the scheduler waits for it here, for as long as the device is
+        busy anyway, instead of finding the queue empty by microseconds
+        and decoding a further chunk around the free slot."""
+        b = self._ahead
+        if not b.after_end or self._active.all() or self._waiting:
+            return
+        while not (b.chunks[-1].is_ready() or self._stop.is_set()):
+            try:
+                self._waiting.append(self._queue.get(timeout=0.001))
+                return
+            except _queue.Empty:
+                pass
+
     def _decode_step(self) -> None:
         """One decode BURST: chain chunk dispatches device-to-device —
         pos/tok/keys flow from one executable's output straight into
@@ -2000,78 +2226,57 @@ class DecodeEngine:
         to the host only when the roster can change: the nearest
         request completion, an active eos_id (completion unpredictable
         -> single chunk), or a queued request that could join a free
-        slot."""
+        slot. The next burst's first chunk goes out before this
+        burst's tokens are read and handed to the callers
+        (``_run_ahead``); the next call takes that burst up."""
         if self._spec is not None and self._spec_burst():
             return
-        active_idx = np.flatnonzero(self._active)
-        min_rem = min(
-            self._slot_req[s].max_new_tokens - int(self._slot_emitted[s])
-            for s in active_idx)
-        has_eos = any(self._slot_req[s].eos_id is not None
-                      for s in active_idx)
-        free_slots = not self._active.all()
-        live = int(len(active_idx))
-        occupancy = float(live) / self.slots
-        pos_live = self._pos[active_idx].astype(np.int64)
-        pos_sum = int(pos_live.sum())
-        with _telemetry.span(
-                "engine.burst",
-                metric=_telemetry.SERVING_DECODE_STEP_SECONDS,
-                engine=self.engine_id) as burst:
-            tables, active, temps = self._dev_slot_state()
-            pos = jnp.asarray(self._pos)
-            tok = jnp.asarray(self._tok)
-            kd = jnp.asarray(self._keydata)
-            chunks: List[Any] = []
-            expert_counts: List[Any] = []   # a model's own counts
-            steps = 0
-            while True:
-                k = 1
-                while k * 2 <= min(min_rem - steps, self.max_chunk):
-                    k *= 2
-                # step j of the chunk attends the pos + steps + j + 1
-                # positions each live slot holds by then
-                ctx = k * (pos_sum + live * steps) \
-                    + live * (k * (k + 1) // 2)
-                pages = self._pages_held(
-                    pos_live[:, None] + steps + np.arange(1, k + 1))
-                self.n_attended_tokens += ctx
-                self.n_attended_pages += pages
-                with _telemetry.span("engine.dispatch", k=k, live=live,
-                                     ctx_tokens=ctx, ctx_pages=pages):
-                    (kvt, toks, pos, tok, kd, counts) = self._warm.run(
-                        ("decode", k), self._decode_fallbacks[k],
-                        self._decode_params, self._cache(), tables,
-                        pos, active, tok, kd, temps)
-                self._rebind(kvt)
-                chunks.append(toks)
-                if counts is not None:
-                    expert_counts.append(counts)
-                steps += k
-                self.n_dispatches += 1
-                if has_eos or steps >= min_rem \
-                        or len(chunks) >= self.MAX_BURST_DISPATCHES:
-                    break
-                if free_slots and not self._queue.empty():
-                    break      # a waiting request can join a free slot
+        b, self._ahead = self._ahead, None
+        if b is None:
+            b = self._open_burst()
+        with b.span as burst:
+            # a burst begun ahead whose roster has changed since (a
+            # request joined, one was cancelled) is read at once
+            stale = b.gen != self._roster_gen
+            free_slots = not self._active.all()
+            occupancy = float(len(b.idx)) / self.slots
+            while not b.chunks or not (
+                    stale or b.has_eos or b.steps >= b.rem
+                    or len(b.chunks) >= self.MAX_BURST_DISPATCHES
+                    # a waiting request can join a free slot
+                    or (free_slots and not self._queue.empty())):
+                self._dispatch_chunk(b)
+            steps = b.steps
+            # the slots that still serve the request they decoded for:
+            # one cancelled meanwhile has its tokens dropped, and a
+            # request that joined meanwhile keeps the state _admit
+            # gave its slot
+            keep = [i for i, s in enumerate(b.idx)
+                    if self._slot_req[s] is b.reqs[i]]
+            b.idx, b.pos_live = b.idx[keep], b.pos_live[keep]
+            active_idx = b.idx
+            # the roster as the burst knew it: the next one goes out
+            # before this one is read. Else it waits for the read,
+            # which gives the host's copies the slots' new state
+            ahead = None if stale else self._run_ahead(b, True)
             # ONE host sync for the whole burst
             with _telemetry.span("engine.sync", steps=steps,
-                                 dispatches=len(chunks)) as sync:
-                toks = np.concatenate([np.asarray(c) for c in chunks],
-                                      axis=1)
-                # np.array (copy): device views are read-only, and
-                # _admit writes newly-joined slots' state into these
-                # buffers in place
-                self._pos = np.array(pos)
-                self._tok = np.array(tok)
-                self._keydata = np.array(kd)
-                if expert_counts:
-                    sync.set(**self._count_experts(np.concatenate(
-                        [np.asarray(c) for c in expert_counts])))
+                                 dispatches=len(b.chunks)) as sync:
+                # every copy to the host started before the first is
+                # waited for
+                chunks, pos, tok, kd, counts = jax.device_get(
+                    (b.chunks, b.pos, b.tok, b.kd, b.counts))
+                toks = np.concatenate(chunks, axis=1)
+                self._pos[active_idx] = pos[active_idx]
+                self._tok[active_idx] = tok[active_idx]
+                self._keydata[active_idx] = kd[active_idx]
+                if counts:
+                    sync.set(**self._count_experts(
+                        np.concatenate(counts)))
             self.n_steps += steps
             self._occupancy_sum += occupancy * steps
             _flight.record("serving_burst", engine=self.engine_id,
-                           steps=steps, dispatches=len(chunks),
+                           steps=steps, dispatches=len(b.chunks),
                            occupancy=round(occupancy, 4))
             if _tracing.enabled():
                 for s in active_idx:
@@ -2089,6 +2294,8 @@ class DecodeEngine:
                 reg.counter(_telemetry.SERVING_DECODE_STEPS,
                             "fixed-shape decode steps executed").inc(
                     steps, engine=self.engine_id)
+            if stale:
+                ahead = self._run_ahead(b, False)
             emitted0 = self.n_tokens
             with _telemetry.span("engine.emit") as emit:
                 for s in active_idx:
@@ -2097,6 +2304,10 @@ class DecodeEngine:
                             break          # finished on eos mid-chunk
                         self._emit(int(s), int(toks[s, k]))
                 emit.set(tokens=self.n_tokens - emitted0)
+            if ahead is not None:
+                # the roster it was given is the one the hand-out left
+                ahead.gen = self._roster_gen
+                self._ahead = ahead
         self.last_progress = time.monotonic()
         if _telemetry.enabled() and self.n_tokens > emitted0:
             _telemetry.MetricsRegistry.get_default().counter(
@@ -2144,6 +2355,7 @@ class DecodeEngine:
         self._temps[s] = 0.0
         self._active[s] = False
         self._dev_static = None      # roster changed: re-upload
+        self._roster_gen += 1
         self.n_completed += 1
         req._finish(reason, error)
         _flight.record("serving_evict", request_id=req.request_id,

@@ -202,10 +202,14 @@ class TestFailover:
             doomed = s1.routing["replica"]
             idx = next(i for i, r in enumerate(fl._replicas)
                        if r.engine.engine_id == doomed)
-            # a long request pinned to the doomed replica via affinity,
-            # plus bystanders spread across the fleet
-            long_p = rng.integers(0, VOCAB, (4,)).astype(np.int32)
-            victim = fl.submit(long_p, 56, session_id="conv2")
+            # a long request drawn to the doomed replica by the page of
+            # its prompt that s1 left in that replica's prefix cache
+            # (a new session has no affinity yet, and the pages s1's
+            # session pins there would send it elsewhere), plus
+            # bystanders spread across the fleet
+            long_p = np.concatenate(
+                [t1, rng.integers(0, VOCAB, (4,)).astype(np.int32)])
+            victim = fl.submit(long_p, 52, session_id="conv2")
             others = [fl.submit(
                 rng.integers(0, VOCAB, (6,)).astype(np.int32), 8)
                 for _ in range(4)]
@@ -213,6 +217,7 @@ class TestFailover:
             while not victim.tokens and time.time() < deadline:
                 time.sleep(0.0002)
             assert victim.tokens, "victim never started"
+            assert victim.routing["replica"] == doomed
             # stall the doomed scheduler before the kill: a fully warm
             # compile cache can otherwise finish the victim between
             # the progress poll and the kill, leaving nothing in
@@ -222,7 +227,7 @@ class TestFailover:
             fl.kill_replica(idx)
             got = victim.result(timeout=120)
             np.testing.assert_array_equal(
-                got, _solo(model, params, long_p, 56))
+                got, _solo(model, params, long_p, 52))
             for o in others:
                 o.result(timeout=120)
             assert fl.alive_replicas() == 1

@@ -92,17 +92,20 @@ def _programs(eng):
     cache, dec, par = eng._cache(), eng._decode_params, eng.params
     slot = [((S, P), i32), ((S,), i32), ((S,), bool), ((S,), i32)]
     tail = [((S, kw), u32), ((S,), f32)]
-    return {
+    out = {
         "chunk": (eng._decode_jits[CHUNK], [dec, cache, *slot, *tail]),
-        "verify": (eng._verify_jit, [
-            dec, cache, *slot, ((S, SPEC_K), i32), ((S,), i32), *tail]),
-        "suffix_prefill": (eng._prefix_prefill_jit, [
-            par, cache, ((BUCKET,), i32), ((P,), i32), ((), i32),
-            ((), i32)]),
         "prefill": (eng._prefill_jit, [
             par, cache, ((1, BUCKET), i32),
             ((BUCKET // eng.page_size,), i32), ((), i32), ((), i32)]),
     }
+    if eng._spec is not None:
+        out["verify"] = (eng._verify_jit, [
+            dec, cache, *slot, ((S, SPEC_K), i32), ((S,), i32), *tail])
+    if eng._reuse:
+        out["suffix_prefill"] = (eng._prefix_prefill_jit, [
+            par, cache, ((BUCKET,), i32), ((P,), i32), ((), i32),
+            ((), i32)])
+    return out
 
 
 def _compiled_text(jitted, args, one_chip, layouts) -> str:
@@ -340,3 +343,106 @@ def test_paged_kernel_compiles_at_published_widths(one_chip, cell, slots,
                _sds((slots,), i32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo and "paged_attention" in hlo
     assert pool_copies(hlo, pool)["all"] == 0
+
+
+# ------------- a cache of two kinds: K-EXAONE-236B-A23B's pool and rings
+#: the cell's stores (BENCHMARK.json): the global layer's pool, and the
+#: window layers' rings ``[layers, slots, ring pages, page, KV x head]``
+EXAONE_POOL = (1, 1 + 64 * 320, 16, 8 * 128)
+EXAONE_RINGS = (4, 64, 9, 16, 8 * 128)
+
+
+@functools.lru_cache(maxsize=None)
+def _two_kind_engine():
+    """Never started, only traced: window (32) and global layers, a
+    group of 2 query heads on 2 KV heads of 64 (rows of 128 lanes, the
+    least that rest row-major), 2 of 8 experts held."""
+    from deeplearning4j_tpu.models.exaone_moe import (ExaoneMoeConfig,
+                                                      ExaoneMoeLM)
+
+    L, G = "sliding_attention", "full_attention"
+    model = ExaoneMoeLM(ExaoneMoeConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=5,
+        layer_types=(L, L, L, G, L),
+        mlp_layer_types=("dense",) + ("sparse",) * 4, sliding_window=32,
+        num_experts=2, n_routed_experts=8, expert_offset=2,
+        num_experts_per_tok=2, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=64, max_position_embeddings=1024),
+        jnp.bfloat16)
+    return DecodeEngine(
+        model, model.init_params(jax.random.key(0)), slots=SLOTS,
+        page_size=16, max_context=1024, attn_mode="pallas",
+        max_chunk=CHUNK, warm_start=False)
+
+
+@pytest.mark.parametrize("layouts", ["pinned", "at_rest"])
+@pytest.mark.parametrize("program", ["chunk", "prefill"])
+def test_two_kind_cache_is_never_copied(one_chip, program, layouts):
+    """The decode chunk and the prefill of a model with window and
+    global layers copy neither store: not the global layers' pool, not
+    the window layers' rings, nor the rings seen as a pool (a merge of
+    leading dimensions). The window layers' attention is the paged
+    kernel, one trace of it for each kind of layer."""
+    eng = _two_kind_engine()
+    jitted, args = _programs(eng)[program]
+    hlo = _compiled_text(jitted, args, one_chip, layouts)
+    rings = eng._state["k"]
+    Lw, S, R, ps, W = rings.shape
+    assert (Lw, S, R, ps, W) == (4, SLOTS, 3, 16, 128)
+    for store in (eng.pool.k, rings,
+                  jax.ShapeDtypeStruct((Lw, S * R, ps, W), rings.dtype)):
+        assert pool_copies(hlo, store) == {"loop": 0, "all": 0}
+    if program == "chunk":
+        assert "paged_attention" in hlo and "moe_experts" in hlo
+
+
+@pytest.mark.parametrize("shape", [EXAONE_POOL, EXAONE_RINGS],
+                         ids=["global-pool", "window-rings"])
+def test_both_stores_of_the_two_kind_cache_rest_row_major(one_chip, shape):
+    rests = jax.jit(lambda x: x).lower(jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=one_chip)) \
+        .compile().input_formats[0][0]
+    assert tuple(rests.layout.major_to_minor) == tuple(range(len(shape)))
+
+
+@pytest.mark.parametrize("window", [None, 128], ids=["global", "window"])
+def test_paged_kernel_compiles_for_a_group_of_8_heads_of_128(one_chip,
+                                                             window):
+    """K-EXAONE-236B-A23B's decode step: 64 slots, 64 query heads on 8
+    KV heads of 128 (a row of 1,024 lanes), through a table of 320
+    pages for the global layer and through a ring of 9 for a window
+    layer (``window=128``: the walk starts at the window's first page);
+    the stores go in as they rest."""
+    from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    Lw, S, R, ps, W = EXAONE_RINGS
+    shape = EXAONE_POOL if window is None else (Lw, S * R, ps, W)
+    pool = _sds(shape, bf16, one_chip)
+    hlo = jax.jit(lambda q, kv, t, b: paged_attention(
+        q, kv, 0, t, b, mode="pallas", window=window)) \
+        .lower(_sds((S, 1, 64, 128), bf16, one_chip),
+               {"k": pool, "v": pool},
+               _sds((S, 320 if window is None else R), i32, one_chip),
+               _sds((S,), i32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "paged_attention" in hlo
+    assert pool_copies(hlo, pool)["all"] == 0
+
+
+@pytest.mark.parametrize("rows,k,n", [(512, 6144, 2048), (512, 2048, 6144),
+                                      (16384, 6144, 2048)],
+                         ids=["decode-up", "decode-down", "prefill-up"])
+def test_grouped_matmul_compiles_for_16_held_experts(one_chip, rows, k, n):
+    """64 slots x 8 experts a token (or a bucket of 2,048) over the 16
+    experts of 6144 x 2048 a chip holds: rows past the held experts'
+    belong to no group."""
+    from deeplearning4j_tpu.ops.grouped_matmul_pallas import grouped_matmul
+
+    bf16 = jnp.bfloat16
+    hlo = jax.jit(lambda a, b, c: grouped_matmul(a, b, c, mode="pallas")) \
+        .lower(_sds((rows, k), bf16, one_chip),
+               _sds((16, k, n), bf16, one_chip),
+               _sds((16,), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "moe_experts" in hlo
+    assert not re.search(r"bf16\[16,%d,%d\][^\n]* copy\(" % (k, n), hlo)

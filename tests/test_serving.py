@@ -175,6 +175,179 @@ class TestEngineGreedyParity:
                 got, _solo(model, params, p, new))
 
 
+# ------------------------------------- a burst's first chunk sent ahead
+class TestRunAhead:
+    """Once a burst's tokens are read, the next burst's first chunk goes
+    to the device BEFORE the tokens are handed to the callers
+    (``DecodeEngine._run_ahead``). What that must not change: any
+    request's tokens, whoever joins, ends or is cancelled while the
+    chunk is in flight."""
+
+    @staticmethod
+    def _watch(eng):
+        """Record every burst ``_run_ahead`` begins."""
+        begun, inner = [], eng._run_ahead
+
+        def run_ahead(read, on_device):
+            b = inner(read, on_device)
+            if b is not None:
+                begun.append(b)
+            return b
+
+        eng._run_ahead = run_ahead
+        return begun
+
+    def test_callers_that_wait_for_each_reply_match_solo(self, model,
+                                                         params):
+        """Closed-loop callers (as many as slots): a request ends, the
+        next chunk is already out for the others, the caller's next
+        request joins while it is in flight."""
+        rng = np.random.default_rng(5)
+        plans = [[(int(rng.integers(3, 12)), int(n)) for n in ns]
+                 for ns in ([9, 4, 13], [5, 11, 7], [14, 3, 6])]
+        prompts = [[rng.integers(0, VOCAB, (t0,)).astype(np.int32)
+                    for t0, _ in plan] for plan in plans]
+        outs = [[None] * 3 for _ in plans]
+        with DecodeEngine(model, params, slots=3, page_size=8) as eng:
+            begun = self._watch(eng)
+
+            def caller(c):
+                for i, (_, n) in enumerate(plans[c]):
+                    outs[c][i] = eng.submit(prompts[c][i], n).result(
+                        timeout=120)
+
+            threads = [threading.Thread(target=caller, args=(c,))
+                       for c in range(3)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=180)
+            assert eng.stats()["completed"] == 9
+            assert eng.pool.allocated == 0
+        # the path under test ran, with and without a request ending
+        assert any(b.after_end for b in begun)
+        for c, plan in enumerate(plans):
+            for i, (_, n) in enumerate(plan):
+                np.testing.assert_array_equal(
+                    outs[c][i], _solo(model, params, prompts[c][i], n))
+
+    def test_cancelled_with_a_chunk_in_flight_and_its_slot_reused(
+            self, model, params):
+        """A request cancelled while the chunk sent ahead still decodes
+        for it: its tokens of that chunk are dropped, the request that
+        takes its slot at the same pass keeps the state its admission
+        gave the slot, the neighbour is not disturbed."""
+        rng = np.random.default_rng(6)
+        p_cancel, p_stay, p_next = (
+            rng.integers(0, VOCAB, (t0,)).astype(np.int32)
+            for t0 in (5, 7, 6))
+        with DecodeEngine(model, params, slots=2, page_size=8,
+                          max_chunk=2) as eng:
+            inner, state = eng._run_ahead, {}
+
+            def run_ahead(read, on_device):
+                b = inner(read, on_device)
+                if b is not None and "next" not in state \
+                        and len(state["cancel"].tokens) >= 3:
+                    # the chunk is out for both slots: cancel one and
+                    # queue its successor before the scheduler's next
+                    # pass (aborts first, then admissions, then the
+                    # burst is taken up)
+                    assert state["cancel"].cancel()
+                    state["next"] = eng.submit(p_next, 9)
+                return b
+
+            eng._run_ahead = run_ahead
+            state["cancel"] = eng.submit(p_cancel, 30)
+            stay = eng.submit(p_stay, 25)
+            got_stay = stay.result(timeout=120)
+            got_cancel = state["cancel"].result(timeout=120)
+            got_next = state["next"].result(timeout=120)
+            assert state["cancel"].finish_reason == "cancelled"
+            assert eng.pool.allocated == 0
+        full = _solo(model, params, p_cancel, 30)
+        assert 3 <= len(got_cancel) < 30
+        np.testing.assert_array_equal(got_cancel, full[:len(got_cancel)])
+        np.testing.assert_array_equal(
+            got_stay, _solo(model, params, p_stay, 25))
+        np.testing.assert_array_equal(
+            got_next, _solo(model, params, p_next, 9))
+
+    def test_a_submit_asks_nothing_of_the_device(self, model, params,
+                                                 monkeypatch):
+        """The device works through its calls in order: a submit that
+        folded its sampling key there would wait for the chunk in
+        flight, and the slot its request is to take would stand empty
+        meanwhile. The keys come from the host's copy of a block, and
+        are the ones the fold gives."""
+        with DecodeEngine(model, params, slots=2, page_size=8,
+                          seed=7) as eng:
+            for n in (0, 5, 1023, 1024, 3000, 1023):
+                np.testing.assert_array_equal(
+                    eng._sample_keydata(n),
+                    np.asarray(jax.random.key_data(jax.random.fold_in(
+                        jax.random.key(7), n))))
+
+            def no_fold(*a, **k):
+                raise AssertionError("a submit folded a key on the device")
+
+            monkeypatch.setattr(jax.random, "fold_in", no_fold)
+            p = np.asarray([1, 2, 3, 4], np.int32)
+            got = eng.submit(p, 5).result(timeout=60)
+        np.testing.assert_array_equal(got, _solo(model, params, p, 5))
+
+    def test_the_chunk_around_a_freed_slot_is_short(self, model, params):
+        """The chunk sent ahead when a request has just ended is
+        JOIN_CHUNK steps at most, whatever the others have left."""
+        rng = np.random.default_rng(8)
+        p_short, p_long = (rng.integers(0, VOCAB, (5,)).astype(np.int32)
+                           for _ in range(2))
+        with DecodeEngine(model, params, slots=2, page_size=8) as eng:
+            begun = self._watch(eng)
+            long_req = eng.submit(p_long, 40)
+            eng.submit(p_short, 4).result(timeout=60)
+            long_req.result(timeout=60)
+        after = [b for b in begun if b.after_end]
+        assert after and all(
+            int(b.chunks[0].shape[1]) <= DecodeEngine.JOIN_CHUNK
+            for b in after)
+
+    def test_not_where_the_roster_is_not_known_ahead(self, model, params):
+        """A request that may end on an eos_id, or a speculating engine:
+        the next roster is known only from the tokens, so nothing is
+        sent ahead."""
+        p = np.asarray([1, 2, 3, 4], np.int32)
+        with DecodeEngine(model, params, slots=2, page_size=8) as eng:
+            begun = self._watch(eng)
+            eng.submit(p, 40, eos_id=VOCAB).result(timeout=60)
+            assert not begun
+            # 39 decode steps: a burst of four chunks of 8, then more
+            eng.submit(p, 40).result(timeout=60)
+            assert begun
+        with DecodeEngine(model, params, slots=2, page_size=8,
+                          spec_decode=2) as eng:
+            begun = self._watch(eng)
+            eng.submit(p, 40, spec_decode=False).result(timeout=60)
+            assert not begun
+
+    def test_a_queued_request_joins_before_anything_is_sent_ahead(
+            self, model, params):
+        """More callers than slots: when a request ends and another
+        waits, the admission goes first, as before."""
+        rng = np.random.default_rng(7)
+        prompts = [rng.integers(0, VOCAB, (6,)).astype(np.int32)
+                   for _ in range(5)]
+        with DecodeEngine(model, params, slots=1, page_size=8) as eng:
+            begun = self._watch(eng)
+            reqs = [eng.submit(q, 6) for q in prompts]
+            outs = [r.result(timeout=120) for r in reqs]
+        # a request's own bursts may run ahead; none was begun with the
+        # slot just freed and a request waiting for it
+        assert not any(b.after_end for b in begun)
+        for q, got in zip(prompts, outs):
+            np.testing.assert_array_equal(got, _solo(model, params, q, 6))
+
+
 # ------------------------------------------------------- AOT warm pool
 class TestWarmPool:
     def _compiles(self, site):
