@@ -336,6 +336,27 @@ class CausalLM:
         x = self._run(params, self._embed(params, toks, at), cache)
         return cache.kv, self._head(x, params).astype(jnp.float32)
 
+    def serving_params(self, params):
+        """The tree a serving engine holds: every floating leaf in the
+        compute dtype. Each product above casts a parameter there at
+        its point of use (``_embed``, ``_head``, ``_ln``,
+        ``int8_matmul``), so the cast made once gives the values the
+        cast made a call gives, and a program handed this tree holds no
+        cast of a weight (774 M of them a dispatch at GPT-2-large
+        widths, PERF.md PR 33). A leaf already there is returned as it
+        is, the same array; int8 codes and their scales are left
+        alone. Idempotent. Training keeps its float32 masters: this is
+        a copy, made where a model is put to serve."""
+        cd = jnp.dtype(self._cdtype)
+
+        def rest(leaf):
+            if (is_int8(leaf) or leaf.dtype == cd
+                    or not jnp.issubdtype(leaf.dtype, jnp.floating)):
+                return leaf
+            return leaf.astype(cd)
+
+        return jax.tree_util.tree_map(rest, params, is_leaf=is_int8)
+
     def quantize_decode_params(self, params):
         """int8 weight-only tree for the decode step: every 2-D matmul
         weight gets per-output-channel scales; tok_emb is per-ROW

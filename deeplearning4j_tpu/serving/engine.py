@@ -499,14 +499,30 @@ class DecodeEngine:
                                                     self.page_size)
         if n_pages is None:
             n_pages = 1 + self.slots * self.pages_per_slot
-        self.params = (jax.device_put(params, device)
-                       if device is not None else jax.device_put(params))
         self.quantization = quantization
         if quantization not in (None, "int8"):
             raise ValueError(f"unknown quantization {quantization!r} "
                              "(expected None or 'int8')")
-        self._decode_params = (model.quantize_decode_params(self.params)
-                               if quantization == "int8" else self.params)
+        given = (jax.device_put(params, device)
+                 if device is not None else jax.device_put(params))
+        #: the trees the programs take, at rest as the model serves
+        #: them (``serving_params``, where it brings one: a float32
+        #: GPT-2 becomes a copy in its compute dtype, made once here
+        #: and not inside every dispatch). The tree as given is not
+        #: kept: it lives on only if the caller holds it. int8 codes
+        #: and scales come from the tree as given.
+        at_rest = getattr(model, "serving_params", lambda tree: tree)
+        self.params = at_rest(given)
+        self._decode_params = (
+            at_rest(model.quantize_decode_params(given))
+            if quantization == "int8" else self.params)
+        del given
+        #: bytes of every array those trees hold, one shared by both
+        #: counted once (``stats()["weight_bytes"]``)
+        leaves = jax.tree_util.tree_leaves(
+            (self.params, self._decode_params))
+        self._weight_bytes = sum(
+            int(a.nbytes) for a in {id(a): a for a in leaves}.values())
         #: canonical kv_dtype (None = pool in the compute dtype) and
         #: the attention implementation, both resolved ONCE here and
         #: baked statically into the step builders — every executable
@@ -1329,6 +1345,7 @@ class DecodeEngine:
             "tokens": self.n_tokens,
             **self._expert_totals,
             "state_bytes": self._state_bytes(),
+            "weight_bytes": self._weight_bytes,
             "active_slots": int(self._active.sum()),
             "queued": self._queue.qsize() + len(self._waiting),
             "avg_occupancy": (self._occupancy_sum / self.n_steps

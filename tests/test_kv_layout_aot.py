@@ -24,6 +24,14 @@ copied, both found on the chip first (PERF.md section 6):
   the control finds the four copies around the page this repo had
   before, ``[H, page_size, hd]`` with ``hd`` half a lane tile.
 
+The same compile answers a second question (PR 33: 21% of the GPT-2
+serving window): does a program cast a WEIGHT? ``CausalLM`` casts each
+parameter to its compute dtype where it is used; an engine that held
+float32 masters compiled to programs that read and rewrote all of them
+once a dispatch. The engine now holds ``serving_params``' copy, so its
+programs hold no ``convert`` with a weight's shape; the controls hand
+the same programs, and ``decode_step`` itself, the float32 tree.
+
 The kernel's grid is (KV head blocks, live visits): a visit holds every
 KV head of eight pages of one sequence, the heads along the lanes, and
 the visits are as many as the pages the sequences hold (PR 29); the
@@ -168,6 +176,60 @@ def test_serving_program_never_copies_a_pool(one_chip, program,
     hlo = _compiled_text(jitted, args, one_chip, layouts)
     assert "tpu_custom_call" in hlo or program == "prefill"
     assert pool_copies(hlo, eng.pool.k) == {"loop": 0, "all": 0}
+
+
+# ------------------------------ the weights rest in the compute dtype
+def weight_casts(hlo: str, eng) -> dict:
+    """``convert`` instructions of an optimised HLO module whose result
+    is a bf16 array of a matrix parameter's shape, by parameter name
+    (``pos_emb`` [1024, 256] counts under ``w2``, whose shape it has)."""
+    lp = eng.params["layers"][0]
+    shapes = {"tok_emb": eng.params["tok_emb"].shape,
+              **{k: lp[k].shape for k in ("wqkv", "wo", "w1", "w2")}}
+    return {name: len(re.findall(
+        r"= bf16\[%s\]\S* convert\(" % ",".join(map(str, shape)), hlo))
+        for name, shape in shapes.items()}
+
+
+@pytest.mark.parametrize("program,given", [
+    ("chunk", "served"), ("prefill", "served"),
+    ("chunk", "float32"), ("prefill", "float32"),
+    ("decode_step", "float32")])
+def test_serving_program_casts_no_weight(one_chip, program, given):
+    """An engine built from FLOAT32 parameters: its chunk program and a
+    prefill bucket take the tree it holds and cast none of it. The
+    controls: the same two programs handed the float32 tree (the engine
+    before PR 33), and ``CausalLM.decode_step`` on it directly, hold a
+    cast of every matrix of every layer and of the embedding."""
+    eng = _engine(None)
+    masters = jax.tree_util.tree_map(
+        lambda a: (a.shape, jnp.float32), eng.params)
+    if program == "decode_step":
+        model, ps = eng.model, eng.page_size
+
+        def decode_step(params, kv, tables, pos, tok, active):
+            kv, _, logits, _ = model.decode_step(
+                params, kv, None, tables, pos, tok, active, ps,
+                mode="pallas")
+            return kv, logits
+
+        S, P, i32 = eng.slots, eng.pages_per_slot, jnp.int32
+        jitted, args = jax.jit(decode_step), [
+            masters, eng.pool.tree(), ((S, P), i32), ((S,), i32),
+            ((S,), i32), ((S,), bool)]
+    else:
+        jitted, args = _programs(eng)[program]
+        assert {a.dtype for a in jax.tree_util.tree_leaves(args[0])} \
+            == {jnp.dtype(jnp.bfloat16)}
+        if given == "float32":
+            args = [masters, *args[1:]]
+    found = weight_casts(_compiled_text(jitted, args, one_chip, "at_rest"),
+                         eng)
+    if given == "served":
+        assert found == dict.fromkeys(found, 0)
+    else:
+        assert found["tok_emb"] >= 1
+        assert all(found[k] >= LAYERS for k in ("wqkv", "wo", "w1", "w2"))
 
 
 # ------------------------------------------- the controls: the old page
