@@ -1,6 +1,7 @@
-"""The two small pieces the served decoder blocks share
-(``models/lfm2_moe.py``, ``models/exaone_moe.py``): RMSNorm with its
-statistics in float32, and rotate-half rotary positions in float32."""
+"""The small pieces the served decoder blocks share
+(``models/lfm2_moe.py``, ``models/exaone_moe.py``,
+``models/glm_moe_dsa.py``): RMSNorm with its statistics in float32, and
+rotary positions in float32, rotate-half or over interleaved pairs."""
 
 from __future__ import annotations
 
@@ -29,4 +30,18 @@ def rope(x, pos, theta):
     return (xf * cos + rot * sin).astype(x.dtype)
 
 
-__all__ = ["rms_norm", "rope"]
+def rope_interleaved(x, pos, theta):
+    """RoPE over INTERLEAVED pairs of ``x [n, t, heads, hd]`` at ``pos
+    [n, t]``, in float32: lanes ``(2i, 2i + 1)`` turn by ``pos *
+    theta^(-2i / hd)`` (``rope`` pairs lane ``i`` with ``i + hd / 2``)."""
+    hd = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = pos.astype(jnp.float32)[..., None] * inv        # [n, t, hd / 2]
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], hd // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+__all__ = ["rms_norm", "rope", "rope_interleaved"]

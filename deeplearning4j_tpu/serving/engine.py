@@ -417,7 +417,9 @@ class DecodeEngine:
     written by prefill at admission (a reused slot never sees its
     predecessor's), carried through the chunk's scan and donated
     across dispatches like the pool; options that key on pages alone
-    are then refused by name.
+    are then refused by name. Where it names ``stores`` (a latent row,
+    an indexer's keys), the pool is those arrays under one page table
+    a slot, and the options written for K and V pools are refused.
     """
 
     def __init__(self, model, params, *, slots: int = 8,
@@ -445,21 +447,33 @@ class DecodeEngine:
         #: that and from the methods it has, decided here and nowhere
         #: else
         spec = model.cache_spec()
+
+        def refuse(asked, why):
+            for name, wanted in asked.items():
+                if wanted:
+                    raise ValueError(f"{name} cannot be honoured for "
+                                     f"{type(model).__name__}: {why}")
+
         if spec["state"] is not None:
-            refused = {"prefix_cache": prefix_cache,
-                       "session_capacity": session_capacity > 0,
-                       "spec_decode": spec_decode is not None,
-                       "quantization": quantization is not None,
-                       "kv_dtype": kv_dtype is not None,
-                       "handoff_threshold": handoff_threshold is not None}
-            for name, asked in refused.items():
-                if asked:
-                    raise ValueError(
-                        f"{name} cannot be honoured for "
-                        f"{type(model).__name__}: the model keeps a "
-                        "per-slot state beside its pages (a recurrent "
-                        "state, or window layers' rings), and this "
-                        "option keys on pages alone")
+            refuse({"prefix_cache": prefix_cache,
+                    "session_capacity": session_capacity > 0,
+                    "spec_decode": spec_decode is not None,
+                    "quantization": quantization is not None,
+                    "kv_dtype": kv_dtype is not None,
+                    "handoff_threshold": handoff_threshold is not None},
+                   "the model keeps a per-slot state beside its pages (a "
+                   "recurrent state, or window layers' rings), and this "
+                   "option keys on pages alone")
+        #: the pool's stores where the model names its own (a latent
+        #: row, an indexer's keys: ``{name: (layers, row width)}``),
+        #: else None: K and V pools of ``kv_heads x head_dim``
+        self._stores = spec.get("stores")
+        if self._stores is not None:
+            refuse({"kv_dtype": kv_dtype is not None,
+                    "handoff_threshold": handoff_threshold is not None},
+                   f"its pool is stores of its own "
+                   f"({', '.join(self._stores)}), not K and V, and this "
+                   "option is written for those")
         needs = {"prefix_cache": (prefix_cache, "paged_rows"),
                  "session_capacity": (session_capacity > 0, "paged_rows"),
                  "spec_decode": (spec_decode is not None, "paged_rows"),
@@ -535,10 +549,10 @@ class DecodeEngine:
                 f"unknown attn_mode {attn_mode!r} (expected None, "
                 "'pallas', 'interpret' or 'xla')")
         self.pool = kv_pages.PagePool(
-            spec["kv_layers"], spec["kv_heads"], self.page_size,
-            spec["head_dim"], n_pages, dtype=model._cdtype,
+            spec.get("kv_layers"), spec.get("kv_heads"), self.page_size,
+            spec.get("head_dim"), n_pages, dtype=model._cdtype,
             engine_id=self.engine_id, device=device,
-            kv_dtype=self.kv_dtype)
+            kv_dtype=self.kv_dtype, stores=self._stores)
         #: the per-slot state beside the pool: an array ``[state
         #: layers, slots, ...]`` in the compute dtype, or a dict of such
         #: (a cache's second kind: window layers' rings of K and of V),
@@ -553,6 +567,9 @@ class DecodeEngine:
         #: positions a window layer attends (None: the model has none);
         #: such a model's K/V of those layers is the state above
         self._window = spec.get("window")
+        #: the most positions an attention layer reads of a context
+        #: (None: all of it); such a model's indexer scores them all
+        self._selected = spec.get("selected")
         #: the most slots ever live at once: what of the window layers'
         #: store was ever in use
         self._slots_high_water = 0
@@ -835,10 +852,15 @@ class DecodeEngine:
             kv, state = cache
             ks, vs, mine, last, counts = m.prefill(params, prompt, t0,
                                                    mode=attn)
-            # t0 bounds the REAL positions: an fp8 pool's page scales
-            # are minted from them only, never from padding garbage
-            kv = kv_pages.commit_prefill(kv, ks, vs, page_row, ps,
-                                         n_valid=t0)
+            if self._stores is not None:
+                # the model's own stores: ``ks`` is a row a position a
+                # store, ``vs`` nothing
+                kv = kv_pages.commit_rows(kv, ks, page_row, ps)
+            else:
+                # t0 bounds the REAL positions: an fp8 pool's page
+                # scales are minted from them only, never from padding
+                kv = kv_pages.commit_prefill(kv, ks, vs, page_row, ps,
+                                             n_valid=t0)
             if state is not None:
                 state = jax.tree_util.tree_map(
                     lambda all_, one: lax.dynamic_update_slice_in_dim(
@@ -1355,6 +1377,7 @@ class DecodeEngine:
                          "high_water": self.pool.high_water,
                          "shared": self.pool.shared_pages(),
                          "page_bytes": self.pool.bytes_per_page(),
+                         "store_bytes": self.pool.store_bytes(),
                          **self._window_store()},
             "warm_pool": {"hits": self._warm.hits,
                           "misses": self._warm.misses,
@@ -2178,6 +2201,12 @@ class DecodeEngine:
             # what a window layer's calls had to read of it
             attrs["ctx_window_tokens"] = int(
                 np.minimum(held, self._window).sum())
+        if self._selected is not None:
+            # what an attention layer's calls read of the contexts, and
+            # what an indexer layer's scored to choose it
+            attrs["ctx_selected_tokens"] = int(
+                np.minimum(held, self._selected).sum())
+            attrs["ctx_index_tokens"] = ctx
         tables, active, temps = b.roster
         with _telemetry.span("engine.dispatch", parent=b.span.id, k=k,
                              live=live, ctx_tokens=ctx, ctx_pages=pages,

@@ -16,6 +16,11 @@ pool of fixed-size pages shared by every slot:
   that absorbs writes from inactive slots and from the padded tail of
   prefill commits; it is never read through a valid attention
   position.
+- a model that names its own ``stores`` (``cache_spec()``; PR 34:
+  GLM-5.2's latent rows and indexer keys) gets ``{name: [layers,
+  n_pages, page_size, row width]}`` instead, each store with its own
+  layer count and row width, all under the one allocator and the one
+  page table a slot; ``commit_rows`` / ``append_rows`` are its writers.
 - per-slot page table: row ``j`` of a slot's table names the page
   holding absolute positions ``[j*page_size, (j+1)*page_size)`` of
   that slot's sequence. Unallocated tail entries point at the null
@@ -113,7 +118,8 @@ class PagePool:
 
     def __init__(self, n_layers: int, n_heads: int, page_size: int,
                  head_dim: int, n_pages: int, dtype=jnp.bfloat16,
-                 engine_id: str = "solo", device=None, kv_dtype=None):
+                 engine_id: str = "solo", device=None, kv_dtype=None,
+                 stores: Optional[Dict[str, Sequence[int]]] = None):
         if page_size < 1 or n_pages < 2:
             raise ValueError(
                 f"need page_size >= 1 and n_pages >= 2 (one null page "
@@ -128,18 +134,29 @@ class PagePool:
                  else jnp.dtype(dtype))
         #: ``kv_dtype=`` label value on every SERVING_KV_* series
         self.dtype_label = self.kv_dtype or jnp.dtype(dtype).name
+        if stores is not None and self.kv_dtype:
+            raise ValueError("only K/V pools are quantized: a model that "
+                             "names its own stores keeps them in its "
+                             "compute dtype")
         # a page row is every KV head side by side, head ``h`` on
-        # lanes ``[h * hd, (h + 1) * hd)`` (module docstring)
-        shape = (n_layers, n_pages, page_size, n_heads * head_dim)
+        # lanes ``[h * hd, (h + 1) * hd)`` (module docstring); a model
+        # that names its stores gives each one's layers and row width
+        if stores is None:
+            stores = dict.fromkeys(("k", "v"),
+                                   (n_layers, n_heads * head_dim))
         # allocated where they live (device=None is the default
         # device): a pool must never pass through another chip's HBM
-        self.k = jnp.zeros(shape, store, device=device)
-        self.v = jnp.zeros(shape, store, device=device)
-        self.k_scale = self.v_scale = None
+        #: the device arrays by name, in the order the programs' tree
+        #: has them: ``{"k", "v"}`` (+ scale planes), or the model's own
+        self._arrays: Dict[str, jnp.ndarray] = {
+            name: jnp.zeros((int(layers), n_pages, page_size, int(width)),
+                            store, device=device)
+            for name, (layers, width) in stores.items()}
         if self.kv_dtype:
             sshape = (n_layers, n_pages, n_heads)
-            self.k_scale = jnp.ones(sshape, jnp.float32, device=device)
-            self.v_scale = jnp.ones(sshape, jnp.float32, device=device)
+            for name in ("k_scale", "v_scale"):
+                self._arrays[name] = jnp.ones(sshape, jnp.float32,
+                                              device=device)
         # LIFO free list: recently-freed pages are re-used first, which
         # keeps the hot working set of pages small and cache-friendly
         self._free: List[int] = list(range(n_pages - 1, 0, -1))
@@ -156,19 +173,20 @@ class PagePool:
         insertion order is the flatten order, so a non-fp8 tree
         flattens to exactly the (kpool, vpool) pair the pre-tree
         programs took — both features off stays program-identical."""
-        kv = {"k": self.k, "v": self.v}
-        if self.k_scale is not None:
-            kv["k_scale"] = self.k_scale
-            kv["v_scale"] = self.v_scale
-        return kv
+        return dict(self._arrays)
 
     def rebind(self, kv: Dict[str, jnp.ndarray]) -> None:
         """Adopt the arrays a jitted program returned (the functional
         counterpart of ``tree()``; donation invalidated the old ones).
         """
-        self.k, self.v = kv["k"], kv["v"]
-        if "k_scale" in kv:
-            self.k_scale, self.v_scale = kv["k_scale"], kv["v_scale"]
+        self._arrays = {name: kv[name] for name in self._arrays}
+
+    # the K/V pools by their names (None where the pool is a model's
+    # own stores, or not quantized)
+    k = property(lambda self: self._arrays.get("k"))
+    v = property(lambda self: self._arrays.get("v"))
+    k_scale = property(lambda self: self._arrays.get("k_scale"))
+    v_scale = property(lambda self: self._arrays.get("v_scale"))
 
     # ------------------------------------------------------- accounting
     @property
@@ -202,13 +220,13 @@ class PagePool:
             return sum(1 for r in self._refs.values() if r > 1)
 
     def bytes_per_page(self) -> int:
-        # k + v (+ scale planes), all layers, one page
-        per = self.k.size // self.n_pages
-        total = 2 * per * jnp.dtype(self.k.dtype).itemsize
-        if self.k_scale is not None:
-            total += 2 * (self.k_scale.size // self.n_pages) \
-                * jnp.dtype(self.k_scale.dtype).itemsize
-        return total
+        # every store (k + v + scale planes, or the model's own), all
+        # layers, one page
+        return sum(self.store_bytes().values()) // self.n_pages
+
+    def store_bytes(self) -> Dict[str, int]:
+        """Bytes of each device array of the pool, by name."""
+        return {name: int(a.nbytes) for name, a in self._arrays.items()}
 
     # ------------------------------------------------------- allocation
     def alloc(self, n: int) -> Optional[List[int]]:
@@ -324,16 +342,14 @@ def commit_prefill(kv, ks, vs, page_row, page_size: int, n_valid=None):
     """
     L, one, H, B, hd = ks.shape
     pb = B // page_size
-    # [L, 1, H, B, hd] -> [L, pb, ps, H * hd]: position-major, the
-    # heads along the row
-    rows = lambda c: c[:, 0].transpose(0, 2, 1, 3).reshape(
-        L, pb, page_size, H * hd)
-    ck, cv = rows(ks), rows(vs)
-    out = dict(kv)
+    # [L, 1, H, B, hd] -> [L, B, H * hd]: position-major, the heads
+    # along the row
+    rows = lambda c: c[:, 0].transpose(0, 2, 1, 3).reshape(L, B, H * hd)
     if not _is_fp8(kv):
-        out["k"] = kv["k"].at[:, page_row].set(ck.astype(kv["k"].dtype))
-        out["v"] = kv["v"].at[:, page_row].set(cv.astype(kv["v"].dtype))
-        return out
+        return commit_rows(kv, {"k": rows(ks), "v": rows(vs)}, page_row,
+                           page_size)
+    ck, cv = (rows(c).reshape(L, pb, page_size, H * hd) for c in (ks, vs))
+    out = dict(kv)
 
     def one(c):  # -> quantized rows, scales [L, pb, H]
         ch = _by_head(c.astype(jnp.float32), H)   # [L, pb, ps, H, hd]
@@ -351,6 +367,32 @@ def commit_prefill(kv, ks, vs, page_row, page_size: int, n_valid=None):
     out["v"] = kv["v"].at[:, page_row].set(qv)
     out["k_scale"] = kv["k_scale"].at[:, page_row].set(ksc)
     out["v_scale"] = kv["v_scale"].at[:, page_row].set(vsc)
+    return out
+
+
+def commit_rows(kv, rows, page_row, page_size: int):
+    """Lay one prompt's rows into its pages, store by store (the one
+    float write of a prefill: a pool of a model's own stores calls it
+    with the rows its ``prefill`` returned, :func:`commit_prefill` with
+    K and V): ``rows[name]`` is ``[layers, B, width]``, a row a position
+    of the padded prompt as the store holds it, laid into whole pages
+    ``[layers, B // page_size, page_size, width]`` at ``page_row`` (the
+    null page for the padded tail)."""
+    out = dict(kv)
+    for name, r in rows.items():
+        L, B, W = r.shape
+        out[name] = kv[name].at[:, page_row].set(
+            r.reshape(L, B // page_size, page_size, W)
+            .astype(kv[name].dtype))
+    return out
+
+
+def append_rows(kv, name: str, layer: int, page_idx, offset, x):
+    """Write one decode position's row ``x [S, width]`` of the store
+    ``name``: lane ``s`` lands at ``(layer, page_idx[s], offset[s])``
+    (what :func:`append_token` does for K and for V)."""
+    out = dict(kv)
+    out[name] = _write_rows(kv[name], layer, page_idx, offset, x)
     return out
 
 
@@ -384,11 +426,10 @@ def append_token(kv, layer: int, page_idx, offset, k, v):
     only estimates the page's range, so later outlier tokens clip at
     ±448 (bounded error) instead of silently breaking earlier ones.
     """
-    out = dict(kv)
     if not _is_fp8(kv):
-        out["k"] = _write_rows(kv["k"], layer, page_idx, offset, k)
-        out["v"] = _write_rows(kv["v"], layer, page_idx, offset, v)
-        return out
+        return append_rows(append_rows(kv, "k", layer, page_idx, offset, k),
+                           "v", layer, page_idx, offset, v)
+    out = dict(kv)
     fresh = (offset == 0)[:, None]
 
     def one(pool, scales, x):
@@ -536,7 +577,7 @@ def pages_needed(total_positions: int, page_size: int) -> int:
     return -(-int(total_positions) // int(page_size))
 
 
-__all__ = ["PagePool", "commit_prefill", "append_token",
-           "append_spec", "spec_rewind",
+__all__ = ["PagePool", "commit_prefill", "commit_rows", "append_token",
+           "append_rows", "append_spec", "spec_rewind",
            "gather_pages", "copy_page", "handoff_commit",
            "pages_needed"]

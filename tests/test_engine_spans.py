@@ -471,3 +471,41 @@ def test_the_fleets_route_is_the_parent_of_the_replicas_admit(gpt):
         assert route["args"]["request"] == a["args"]["request"]
         assert route["args"]["replica"] == a["args"]["engine"]
         assert route["tid"] != a["tid"]
+
+
+def test_a_model_that_selects_counts_what_it_reads_and_what_it_scores():
+    """A model whose ``cache_spec()`` names ``selected`` (learned sparse
+    attention): each dispatch carries the contexts cut to the selection
+    and the positions the indexer scored beside ``ctx_tokens``, and
+    ``stats()`` the pool's stores by name; a model without the key
+    carries neither attribute."""
+    from deeplearning4j_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
+                                                       GlmMoeDsaLM)
+    from deeplearning4j_tpu.serving import DecodeEngine
+
+    m = GlmMoeDsaLM(GlmMoeDsaConfig(
+        vocab_size=17, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2,
+        indexer_types=("full", "shared"),
+        mlp_layer_types=("dense", "sparse"), num_attention_heads=2,
+        q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, index_n_heads=2,
+        index_head_dim=16, index_topk=8, n_routed_experts=4,
+        num_experts_per_tok=2, max_position_embeddings=64), jnp.float32)
+    with DecodeEngine(m, m.init_params(jax.random.key(0)), slots=2,
+                      page_size=8, max_context=64) as eng:
+        reqs = [eng.submit(np.arange(1, 1 + p, dtype=np.int32) % 17, n)
+                for p, n in WORK]
+        for r in reqs:
+            r.result(timeout=120)
+        stats = eng.stats()
+    calls = [e["args"] for e in _ring("engine.dispatch")]
+    assert calls and sum(a["ctx_tokens"] for a in calls) \
+        == stats["attended_tokens"]
+    for a in calls:
+        assert a["ctx_index_tokens"] == a["ctx_tokens"]
+        assert 0 < a["ctx_selected_tokens"] <= min(
+            a["ctx_tokens"], 8 * a["live"] * a["k"])
+    # contexts run to 16, twice the selection
+    assert any(a["ctx_selected_tokens"] < a["ctx_tokens"] for a in calls)
+    assert set(stats["kv_pages"]["store_bytes"]) == {"latent", "index_k"}

@@ -508,3 +508,122 @@ def test_grouped_matmul_compiles_for_16_held_experts(one_chip, rows, k, n):
                _sds((16,), jnp.int32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo and "moe_experts" in hlo
     assert not re.search(r"bf16\[16,%d,%d\][^\n]* copy\(" % (k, n), hlo)
+
+
+# ---------- a pool of a model's own stores: GLM-5.2's latent rows and keys
+#: the cell's stores (BENCHMARK.json): every layer's latent rows, 576
+#: numbers a position resting in 640 lanes, and the two full-indexer
+#: layers' keys; 1 + 32 slots x 896 pages
+GLM_LATENT = (5, 1 + 32 * 896, 16, 640)
+GLM_INDEX_K = (2, 1 + 32 * 896, 16, 128)
+
+
+@pytest.mark.parametrize("shape,row_major", [
+    (GLM_LATENT, True), (GLM_INDEX_K, True),
+    (GLM_LATENT[:3] + (576,), False),    # the row as the mathematics has it
+    (GLM_LATENT[:3] + (512,), True),     # the latent alone ...
+    (GLM_LATENT[:3] + (64,), False)],    # ... and its rotary key alone
+    ids=["latent-640", "index-k", "latent-576", "c-kv-512", "k-rope-64"])
+def test_how_a_latent_store_rests(one_chip, shape, row_major):
+    """Why the latent row rests in 640 lanes: a row of 576 (four and a
+    half lane tiles) rests with the PAGE dimension minor-most, as the
+    page of PR 31's fault did, and so would a store of the 64 rotary
+    lanes alone; 640, 512 and the indexer's 128 rest row-major. (Two
+    stores, 512 and 64 padded to 128, would hold the same 640 lanes a
+    position behind two gathers.)"""
+    rests = jax.jit(lambda x: x).lower(jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=one_chip)).compile().input_formats[0][0]
+    order = tuple(rests.layout.major_to_minor)
+    assert (order == tuple(range(len(shape)))) == row_major
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_engine():
+    """Never started, only traced: MLA over a latent of 64 + 32 (a row
+    of 128 lanes, the least that rests row-major), an indexer of 4
+    heads of 128 selecting 64 positions, full and shared layers, 2 of 8
+    experts held."""
+    from deeplearning4j_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
+                                                       GlmMoeDsaLM)
+
+    model = GlmMoeDsaLM(GlmMoeDsaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=5,
+        indexer_types=("full", "shared", "shared", "shared", "full"),
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        num_attention_heads=4, q_lora_rank=128, kv_lora_rank=64,
+        qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=32,
+        index_n_heads=4, index_head_dim=128, index_topk=64,
+        num_experts=2, n_routed_experts=8, expert_offset=2,
+        num_experts_per_tok=2, max_position_embeddings=1024), jnp.bfloat16)
+    return DecodeEngine(
+        model, model.init_params(jax.random.key(0)), slots=SLOTS,
+        page_size=16, max_context=1024, attn_mode="pallas",
+        max_chunk=CHUNK, warm_start=False)
+
+
+@pytest.mark.parametrize("layouts", ["pinned", "at_rest"])
+@pytest.mark.parametrize("program", ["chunk", "prefill"])
+def test_a_pool_of_two_stores_is_never_copied(one_chip, program, layouts):
+    """The decode chunk and the prefill of a model whose pool is a
+    latent store and an indexer-key store under one page table copy
+    neither, nor either seen as rows (the gather's view: a merge of
+    leading dimensions); both new stages are kernels in the chunk."""
+    eng = _latent_engine()
+    stores = eng.pool.tree()
+    assert {n: a.shape for n, a in stores.items()} == {
+        "latent": (5, 1 + SLOTS * 64, 16, 128),
+        "index_k": (2, 1 + SLOTS * 64, 16, 128)}
+    jitted, args = _programs(eng)[program]
+    hlo = _compiled_text(jitted, args, one_chip, layouts)
+    for a in stores.values():
+        L, n_pages, ps, W = a.shape
+        for store in (a, jax.ShapeDtypeStruct((L * n_pages * ps, W),
+                                              a.dtype)):
+            assert pool_copies(hlo, store) == {"loop": 0, "all": 0}
+    if program == "chunk":
+        assert "sparse_latent_attention" in hlo and "index_scores" in hlo
+        assert "moe_experts" in hlo
+
+
+def test_index_scores_kernel_compiles_at_published_widths(one_chip):
+    """GLM-5.2's selection: 32 slots, 32 indexer heads of 128 against
+    the paged keys through a table of 896 pages, eight pages a visit,
+    the visits as many as the pages held; the store goes in as it
+    rests."""
+    from deeplearning4j_tpu.ops.sparse_latent_attention_pallas import \
+        index_select
+
+    bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    store = _sds(GLM_INDEX_K, bf16, one_chip)
+    hlo = jax.jit(lambda q, w, st, t, p: index_select(
+        q, w, st, 1, t, p, 2048, mode="pallas")) \
+        .lower(_sds((32, 32, 128), bf16, one_chip),
+               _sds((32, 32), f32, one_chip), store,
+               _sds((32, 896), i32, one_chip),
+               _sds((32,), i32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "index_scores" in hlo
+    assert pool_copies(hlo, store)["all"] == 0
+
+
+def test_sparse_latent_kernel_compiles_at_published_widths(one_chip):
+    """GLM-5.2's attention: 64 query heads against ONE shared row of
+    640 lanes a position (576 used), 2,048 selected rows a slot read
+    through the table, values the row's first 512 lanes; the store
+    goes in as it rests, the gather reads rows of it."""
+    from deeplearning4j_tpu.ops.sparse_latent_attention_pallas import \
+        sparse_latent_attention
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    store = _sds(GLM_LATENT, bf16, one_chip)
+    hlo = jax.jit(lambda q, st, t, sel, n: sparse_latent_attention(
+        q, st, 3, t, sel, n, dv=512, scale=1 / 16, mode="pallas")) \
+        .lower(_sds((32, 64, 640), bf16, one_chip), store,
+               _sds((32, 896), i32, one_chip),
+               _sds((32, 2048), i32, one_chip),
+               _sds((32,), i32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "sparse_latent_attention" in hlo
+    assert pool_copies(hlo, store)["all"] == 0
+    L, n_pages, ps, W = GLM_LATENT
+    assert pool_copies(hlo, jax.ShapeDtypeStruct(
+        (L * n_pages * ps, W), bf16))["all"] == 0
