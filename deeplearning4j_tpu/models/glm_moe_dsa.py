@@ -87,7 +87,7 @@ from deeplearning4j_tpu.models.decoder_ops import rms_norm, rope_interleaved
 from deeplearning4j_tpu.models.routed_experts import (expert_stats,
                                                       routed_experts)
 from deeplearning4j_tpu.ops.sparse_latent_attention_pallas import (
-    index_select, selected_rows, sparse_latent_attention)
+    index_select, selection_reads, sparse_latent_attention)
 from deeplearning4j_tpu.serving import kv_pages
 
 FULL, SHARED = "full", "shared"
@@ -359,7 +359,7 @@ class _Paged:
             tables, (pos // page_size)[:, None], axis=1)[:, 0]
         self.off = pos % page_size
         self.page_size = page_size
-        self.sel = self.n_sel = self.rows = None
+        self.sel = self.n_sel = self.reads = None
 
     def attend(self, li, lp, q_nope, q_rope, c_kv, k_r, index, pos):
         c = self.c
@@ -373,13 +373,14 @@ class _Paged:
             fi = self.full_index[li]
             self.kv = kv_pages.append_rows(self.kv, "index_k", fi,
                                            self.page, self.off, ki[:, 0])
-            self.sel, self.n_sel = index_select(
+            self.sel, self.n_sel, mask = index_select(
                 qi[:, 0], wi[:, 0], self.kv["index_k"], fi, self.tables,
-                self.pos, c.index_topk, mode=self.mode)
-            # where they lie in a layer of the store: the same for the
-            # shared layers after this one
-            self.rows = selected_rows(self.tables, self.sel,
-                                      self.page_size)
+                self.pos, c.index_topk, mode=self.mode, with_mask=True)
+            # how a layer of the store is read under this selection: the
+            # same for the shared layers after this one
+            self.reads = selection_reads(
+                self.tables, self.sel, self.n_sel, self.page_size,
+                mode=self.mode, mask=mask)
         # the query carried into the latent: q_nope_j (W_kvb^K_j)^T
         q_lat = jnp.einsum("shd,chd->shc", q_nope[:, 0],
                            lp["wkv_k"].reshape(C, H, c.qk_nope_head_dim))
@@ -388,7 +389,7 @@ class _Paged:
         ctx = sparse_latent_attention(
             q, self.kv["latent"], li, self.tables, self.sel, self.n_sel,
             dv=C, scale=float(c.qk_head_dim) ** -0.5, mode=self.mode,
-            rows=self.rows)
+            reads=self.reads)
         out = jnp.einsum("shc,chv->shv", ctx,
                          lp["wkv_v"].reshape(C, H, dv))
         return out.reshape(S, 1, H * dv)

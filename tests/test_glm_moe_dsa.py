@@ -33,6 +33,7 @@ from deeplearning4j_tpu.models import glm_moe_dsa
 from deeplearning4j_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
                                                    GlmMoeDsaLM)
 from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
+from deeplearning4j_tpu.ops import sparse_latent_attention_pallas as stages
 from deeplearning4j_tpu.ops.sparse_latent_attention_pallas import (
     gather_rows, index_select, selected_rows, sparse_latent_attention)
 from deeplearning4j_tpu.serving.engine import DecodeEngine
@@ -464,41 +465,129 @@ def test_index_select_is_the_exact_topk(D, Hi, P, ps, top, mode):
 
 def test_index_select_breaks_ties_to_the_lower_position():
     """Keys that score alike: the lower positions are taken."""
-    ps, P, D = 4, 6, 16
-    index_k = jnp.ones((1, 1 + P, ps, D), jnp.float32)
-    tables = jnp.arange(1, 1 + P, dtype=jnp.int32)[None]
-    qi, w = jnp.ones((1, 2, D), jnp.float32), jnp.ones((1, 2), jnp.float32)
-    sel, n_sel = index_select(qi, w, index_k, 0, tables,
-                              jnp.asarray([20], jnp.int32), 8, mode="xla")
+    sel, n_sel = index_select(*_tie_case(), mode="xla")
     assert int(n_sel[0]) == 8
     np.testing.assert_array_equal(np.sort(np.asarray(sel[0])), np.arange(8))
 
 
-@pytest.mark.parametrize("shape", [
-    (4, 128, 64, 10, 4, 16),            # the tests' model
-    (64, 640, 512, 24, 16, 128)],       # G = 64 over a row of 576 in 640
-    ids=["toy", "published-row"])
+TIES = dict(ps=4, P=6, D=16, pos=20, top=8)
+
+
+def _tie_case():
+    """Keys that score alike, 21 of them held."""
+    ps, P, D = TIES["ps"], TIES["P"], TIES["D"]
+    index_k = jnp.ones((1, 1 + P, ps, D), jnp.float32)
+    tables = jnp.arange(1, 1 + P, dtype=jnp.int32)[None]
+    qi, w = jnp.ones((1, 2, D), jnp.float32), jnp.ones((1, 2), jnp.float32)
+    return qi, w, index_k, 0, tables, jnp.asarray([TIES["pos"]], jnp.int32), \
+        TIES["top"]
+
+
+@pytest.mark.parametrize("case,mode", [
+    ((16, 4, 10, 4, 16), "xla"), ((16, 4, 10, 4, 16), "interpret"),
+    ((128, 32, 24, 16, 128), "xla"), ((128, 32, 24, 16, 128), "interpret"),
+    ("ties", "xla"), ("ties", "interpret")],
+    ids=["toy-xla", "toy-interpret", "published-xla", "published-interpret",
+         "ties-xla", "ties-interpret"])
+def test_the_mask_is_the_selection(case, mode):
+    """The mask ``index_select`` makes from the scores (a threshold and
+    a running count, no scatter) names exactly ``sel[s, :n_sel[s]]``,
+    on the exact top-k's cases and where every key scores alike; the
+    scatter of the list, which the stage falls back to, names them
+    too."""
+    if case == "ties":
+        args = _tie_case()
+    else:
+        D, Hi, P, ps, top = case
+        pos = [0, top // 2, top - 1, top, P * ps - ps - 2, P * ps - 1]
+        _, qi, w, _, index_k, tables, qpos = _stage_case(
+            len(pos), 4, 128, 64, D, Hi, P, ps, pos)
+        args = (qi, w, index_k, 1, tables, qpos, top)
+    with jax.default_matmul_precision("highest"):
+        sel, n_sel, mask = index_select(*args, mode=mode, with_mask=True)
+        plain = index_select(*args, mode=mode)
+    np.testing.assert_array_equal(plain[0], sel)
+    np.testing.assert_array_equal(plain[1], n_sel)
+    cells = args[4].shape[1] * args[2].shape[2]
+    assert mask.shape == (len(n_sel), cells) and mask.dtype == bool
+    scattered = np.asarray(stages.selection_mask(sel, n_sel, cells))
+    for s in range(len(n_sel)):
+        want = np.asarray(sel[s, :int(n_sel[s])])
+        np.testing.assert_array_equal(np.flatnonzero(np.asarray(mask[s])),
+                                      want)
+        np.testing.assert_array_equal(np.flatnonzero(scattered[s]), want)
+    if case == "ties":
+        np.testing.assert_array_equal(np.flatnonzero(np.asarray(mask[0])),
+                                      np.arange(TIES["top"]))
+
+
+#: pages a visit of the walk: contexts "several visits long" are
+#: measured in it
+WALK = stages._PAGES_A_WALK
+#: H, W, dv, P, ps, top, positions (None: 0, top - 1, top, near the end)
+STAGE_CASES = {
+    "toy": (4, 128, 64, 10, 4, 16, None),
+    # G = 64 over a row of 576 in 640
+    "published-row": (64, 640, 512, 24, 16, 128, None),
+    # three and four visits of 64 pages; the contexts end on a visit's
+    # last row, on its first, mid-page and mid-visit, and inside the
+    # first visit beside slots that go on
+    "toy-many-visits": (4, 128, 64, 4 * WALK, 4, 16,
+                        [2 * WALK * 4 - 1, 2 * WALK * 4, 3 * WALK * 4 + 6,
+                         4 * WALK * 4 - 1, 9]),
+    "published-row-many-visits": (64, 640, 512, 2 * WALK + 9, 16, 128,
+                                  [WALK * 16 + 7, 2 * WALK * 16 + 100, 130,
+                                   2 * WALK * 16 - 1]),
+}
+
+
+@pytest.mark.parametrize("fill", ["as-left", "unselected-1e4"])
+@pytest.mark.parametrize("null_slot", [False, True],
+                         ids=["live", "beside-a-null-table"])
+@pytest.mark.parametrize("shape", sorted(STAGE_CASES))
 def test_sparse_latent_attention_forms_agree_and_read_only_the_selected(
-        shape):
-    H, W, dv, P, ps, top = shape
-    pos = [0, top - 1, top, P * ps - 3]
+        shape, null_slot, fill):
+    """The walk over the pages held against the gather of the selected
+    rows, and both against plain arithmetic over ``sel``'s rows.
+    ``beside-a-null-table``: one more slot at position 0 whose table is
+    all the null page (an evicted slot) between the live ones.
+    ``unselected-1e4``: every row of the store the selection does not
+    name, pages the slots hold and the null page included, is 10,000: a
+    masked row is read and weighs nothing."""
+    H, W, dv, P, ps, top, pos = STAGE_CASES[shape]
+    pos = list(pos or [0, top - 1, top, P * ps - 3])
     q, qi, w, latent, index_k, tables, qpos = _stage_case(
-        len(pos), H, W, dv, 16, 4, P, ps, pos, seed=2)
+        len(pos) + null_slot, H, W, dv, 16, 4, P, ps, pos + [0] * null_slot,
+        seed=2)
+    if null_slot:
+        # the evicted slot second, so that the walk passes through it
+        order = [0, len(pos)] + list(range(1, len(pos)))
+        q, qi, w, tables, qpos = (a[jnp.asarray(order)]
+                                  for a in (q, qi, w, tables, qpos))
+        tables = tables.at[1].set(0)
     scale = 0.125
     with jax.default_matmul_precision("highest"):
         sel, n_sel = index_select(qi, w, index_k, 0, tables, qpos, top,
                                   mode="xla")
+        if fill == "unselected-1e4":
+            named = np.zeros(latent.shape[1] * ps, bool)
+            named[np.asarray(selected_rows(tables, sel, ps))[
+                np.arange(sel.shape[1])[None] < np.asarray(n_sel)[:, None]]] \
+                = True
+            latent = latent.at[1].set(jnp.where(
+                named.reshape(-1, ps)[..., None], latent[1], 1e4))
         got = {mode: np.asarray(sparse_latent_attention(
             q, latent, 1, tables, sel, n_sel, dv=dv, scale=scale,
             mode=mode)) for mode in ("xla", "interpret")}
     np.testing.assert_allclose(got["interpret"], got["xla"], atol=2e-5)
     rows = np.asarray(gather_rows(latent, 1, selected_rows(tables, sel, ps)))
-    for s in range(len(pos)):
+    for s in range(len(n_sel)):
         r = rows[s, :int(n_sel[s])]
         a = np.asarray(q)[s] @ r.T * scale
         a = np.exp(a - a.max(-1, keepdims=True))
         want = (a / a.sum(-1, keepdims=True)) @ r[:, :dv]
         np.testing.assert_allclose(got["xla"][s], want, atol=2e-5)
+        assert np.abs(got["interpret"][s]).max() < 100
 
 
 @pytest.mark.parametrize("mode", ["xla", "interpret"])

@@ -538,11 +538,12 @@ def test_how_a_latent_store_rests(one_chip, shape, row_major):
 
 
 @functools.lru_cache(maxsize=None)
-def _latent_engine():
+def _latent_engine(attn_mode="pallas"):
     """Never started, only traced: MLA over a latent of 64 + 32 (a row
     of 128 lanes, the least that rests row-major), an indexer of 4
-    heads of 128 selecting 64 positions, full and shared layers, 2 of 8
-    experts held."""
+    heads of 128 selecting 128 positions (of the 64 pages a slot's table
+    holds: no other look-up of the program has as many entries as the
+    selection), full and shared layers, 2 of 8 experts held."""
     from deeplearning4j_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
                                                        GlmMoeDsaLM)
 
@@ -553,12 +554,12 @@ def _latent_engine():
         mlp_layer_types=("dense",) + ("sparse",) * 4,
         num_attention_heads=4, q_lora_rank=128, kv_lora_rank=64,
         qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=32,
-        index_n_heads=4, index_head_dim=128, index_topk=64,
+        index_n_heads=4, index_head_dim=128, index_topk=128,
         num_experts=2, n_routed_experts=8, expert_offset=2,
         num_experts_per_tok=2, max_position_embeddings=1024), jnp.bfloat16)
     return DecodeEngine(
         model, model.init_params(jax.random.key(0)), slots=SLOTS,
-        page_size=16, max_context=1024, attn_mode="pallas",
+        page_size=16, max_context=1024, attn_mode=attn_mode,
         max_chunk=CHUNK, warm_start=False)
 
 
@@ -606,24 +607,68 @@ def test_index_scores_kernel_compiles_at_published_widths(one_chip):
     assert pool_copies(hlo, store)["all"] == 0
 
 
-def test_sparse_latent_kernel_compiles_at_published_widths(one_chip):
+def row_gathers(hlo: str, rows: int, width: int, dtype="bf16") -> dict:
+    """The gather of ``rows`` single rows of ``width`` lanes as a device
+    trace lists it: a gather fusion whose result is ``[rows, width]``
+    (``fusion bf16[65536,640]`` in the GLM-5.2 cell until PR 36: 32
+    slots x 2,048 selected rows, 99 GB/s), and the fusions that make
+    its ``rows`` addresses (``fusion s32[65536]``)."""
+    def fusions(shape):
+        return len(re.findall(
+            r"= %s\S* fusion\([^\n]*op_name=\"[^\"]*gather" % re.escape(shape),
+            hlo))
+
+    return {"rows": fusions(f"{dtype}[{rows},{width}]"),
+            "addresses": fusions(f"s32[{rows}]")}
+
+
+@pytest.mark.parametrize("mode", ["pallas", "xla"])
+def test_sparse_latent_kernel_compiles_at_published_widths(one_chip, mode):
     """GLM-5.2's attention: 64 query heads against ONE shared row of
-    640 lanes a position (576 used), 2,048 selected rows a slot read
-    through the table, values the row's first 512 lanes; the store
-    goes in as it rests, the gather reads rows of it."""
+    640 lanes a position (576 used), 32 slots, tables 896 wide, values
+    the row's first 512 lanes. ``pallas``: the walk over the pages held
+    compiles, the store goes in as it rests and stays in HBM, and no
+    row is gathered. ``xla``, the control: the detector finds the
+    gather of 65,536 rows and their addresses."""
     from deeplearning4j_tpu.ops.sparse_latent_attention_pallas import \
         sparse_latent_attention
 
     bf16, i32 = jnp.bfloat16, jnp.int32
     store = _sds(GLM_LATENT, bf16, one_chip)
     hlo = jax.jit(lambda q, st, t, sel, n: sparse_latent_attention(
-        q, st, 3, t, sel, n, dv=512, scale=1 / 16, mode="pallas")) \
+        q, st, 3, t, sel, n, dv=512, scale=1 / 16, mode=mode)) \
         .lower(_sds((32, 64, 640), bf16, one_chip), store,
                _sds((32, 896), i32, one_chip),
                _sds((32, 2048), i32, one_chip),
                _sds((32,), i32, one_chip)).compile().as_text()
+    found = row_gathers(hlo, 32 * 2048, 640)
+    if mode == "xla":
+        assert found["rows"] >= 1 and found["addresses"] >= 1
+        return
     assert "tpu_custom_call" in hlo and "sparse_latent_attention" in hlo
+    assert found == {"rows": 0, "addresses": 0}
     assert pool_copies(hlo, store)["all"] == 0
     L, n_pages, ps, W = GLM_LATENT
     assert pool_copies(hlo, jax.ShapeDtypeStruct(
         (L * n_pages * ps, W), bf16))["all"] == 0
+
+
+@pytest.mark.parametrize("mode", ["pallas", "xla"])
+def test_the_decode_chunk_gathers_no_row(one_chip, mode):
+    """The decode chunk program of the latent engine (4 slots selecting
+    128 positions: 512 rows of 128 lanes a layer-step): served through
+    the kernels it walks pages and gathers no row and looks no address
+    up; the ``xla`` form is the control in which the detector finds
+    both."""
+    eng = _latent_engine(mode)
+    jitted, args = _programs(eng)["chunk"]
+    # as served: the suite's "highest" is refused by Mosaic for the bf16
+    # products XLA's own expert product lowers to in the control
+    with jax.default_matmul_precision("default"):
+        hlo = _compiled_text(jitted, args, one_chip, "at_rest")
+    found = row_gathers(hlo, SLOTS * 128, 128)
+    if mode == "xla":
+        assert found["rows"] >= 1 and found["addresses"] >= 1
+    else:
+        assert "sparse_latent_attention" in hlo
+        assert found == {"rows": 0, "addresses": 0}
