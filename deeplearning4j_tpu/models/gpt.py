@@ -5,9 +5,12 @@ EXTENSION in the same spirit as the ring/Ulysses and MoE recipes —
 the model families a reference user graduates to. What makes it
 TPU-native:
 
-- training = one jit step with in-graph causal masking (plain fused
-  einsum attention; at these sequence lengths XLA's fusion covers it —
-  ``ops/flash_attention`` remains the opt-in for long sequences);
+- training = one jit step; a fresh sequence of 1,024 positions or more
+  (whole lane tiles) attends through ``ops/flash_attention.causal``, the
+  splash kernel, forward and backward, over the blocks on and below the
+  diagonal, so no ``[n, H, t, t]`` score matrix reaches HBM; shorter
+  sequences, and any program lowered off the TPU, keep the einsum with
+  an in-graph causal mask, where XLA's fusion wins;
 - generation = batched PARALLEL prefill (one forward pass writes the
   whole prompt's K/V) + ``lax.scan`` decode over a STATIC-shape KV
   cache ([L, N, H, max_len, hd], position-masked) — no dynamic shapes,
@@ -56,12 +59,23 @@ def _attention(q, k, v, mask):
 
 class _Causal:
     """A fresh sequence ``[n, t]``: each position attends those before
-    it; every layer's K/V ``[n, H, t, hd]`` is kept for a prefill."""
+    it; every layer's K/V ``[n, H, t, hd]`` is kept for a prefill. From
+    ``flash_attention.causal_fits(t)`` on, the causal kernel attends
+    where the program runs on a TPU (no padding exists here: a forward
+    sees whole sequences, and a prefill's padding lies after every real
+    position)."""
 
     def __init__(self, cfg, n, t):
         self.cfg, self.n, self.t = cfg, n, t
         self.mask = jnp.tril(jnp.ones((t, t), bool))[None, None]
         self.ks, self.vs = [], []
+        # the kernel's module brings Pallas (1.1 s of import): imported
+        # only where it can be chosen (flash_attention.FLASH_MIN_KV)
+        self.flash = None
+        if t >= 1024:
+            from deeplearning4j_tpu.ops import flash_attention
+            if flash_attention.causal_fits(t):
+                self.flash = flash_attention.causal
 
     def attend(self, li, q, k, v):
         c, n, t = self.cfg, self.n, self.t
@@ -69,7 +83,11 @@ class _Causal:
                    .transpose(0, 2, 1, 3) for y in (q, k, v))
         self.ks.append(k)
         self.vs.append(v)
-        ctx = _attention(q, k, v, self.mask)
+        if self.flash is None:
+            ctx = _attention(q, k, v, self.mask)
+        else:
+            ctx = self.flash(q, k, v, lambda q, k, v: _attention(
+                q, k, v, self.mask))
         return ctx.transpose(0, 2, 1, 3).reshape(n, t, c.d_model)
 
 

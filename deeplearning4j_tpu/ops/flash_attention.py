@@ -20,8 +20,15 @@ Three implementations, one dispatcher:
 3. jax's library Pallas kernel (jax.experimental.pallas.ops.tpu.
    flash_attention) — fwd AND bwd as tuned kernels; preferred on TPU
    when shapes meet its block constraints.
+4. jax's splash kernel (`splash_causal`) — THE causal path: a
+   self-attention with no padding mask at ``t >= FLASH_MIN_KV`` runs
+   forward and backward (one fused dK/dV/dQ kernel) over the blocks on
+   and below the diagonal only, softmax statistics in float32, and no
+   ``[n, H, t, t]`` array reaches HBM. `causal` is what
+   ``models/gpt.py`` trains and prefills through.
 
-`attention(q, k, v, mask, impl="auto")` picks: library kernel on TPU
+`attention(q, k, v, mask, impl="auto")` picks: below FLASH_MIN_KV keys
+XLA's einsum; splash for causal self-attention; library kernel on TPU
 (aligned shapes) → in-repo flash → blockwise.
 """
 
@@ -38,6 +45,10 @@ from jax.experimental import pallas as pl
 from deeplearning4j_tpu.ops.registry import register_op
 
 _NEG_INF = -1e30  # large-but-finite: keeps masked softmax NaN-free
+# Keys below which XLA's fused softmax attention wins on v5e (see
+# `attention`); at 1,024 causal the splash kernel takes a GPT-2-medium
+# layer's fwd + bwd from 2.19 to 0.99 ms (PERF.md section 6).
+FLASH_MIN_KV = 1024
 
 
 # ======================================================================
@@ -252,6 +263,77 @@ def _library_flash(q, k, v, mask, causal):
                                                       k.shape[2]))
 
 
+def causal_fits(t: int) -> bool:
+    """Whether a causal self-attention over ``t`` positions takes the
+    splash kernel on a TPU: long enough to win, whole lane tiles."""
+    return t >= FLASH_MIN_KV and t % 128 == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(t: int, heads: int, interpret: bool):
+    """jax's splash kernel over rows ``[n, heads, t, hd]``, causal.
+    Blocks of 512 (or the largest of 256, 128 that divides ``t``) and
+    the fused backward: measured fastest of splash and the library
+    kernel at ``[4,16,1024,64]`` on v5e (PERF.md section 6). Built once
+    a shape; the mask's block tables are concrete arrays, made outside
+    any trace so the cache holds no tracer."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm,
+    )
+    b = next(b for b in (512, 256, 128) if t % b == 0)
+    blocks = sk.BlockSizes(block_q=b, block_kv=b, block_q_dkv=b,
+                           block_kv_dkv=b, use_fused_bwd_kernel=True)
+    mask = sm.MultiHeadMask([sm.CausalMask((t, t))] * heads)
+    with jax.ensure_compile_time_eval():
+        kernel = jax.vmap(sk.make_splash_mha(
+            mask, block_sizes=blocks, head_shards=1, q_seq_shards=1,
+            interpret=interpret))
+    # The kernel's products take the caller's default matmul precision,
+    # and Mosaic refuses "highest" for bf16 operands: trace forward AND
+    # backward under the default (bf16 products, f32 accumulation).
+    default = functools.partial(jax.default_matmul_precision, "default")
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        with default():
+            return kernel(q, k, v)
+
+    def attend_fwd(q, k, v):
+        with default():
+            return jax.vjp(kernel, q, k, v)
+
+    def attend_bwd(pullback, do):
+        with default():
+            return pullback(do)
+
+    attend.defvjp(attend_fwd, attend_bwd)
+    return attend
+
+
+def splash_causal(q, k, v, interpret: bool = False):
+    """Causal self-attention ``[n, H, t, hd]`` through the splash
+    kernel, scale ``1/sqrt(hd)`` (applied to q: exact in bf16 for
+    ``hd`` a power of four). ``interpret`` runs the same kernel through
+    the Pallas interpreter (tests on the CPU)."""
+    n, h, t, dh = q.shape
+    scale = jnp.asarray(1.0 / dh ** 0.5, q.dtype)
+    return _splash_kernel(t, h, interpret)(q * scale, k, v)
+
+
+def causal(q, k, v, otherwise):
+    """Causal self-attention ``[n, H, t, hd]`` for ``causal_fits(t)``:
+    the splash kernel where the program is lowered for a TPU,
+    ``otherwise(q, k, v)`` elsewhere. A TPU process traces the kernel
+    alone; any other chooses at lowering (``lax.platform_dependent``,
+    which traces both: 1.3 s more for a 24-layer train step), so a
+    program it compiles for a described TPU holds the kernel and one it
+    runs itself the caller's own attention."""
+    if jax.default_backend() == "tpu":
+        return splash_causal(q, k, v)
+    return lax.platform_dependent(q, k, v, tpu=splash_causal,
+                                  default=otherwise)
+
+
 def _xla_attention(q, k, v, mask, causal):
     """Plain fused-softmax attention — what XLA compiles best at short
     sequence (measured: beats every flash variant below ~1024 on v5e)."""
@@ -274,7 +356,8 @@ def _xla_attention(q, k, v, mask, causal):
 def attention(q, k, v, mask=None, causal: bool = False,
               impl: str = "auto"):
     """Dispatching flash attention. q,k,v: [N,H,T,dh]; mask: [N,Tk]
-    key-padding (1 = attend). impl: auto | library | pallas | blockwise.
+    key-padding (1 = attend). impl: auto | splash | library | pallas |
+    blockwise.
     """
     n, h, tq, dh = q.shape
     tk = k.shape[2]
@@ -282,7 +365,7 @@ def attention(q, k, v, mask=None, causal: bool = False,
 
     if impl == "auto":
         aligned = tq % 128 == 0 and tk % 128 == 0 and dh >= 64
-        if on_tpu and tk < 1024:
+        if on_tpu and tk < FLASH_MIN_KV:
             # short-KV: XLA's fused softmax attention beats every
             # flash variant (v5e measurements, BASELINE.md round 5 —
             # T=512 fwd: 4.0 ms XLA vs 6.1 ms best-tuned flash), and
@@ -292,6 +375,9 @@ def attention(q, k, v, mask=None, causal: bool = False,
             # exactly where flash's no-materialization matters, so it
             # must NOT fall through to the einsum path.
             impl = "xla"
+        elif on_tpu and causal and mask is None and tq == tk \
+                and causal_fits(tk):
+            impl = "splash"
         elif on_tpu and aligned:
             impl = "library"
         elif on_tpu and tq % 128 == 0 and tk % 128 == 0:
@@ -300,6 +386,8 @@ def attention(q, k, v, mask=None, causal: bool = False,
             impl = "blockwise"
     if impl == "xla":
         return _xla_attention(q, k, v, mask, causal)
+    if impl == "splash":
+        return splash_causal(q, k, v)
     if impl == "library":
         return _library_flash(q, k, v, mask, causal)
     if impl == "pallas":
@@ -309,4 +397,5 @@ def attention(q, k, v, mask=None, causal: bool = False,
     raise ValueError(f"unknown impl {impl!r}")
 
 
-__all__ = ["attention", "blockwise_attention", "pallas_flash_forward"]
+__all__ = ["attention", "blockwise_attention", "causal", "causal_fits",
+           "pallas_flash_forward", "splash_causal"]
