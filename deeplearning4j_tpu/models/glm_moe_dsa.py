@@ -47,8 +47,11 @@ rows of the embedding and of the head), exactly as
 ``models/exaone_moe.py``: attention, indexer, router, shared expert and
 dense layer are whole (a latent row cannot be split by head).
 
-The block is written ONCE (``_block``) and serves three callers by how
-it reads and writes cache (``_Dense`` / ``_Prefill`` / ``_Paged``):
+The block, the sparse feed-forward and the layer loop are
+``models/sparse_decoder.py``'s ``ExpertDecoder`` (shared with
+``models/exaone_moe.py`` and ``models/mellum.py``); the block serves
+three callers by how it reads and writes cache (``_Dense`` /
+``_Prefill`` / ``_Paged``):
 
 - ``forward``: no cache; logits of every position. The selection is a
   mask, made and applied a block of queries at a time.
@@ -83,9 +86,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.models.decoder_ops import rms_norm, rope_interleaved
-from deeplearning4j_tpu.models.routed_experts import (expert_stats,
-                                                      routed_experts)
+from deeplearning4j_tpu.models.decoder_ops import rope_interleaved
+from deeplearning4j_tpu.models.routed_experts import route_sigmoid
+from deeplearning4j_tpu.models.sparse_decoder import ExpertDecoder
 from deeplearning4j_tpu.ops.sparse_latent_attention_pallas import (
     index_select, selection_reads, sparse_latent_attention)
 from deeplearning4j_tpu.serving import kv_pages
@@ -395,7 +398,7 @@ class _Paged:
         return out.reshape(S, 1, H * dv)
 
 
-class GlmMoeDsaLM:
+class GlmMoeDsaLM(ExpertDecoder):
     """The model; ``compute_dtype`` is the dtype of its parameters and
     activations alike."""
 
@@ -473,9 +476,6 @@ class GlmMoeDsaLM:
         return p
 
     # -- pieces ---------------------------------------------------------
-    def _rms(self, x, g):
-        return rms_norm(x, g, self.cfg.rms_norm_eps)
-
     def _rope(self, x, pos):
         return rope_interleaved(x, pos, self.cfg.rope_theta)
 
@@ -523,62 +523,9 @@ class GlmMoeDsaLM:
         bias selects and does not weigh (``noaux_tc``), the selected
         scores normalised and scaled."""
         c = self.cfg
-        s = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), lp["router"].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, idx = lax.top_k(s + lp["router_bias"].astype(jnp.float32),
-                           c.num_experts_per_tok)
-        w = jnp.take_along_axis(s, idx, axis=-1)
-        if c.norm_topk_prob:
-            w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
-        return idx, w * c.routed_scaling_factor
-
-    def _swiglu(self, h, w1, w3, w2):
-        return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
-
-    def experts(self, lp, x, live=None, mode=None):
-        """The sparse feed-forward of ``x [m, d]`` -> ``([m, d], stats,
-        idx)``: the routed experts HELD here, dropless, plus the shared
-        expert (neither routed nor scaled); ``stats`` as
-        ``ExaoneMoeLM.experts``."""
-        c = self.cfg
-        idx, w = self.route(lp, x)
-        out, counts = routed_experts(
-            x, idx, w, lp["ew1"], lp["ew3"], lp["ew2"],
-            first=c.expert_offset, routed=c.n_routed_experts, live=live,
-            mode=mode)
-        rows = x.shape[0] if live is None else jnp.sum(live)
-        stats = jnp.concatenate([expert_stats(counts), jnp.reshape(
-            rows * c.num_experts_per_tok, (1,)).astype(jnp.int32)])
-        return out + self._swiglu(x, lp["sw1"], lp["sw3"], lp["sw2"]), \
-            stats, idx
-
-    def _block(self, li, lp, x, pos, cache, live, mode, aux):
-        c = self.cfg
-        x = x + self._attn(li, lp, self._rms(x, lp["in_norm"]), cache, pos)
-        h = self._rms(x, lp["post_norm"])
-        if c.mlp_layer_types[li] == "dense":
-            return x + self._swiglu(h, lp["w1"], lp["w3"], lp["w2"])
-        n, t, d = h.shape
-        y, stats, idx = self.experts(
-            lp, h.reshape(n * t, d),
-            None if live is None else live.reshape(n * t), mode)
-        aux["stats"].append(stats)
-        aux["experts"].append(idx.reshape(n, t, -1))
-        return x + y.reshape(n, t, d)
-
-    def _run(self, params, x, pos, cache, live, mode):
-        aux = {"stats": [], "experts": []}
-        for li, lp in enumerate(params["layers"]):
-            x = self._block(li, lp, x, pos, cache, live, mode, aux)
-        return self._rms(x, params["final_norm"]), aux
-
-    def _head(self, x, params):
-        return jnp.dot(x, params["head"],
-                       preferred_element_type=jnp.float32)
-
-    def _stats(self, aux):
-        return jnp.stack(aux["stats"]) if aux["stats"] else None
+        return route_sigmoid(x, lp["router"], lp["router_bias"],
+                             c.num_experts_per_tok, c.norm_topk_prob,
+                             c.routed_scaling_factor)
 
     # -- the three callers ---------------------------------------------
     def forward(self, params, ids, return_aux=False, mode=None):

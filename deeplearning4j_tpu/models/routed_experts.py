@@ -1,6 +1,7 @@
 """The dropless routed expert product, shared by every served model
-that has sparse experts (``models/lfm2_moe.py``,
-``models/exaone_moe.py``).
+that has sparse experts (``models/lfm2_moe.py``, ``models/exaone_moe.py``,
+``models/glm_moe_dsa.py``, ``models/mellum.py``), and the two routers
+that choose for it (``route_sigmoid``, ``route_softmax``).
 
 A layer is told which experts it HOLDS: the contiguous range ``[first,
 first + E)`` of the router's outputs, ``E`` the leading dimension of
@@ -22,9 +23,39 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from deeplearning4j_tpu.ops.grouped_matmul_pallas import (grouped_matmul,
                                                           row_tile)
+
+
+def _scores(x, router):
+    return jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
+def route_sigmoid(x, router, bias, k, normalise, scale):
+    """``x [m, d]`` -> (the router's choices ``[m, k]`` among ALL its
+    outputs, weights ``[m, k]`` float32): sigmoid scores in float32,
+    the per-expert ``bias`` selects and does not weigh, the selected
+    scores normalised (``1e-20`` beside their sum) where ``normalise``,
+    then scaled by ``scale``."""
+    s = jax.nn.sigmoid(_scores(x, router))
+    _, idx = lax.top_k(s + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if normalise:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return idx, w * scale
+
+
+def route_softmax(x, router, k, normalise):
+    """``x [m, d]`` -> (choices ``[m, k]``, weights ``[m, k]`` float32):
+    a softmax over all the router's outputs in float32, its top ``k``,
+    renormalised to sum to 1 where ``normalise``."""
+    w, idx = lax.top_k(jax.nn.softmax(_scores(x, router), axis=-1), k)
+    if normalise:
+        w = w / jnp.sum(w, -1, keepdims=True)
+    return idx, w
 
 
 def routed_experts(x, idx, w, ew1, ew3, ew2, *, first=0, routed=None,
@@ -75,4 +106,5 @@ def expert_stats(counts):
                       jnp.max(counts)]).astype(jnp.int32)
 
 
-__all__ = ["routed_experts", "expert_stats"]
+__all__ = ["routed_experts", "expert_stats", "route_sigmoid",
+           "route_softmax"]

@@ -672,3 +672,57 @@ def test_the_decode_chunk_gathers_no_row(one_chip, mode):
     else:
         assert "sparse_latent_attention" in hlo
         assert found == {"rows": 0, "addresses": 0}
+
+
+# ------- Mellum2-12B-A2.5B: a window of 1,024 in rings, 64 experts whole
+#: the cell's stores (BENCHMARK.json): the two full layers' pool (1 + 64
+#: slots x 768 pages) and the six window layers' rings of 65 pages,
+#: 4 KV heads x 128 a row
+MELLUM_POOL = (2, 1 + 64 * 768, 16, 4 * 128)
+MELLUM_RINGS = (6, 64, 65, 16, 4 * 128)
+
+
+@pytest.mark.parametrize("shape", [MELLUM_POOL, MELLUM_RINGS],
+                         ids=["full-pool", "window-rings"])
+def test_the_stores_of_a_window_of_1024_rest_row_major(one_chip, shape):
+    rests = jax.jit(lambda x: x).lower(jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=one_chip)) \
+        .compile().input_formats[0][0]
+    assert tuple(rests.layout.major_to_minor) == tuple(range(len(shape)))
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+def test_paged_kernel_compiles_for_32_heads_on_4_of_128(one_chip, window):
+    """Mellum2's decode step: 64 slots, 32 query heads on 4 KV heads of
+    128 (a row of 512 lanes), through a table of 768 pages for a full
+    layer and through a ring of 65 for a window layer (``window=1024``);
+    the stores go in as they rest."""
+    from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    Lw, S, R, ps, W = MELLUM_RINGS
+    shape = MELLUM_POOL if window is None else (Lw, S * R, ps, W)
+    pool = _sds(shape, bf16, one_chip)
+    hlo = jax.jit(lambda q, kv, t, b: paged_attention(
+        q, kv, 0, t, b, mode="pallas", window=window)) \
+        .lower(_sds((S, 1, 32, 128), bf16, one_chip), {"k": pool, "v": pool},
+               _sds((S, 768 if window is None else R), i32, one_chip),
+               _sds((S,), i32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "paged_attention" in hlo
+    assert pool_copies(hlo, pool)["all"] == 0
+
+
+@pytest.mark.parametrize("rows,k,n", [(512, 2304, 896), (512, 896, 2304),
+                                      (65536, 2304, 896)],
+                         ids=["decode-up", "decode-down", "prefill-up"])
+def test_grouped_matmul_compiles_for_64_experts_of_896(one_chip, rows, k, n):
+    """64 slots x 8 experts a token (or a bucket of 8,192) over all 64
+    experts of 2304 x 896."""
+    from deeplearning4j_tpu.ops.grouped_matmul_pallas import grouped_matmul
+
+    bf16 = jnp.bfloat16
+    hlo = jax.jit(lambda a, b, c: grouped_matmul(a, b, c, mode="pallas")) \
+        .lower(_sds((rows, k), bf16, one_chip),
+               _sds((64, k, n), bf16, one_chip),
+               _sds((64,), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "moe_experts" in hlo
