@@ -13,13 +13,12 @@ through the einsum pair
 
 materializing (a) the gathered page copies and (b) the full
 ``[n,h,q,p,o]`` logits tensor in HBM between the two contractions.
-This kernel instead walks each sequence's page table via scalar
-prefetch — the lookup happens in the BlockSpec index_map, so each K/V
-page block is DMA'd from the pool into VMEM directly, no gathered copy
-— and runs an online-softmax (flash-style) accumulation per sequence:
-a running max ``m``, running sum ``l`` and context accumulator carried
-in VMEM scratch across the sequence's visits. The logits tensor never
-exists; pages are read once.
+This kernel instead walks each sequence's page table, prefetched into
+scalar memory, and fetches each page it needs from the pool into VMEM
+itself, no gathered copy; it runs an online-softmax (flash-style)
+accumulation per sequence: a running max ``m``, running sum ``l`` and
+context accumulator carried in VMEM scratch across the sequence's
+visits. The logits tensor never exists; pages are read once.
 
 Layout contract (kv_pages.py): pools are ``[L, n_pages, ps, Hkv *
 hd]`` with page 0 the never-read null page: a page is ``ps`` rows, a
@@ -35,27 +34,50 @@ where query ``i`` of sequence ``n`` sits at absolute position
 N=1 with consecutive suffix positions for the prefix-prefill program,
 Q=k+1 with per-slot positions for the speculative verify call.
 
-The walk (PR 29; PERF.md section 6). A VISIT is one grid step: ``B``
-(8) consecutive table slots of one sequence, every KV head of each
-page in one block ``(1, 1, ps, Hkv * hd)`` — the page as it lies in the
-pool, no padding — as ``B`` K and ``B`` V operands over the same
-pool array, each with its own index map. The grid is ``(KV head
-blocks, visits)`` and its visit axis is DYNAMIC: ``_live_visits``
-computes, from the scalars the call already has, the pages each
-sequence HOLDS (up to the page of its last query, ``(qbase + Q - 1) //
-ps``), lays the sequences' visits end to end and hands the kernel that
-schedule (sequence, visit and the ``B`` page ids of every step) as
-prefetched scalars. So the steps, the DMAs and the work of a call
-follow the context held, not slots x heads x table width: a sequence
-of 235 positions in a table of 64 slots is 2 visits, not 20 x 64 grid
-steps. The slots of a sequence's final visit past its last live page
-name that page again (the clamp) and are masked; no table entry past
-the last live page is ever read, so what evicted or shorter sequences
-leave there cannot matter (``engine._evict``: position 0 and an
-all-null table is one visit of the null page). A first form kept the
-grid static, ``(N, P / B)``, clamped the index maps and skipped the
-body past the last live page: on the chip its dead steps still cost
-2 us each (GPT-2-large's shapes), 65 of a call's 76 us.
+The walk (PERF.md section 6). A VISIT is one grid step: up to ``B``
+consecutive table slots of one sequence, every KV head of each page (a
+page ``[ps, Hkv * hd]`` as it lies in the pool, no padding). The grid is ``(KV head blocks, visits)`` and its visit axis
+is DYNAMIC: ``_live_visits`` computes, from the scalars the call
+already has, the pages each sequence HOLDS (up to the page of its last
+query, ``(qbase + Q - 1) // ps``), lays the sequences' visits end to
+end and hands the kernel that schedule (sequence and visit of every
+step) with the page tables as prefetched scalars. So the steps, the
+DMAs and the work of a call follow the context held, not slots x heads
+x table width: a sequence of 235 positions in a table of 64 slots is
+one visit, not 20 x 64 grid steps.
+
+The pools stay in HBM (``memory_space=ANY``) and the kernel fetches its
+own pages: a page's K and its V a DMA each into one of two buffers
+``[B, ps, W]``, step ``g + 1``'s pages sent for before step ``g``'s are
+waited on, page by page, so a visit's fetch runs under the visit
+before it. Only the live slots are fetched or waited on: a slot past a
+sequence's last live page costs no byte, and its rows keep what they
+held (zeros, or a page of this call: the value buffers start a call as
+zeros, since unwritten VMEM may hold NaN and 0 x NaN is NaN in the
+product with the weights) and are masked. No table entry past the last
+live page is ever read, so what evicted or shorter sequences leave
+there cannot matter (``engine._evict``: position 0 and an all-null
+table is one visit of the null page). The fetch loops run in turns of
+8 slots laid out flat.
+
+A visit's size follows the call's shapes (``_visit_shape``): the most
+pages, up to 64, whose two buffers of K and V fit the VMEM budget
+beside the scratch and the working set, spread evenly over the table,
+so that a walk of the table's width does not end in a near-empty
+visit: 64 pages of 16 KB (Mellum2's and LFM2's full layers), 32 of 32
+or 40 KB (K-EXAONE's global layer, GPT-2-large), a ring of 65 as 33 +
+32 and one of 9 in one visit.
+
+History. The walk before this one handed the pipeline each visit's 8
+pages as 8 K and 8 V ``BlockSpec`` operands, each with its own index
+map, and clamped the slots past the last live page to it: bound by the
+pipeline's 45-60 ns a page fetch (measured on GLM's latent pages, whose
+walk fetches its own pages too) it read Mellum2's caches at 30.7% of
+the roofline, a quarter of that cell's device time (PERF.md section
+6). A first form before it kept the grid
+static, ``(N, P / B)``, clamped the index maps and skipped the body
+past the last live page: on the chip its dead steps still cost 2 us
+each (GPT-2-large's shapes), 65 of a call's 76 us.
 
 Inside a visit the heads lie along the lanes, and both products run
 on the MXU a LANE TILE at a time (``_lane_tile``): 128 lanes of whole
@@ -67,16 +89,16 @@ those of head ``i // rows`` zeroed, so ONE product of the stacked rows
 contracted over the tile's lanes, scores every head of the tile, and
 one product of the weights with the tile's values gives each stacked
 row its head's context on its head's lanes; the other lanes of a row
-are dropped at the end. Both products are batched over the block's
-tiles, and the online softmax between them works on ``[tiles, hpt *
-rows, B * ps]``, a vreg or two a tile at decode. bf16 pages under bf16
-queries go to the MXU as they are stored (their products are exact in
-the f32 it accumulates in; no page is converted), the f32 weights as
-two bf16 halves over one pass of the values; f32 or fp8 pools are
-worked on in f32. Scores, max, sum and accumulator are f32 always. One
+are dropped at the end. The products run a tile at a time on the
+pages as they lie in their buffer (no copy of a visit), and the online
+softmax between them works on ``[tiles, hpt * rows, B * ps]``. bf16
+pages under bf16 queries go to the MXU as they are stored (their
+products are exact in the f32 it accumulates in; no page is
+converted), the f32 weights as two bf16 halves over one pass of the
+values; f32 or fp8 pools are worked on in f32. Scores, max, sum and accumulator are f32 always. One
 form for one query row a head and for a suffix prefill's bucket: with
 many rows, fewer heads a visit where the scores, the weights and the
-scratch would pass the VMEM budget (``_heads_a_visit``; the head
+scratch would pass the VMEM budget (``_visit_shape``; the head
 blocks are the grid's outer axis, whole lane tiles each). (PR 29's
 kernel kept the heads on a leading axis, scored few rows on the VPU —
 a lane reduction a head and key, an ``exp`` over vregs one lane full —
@@ -107,9 +129,11 @@ argument, and without it the program is the one above, op for op.
 
 fp8 KV (``kv_dtype="fp8_e4m3"``): the pools store float8_e4m3fn with
 per-page-per-head fp32 scale planes ``[L, n_pages, H]`` beside them;
-the kernel dequantizes each page block in VMEM (one multiply by its
-heads' scales, each spread over its head's lanes) so HBM traffic stays
-fp8 — the other half of the bytes/step reduction.
+the pages are fetched as the others, and the scales of a visit's pages,
+gathered by the schedule, come as one block a step; the kernel
+dequantizes each page in VMEM (one multiply by its heads' scales, each
+spread over its head's lanes) so HBM traffic stays fp8 — the other
+half of the bytes/step reduction.
 
 Dispatch (``paged_attention_mode()``), mirroring
 ``DL4J_TPU_FUSED_UPDATE``:
@@ -214,10 +238,14 @@ def _xla_paged_attention(q, kv, layer, tables, qbase, group=1,
 
 
 # -------------------------------------------------------------- kernel
-#: page-table slots a visit (one grid step) brings into VMEM
-_PAGES_A_VISIT = 8
-#: what a visit's blocks, scratch and temporaries may take of the
-#: scoped VMEM (16 MiB on a v5e): sets the KV heads a visit
+#: the most table slots a visit brings into VMEM (on the chip GLM's
+#: walk took 0.69 / 0.54 / 0.48 / 0.47 ms a call at 16 / 32 / 64 / 128
+#: pages a visit: PERF.md section 6)
+_MOST_PAGES_A_VISIT = 64
+#: table slots a turn of the kernel's fetch loops sends for or waits on
+_PAGES_A_TURN = 8
+#: what a visit's buffers, scratch and temporaries may take of the
+#: scoped VMEM (16 MiB on a v5e): sets the KV heads and pages a visit
 _VMEM_BUDGET = 8 << 20
 
 
@@ -230,27 +258,32 @@ def _lane_tile(W, hd):
     return hd if hd % 128 == 0 else W
 
 
-def _heads_a_visit(Hkv, rows, hd, ps, B, itemsize, q_itemsize, fp8):
-    """Most KV heads (a divisor of ``Hkv`` whose lanes are whole lane
-    tiles) whose visit fits the VMEM budget: double-buffered K and V
-    pages (and, fp8, their scales, a tile a lane tile), the query and
-    output blocks, the f32 scratch, and the working set (the stacked
-    queries, the scores, the weights and their two halves, the
-    products; the keys and values too where they are not fed to the
-    MXU as stored). Rows count as the sublane tiles they occupy; lanes
-    are real, a page row has no padding."""
+def _visit_shape(Hkv, rows, hd, ps, P, itemsize, q_itemsize, fp8):
+    """``(heads, pages)`` a visit takes, from the call's shapes. Heads:
+    the most (a divisor of ``Hkv`` whose lanes are whole lane tiles)
+    whose visit of 8 pages fits the VMEM budget; pages: the most, a
+    power of two up to ``_MOST_PAGES_A_VISIT``, whose visit with those
+    heads fits. A visit holds two buffers of K and of V pages (and,
+    fp8, their scales ``[B, 1, heads]``), the query and output
+    blocks, the f32 scratch, and the working set (the stacked queries,
+    the scores, the weights and their two halves, the products; the
+    keys and values too where they are not fed to the MXU as stored).
+    Rows count as the sublane tiles they occupy; lanes are real, a
+    page row has no padding. Then the table's ``P`` slots are spread
+    evenly over the visits they need, so that no walk of the table's
+    width (a ring's ``R`` or ``R - 1`` pages) ends in a near-empty
+    visit: a ring of 65 is 2 visits of 33, not 64 + 1."""
     tw = _lane_tile(Hkv * hd, hd)
     Rp = -(-(tw // hd) * rows // 8) * 8      # stacked query rows a tile
     r8 = -(-rows // 8) * 8
-    T = max(B * ps, 128)
     as_stored = itemsize == 2 and q_itemsize == 2
 
-    def visit(h):
-        W = h * hd
+    def size(h, B):
+        W, T = h * hd, max(B * ps, 128)
         tiles = W // tw
         pages = 2 * 2 * B * max(ps, 32 // itemsize) * W * itemsize
         if fp8:
-            pages += 2 * 2 * B * tiles * 8 * 128 * 4
+            pages += 2 * 2 * B * -(-h // 128) * 8 * 128 * 4
         qo = 2 * 2 * r8 * W * q_itemsize
         scratch = tiles * Rp * (tw + 2 * 128) * 4
         work = tiles * Rp * (4 * T + 3 * tw) * 4
@@ -260,7 +293,12 @@ def _heads_a_visit(Hkv, rows, hd, ps, B, itemsize, q_itemsize, fp8):
 
     fits = [h for h in range(Hkv, 0, -1)
             if Hkv % h == 0 and (h * hd) % tw == 0]
-    return next((h for h in fits if visit(h) <= _VMEM_BUDGET), fits[-1])
+    heads = next((h for h in fits if size(h, 8) <= _VMEM_BUDGET), fits[-1])
+    B = _MOST_PAGES_A_VISIT
+    while B > 8 and size(heads, B) > _VMEM_BUDGET:
+        B //= 2
+    visits = -(-P // B)
+    return heads, -(-P // visits)
 
 
 def _live_visits(tables, qbase, n_queries, ps, B, window=None):
@@ -269,12 +307,12 @@ def _live_visits(tables, qbase, n_queries, ps, B, window=None):
     pages ``pages[g * B:(g + 1) * B]``; ``total`` steps cover every page
     the sequences hold and nothing else. A sequence holds the pages up
     to ``last[n]``, the page of its last query; the slots of its final
-    visit past that page name it again (the kernel masks them), so no
-    table entry beyond a sequence's last live page is ever read. With
-    ``window`` the table is a ring: the walk starts at ``first[n]``, the
-    page of the first position the first query's window admits,
-    ``last`` is a page NUMBER, and page number ``n`` is table entry ``n
-    % P``."""
+    visit past that page name it again, so no table entry beyond a
+    sequence's last live page is ever read. With ``window`` the table
+    is a ring: the walk starts at ``first[n]``, the page of the first
+    position the first query's window admits, ``last`` is a page
+    NUMBER, and page number ``n`` is table entry ``n % P``. (The paged
+    kernel reads the table itself, and ``pages`` only for fp8 scales.)"""
     N, P = tables.shape
     i32 = jnp.int32
     if window is None:
@@ -301,46 +339,111 @@ def _live_visits(tables, qbase, n_queries, ps, B, window=None):
                  for a in (lane, visit, pages, last, ends[-1]))
 
 
-def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
-            q_ref, *rest, page_size, head_dim, sm_scale, fp8, group,
-            pages, window=None):
-    """One visit (grid step ``g``) of the online-softmax walk: ``pages``
-    table slots of sequence ``lane[g]``, every KV head of the block at
-    once, the heads along the lanes. The block's ``W`` lanes are
-    ``tiles`` lane tiles of ``hpt`` whole heads each (``_lane_tile``).
-    A tile's heads are STACKED on the query axis: stacked row ``i`` of
-    a tile is query row ``i % rows`` with every lane but those of head
-    ``i // rows`` zeroed, so one product of the stacked rows with the
-    tile's keys, contracted over the tile's lanes, scores every head of
-    the tile, and one product of the weights with the tile's values
-    gives each stacked row its head's context on its head's lanes.
-    Both are batched over the tiles. Scratch (``m``, ``l`` ``[tiles,
+def _kernel(layer_ref, lane_ref, visit_ref, table_ref, qbase_ref, last_ref,
+            total_ref, q_ref, k_hbm, v_hbm, *rest, page_size, head_dim,
+            sm_scale, fp8, group, window=None):
+    """One visit (grid step ``g``) of the online-softmax walk: the
+    ``B`` table slots from visit ``visit[g]`` on of sequence
+    ``lane[g]``, the ``W`` lanes of KV head block ``h`` of each page.
+    The pools stay in HBM; the kernel fetches a page's K and its V a
+    DMA each into ``buf[g % 2]`` ``[B, ps, W]``, sending for step ``g +
+    1``'s pages before it waits, page by page, for step ``g``'s. Only
+    slots up to the sequence's last live page are fetched or waited
+    on; the rows of the others keep what they held, zeros or a page of
+    this call. fp8 scales come as a block ``[B, 1, W / hd]`` a step.
+    The block's ``W`` lanes are ``tiles`` lane tiles of ``hpt`` whole
+    heads each (``_lane_tile``). A tile's heads are STACKED on the
+    query axis: stacked row ``i`` of a tile is query row ``i % rows``
+    with every lane but those of head ``i // rows`` zeroed, so one
+    product of the stacked rows with the tile's keys, contracted over
+    the tile's lanes, scores every head of the tile, and one product of
+    the weights with the tile's values gives each stacked row its
+    head's context on its head's lanes. Scratch (``m``, ``l`` ``[tiles,
     Rp, 1]``, ``acc`` ``[tiles, Rp, tw]``, ``Rp`` the stacked rows in
     whole sublane tiles) persists across a sequence's consecutive
     visits; initialized at its first, finalized into the output block
     at its last."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    del layer_ref, page_ref          # read by the index maps
-    B, ps, hd = pages, page_size, head_dim
-    k_refs, v_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
-    ks_refs = vs_refs = (None,) * B
+    ks_ref = vs_ref = None
     if fp8:
-        ks_refs, vs_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
-    o_ref, m_ref, l_ref, acc_ref = rest
-    g = pl.program_id(1)
+        ks_ref, vs_ref, *rest = rest
+    o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = rest
+    B, ps, hd = k_buf.shape[1], page_size, head_dim
+    P = table_ref.shape[1]
+    h, g = pl.program_id(0), pl.program_id(1)
     n, j = lane_ref[g], visit_ref[g]
     qbase, last = qbase_ref[n], last_ref[n]
     rows = q_ref.shape[1]
     tiles, Rp, tw = acc_ref.shape
-    hpt, T = tw // hd, B * ps
+    hpt, T, W = tw // hd, B * ps, tiles * tw
+    half = g % 2
     f32, bf16 = jnp.float32, jnp.bfloat16
     # bf16 queries over bf16 pages go to the MXU as they are stored:
     # their products are exact in the f32 it accumulates in
-    as_stored = q_ref.dtype == bf16 and k_refs[0].dtype == bf16
+    as_stored = q_ref.dtype == bf16 and k_buf.dtype == bf16
     mm = bf16 if as_stored else f32
     # Mosaic refuses bf16 operands under a caller's "highest"
     precision = lax.Precision.DEFAULT if as_stored else None
+
+    def first_page(lane):
+        """The page NUMBER a sequence's walk starts at: its first, or,
+        under a window, the page of the first position the first
+        query's window admits."""
+        if window is None:
+            return 0
+        return jnp.maximum(qbase_ref[lane] - (window - 1), 0) // ps
+
+    # a page's lanes of head block ``h``
+    lanes = (slice(None) if W == k_hbm.shape[3]
+             else pl.ds(pl.multiple_of(h * W, W), W))
+
+    def each_page(step, into, do):
+        """``do`` the fetch of each live page of grid step ``step`` into
+        ``buf[into]``: a rolled loop over turns of ``_PAGES_A_TURN``
+        slots, each turn laid out flat by the lowering (on the chip, at
+        Mellum2's full layer, 831 us a call with all ``B`` slots laid
+        out flat, 757 in turns of 8, 914 rolled page by page; unrolled
+        in Python the loops cost seconds of every warm start: PERF.md
+        section 6)."""
+        lane = lane_ref[step]
+        base = first_page(lane) + visit_ref[step] * B
+        live = jnp.minimum(last_ref[lane] - base + 1, B)
+
+        def one(i, carry):
+            @pl.when(i < live)
+            def _live():
+                slot = base + i if window is None else (base + i) % P
+                page = table_ref[lane, slot]
+                for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    do(pltpu.make_async_copy(
+                        pool.at[layer_ref[0], page, :, lanes],
+                        buf.at[into, i], sem.at[into]))
+            return carry
+
+        def turn(t, carry):
+            return lax.fori_loop(
+                0, _PAGES_A_TURN, lambda u, c: one(t * _PAGES_A_TURN + u, c),
+                carry, unroll=True)
+
+        lax.fori_loop(0, (live + _PAGES_A_TURN - 1) // _PAGES_A_TURN, turn,
+                      0)
+
+    start, wait = lambda c: c.start(), lambda c: c.wait()
+
+    @pl.when(g == 0)
+    def _first():
+        # the weights of a slot that is not fetched are 0, but 0 x NaN
+        # is NaN in the product: the values' rows start as zeros
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        each_page(0, 0, start)                 # once a call
+
+    @pl.when(g + 1 < total_ref[0])
+    def _ahead():
+        each_page(g + 1, 1 - half, start)
+
+    each_page(g, half, wait)
 
     @pl.when(j == 0)
     def _init():
@@ -348,26 +451,26 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
         l_ref[...] = jnp.zeros(l_ref.shape, f32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
-    stack = lambda f: jnp.stack([f(c) for c in range(tiles)])
     sub = lax.broadcasted_iota(jnp.int32, (1, Rp, 1), 1)
     head_of_lane = lax.broadcasted_iota(jnp.int32, (1, 1, tw), 2) // hd
 
-    def tile_of(refs, scales, c):
-        """Lane tile ``c`` of the visit's ``B`` pages end to end
-        (``ps`` is whole sublane tiles): ``[T, tw]``; an fp8 page in
-        f32, times its heads' scales, each on its head's lanes."""
-        out = []
-        for ref, scale in zip(refs, scales):
-            x = ref[0, 0, :, c * tw:(c + 1) * tw].astype(mm)
-            if fp8:
-                sc = scale[0, 0, c]                        # [1, hpt]
-                lanes = sc[:, 0:1]
-                for h in range(1, hpt):
-                    lanes = jnp.where(head_of_lane[0] == h,
-                                      sc[:, h:h + 1], lanes)
-                x = x * lanes
-            out.append(x)
-        return out[0] if B == 1 else jnp.concatenate(out, axis=0)
+    def tile_of(buf, scales, c):
+        """Lane tile ``c`` of the visit's pages end to end, ``[T,
+        tw]``; an fp8 page in f32, times its heads' scales, each on its
+        head's lanes."""
+        x = buf[half, :, :, c * tw:(c + 1) * tw].astype(mm)
+        if fp8:
+            sc = scales[0, 0, :, :, c * hpt:(c + 1) * hpt]  # [B, 1, hpt]
+            by_lane = sc[:, :, 0:1]
+            for hh in range(1, hpt):
+                by_lane = jnp.where(head_of_lane == hh, sc[:, :, hh:hh + 1],
+                                    by_lane)
+            x = x * by_lane
+        return x.reshape(T, tw)
+
+    k_of = lambda c: tile_of(k_buf, ks_ref, c)
+    v_of = lambda c: tile_of(v_buf, vs_ref, c)
+    stack = lambda f: jnp.stack([f(c) for c in range(tiles)])
 
     # the stacked queries [tiles, Rp, tw] (laid out in f32: its masks
     # are whole sublane tiles)
@@ -379,27 +482,23 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
         for i in range(hpt * rows):
             q = jnp.where(sub == i, each[:, i % rows:i % rows + 1], q)
     q = jnp.where(head_of_lane == sub // rows, q, 0.0).astype(mm)
-    k = stack(lambda c: tile_of(k_refs, ks_refs, c))    # [tiles, T, tw]
-    v = stack(lambda c: tile_of(v_refs, vs_refs, c))
 
-    s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                        precision=precision,
-                        preferred_element_type=f32) * sm_scale
-    # a key at flat position j*T + t is admitted by stacked row i
-    # (query (i % rows) // G of its head) iff it is <= qbase[n] + that
-    # (causal) and lies on a page the sequence holds (a slot past the
-    # last live page names that page again)
-    pos = j * T + lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
-    if window is not None:
-        # the walk began at the page of the first position the first
-        # query's window admits; a row admits its own window only
-        first = jnp.maximum(qbase - (window - 1), 0) // ps
-        pos = pos + first * ps
+    # a tile at a time: the pages are read from their buffer, no copy
+    s = stack(lambda c: lax.dot_general(
+        q[c], k_of(c), (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=f32)) * sm_scale        # [tiles, Rp, T]
+    # a key at flat position p is admitted by stacked row i (query
+    # (i % rows) // G of its head) iff p <= qbase[n] + that (causal)
+    # and lies on a page the sequence holds (the slots past the last
+    # live page were not fetched)
+    first = first_page(n)
+    pos = (first + j * B) * ps + lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
     valid = (pos <= qbase + (sub % rows) // group) \
         & (pos < (last + 1) * ps)                       # [1, Rp, T]
     if window is not None:
+        # a row admits its own window only
         valid = valid & (pos > qbase + (sub % rows) // group - window)
-    s = jnp.where(valid, s, _MASK_MIN)                  # [tiles, Rp, T]
+    s = jnp.where(valid, s, _MASK_MIN)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
@@ -408,9 +507,9 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
     p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
     m_ref[...] = m_new
-    weigh = lambda w: lax.dot_general(
-        w, v, (((2,), (1,)), ((0,), (0,))), precision=precision,
-        preferred_element_type=f32)
+    weigh = lambda w: stack(lambda c: lax.dot_general(
+        w[c], v_of(c), (((1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=f32))
     if as_stored:
         # the f32 weights as two bf16 halves (16 bits of mantissa
         # between them), one product over the values as stored
@@ -421,16 +520,16 @@ def _kernel(layer_ref, lane_ref, visit_ref, page_ref, qbase_ref, last_ref,
         ctx = weigh(p)
     acc_ref[...] = acc_ref[...] * alpha + ctx
 
-    @pl.when(j == (last // B if window is None else (last - first) // B))
+    @pl.when(j == (last - first) // B)
     def _finish():
         # every query admits flat position 0 (qpos >= 0 always; under
         # a window, its own position), so l >= exp(0) == 1 at the end
         # of the walk: safe division
         full = acc_ref[...] / l_ref[...]
         out = full[:, :rows]
-        for h in range(1, hpt):
-            out = jnp.where(head_of_lane == h,
-                            full[:, h * rows:(h + 1) * rows], out)
+        for hh in range(1, hpt):
+            out = jnp.where(head_of_lane == hh,
+                            full[:, hh * rows:(hh + 1) * rows], out)
         for c in range(tiles):
             o_ref[0, :, c * tw:(c + 1) * tw] = out[c].astype(o_ref.dtype)
 
@@ -452,63 +551,59 @@ def _pallas_paged_attention(q, kv, layer, tables, qbase, interpret,
     P = tables.shape[1]
     ps = kv["k"].shape[2]
     fp8 = "k_scale" in kv
-    B = min(_PAGES_A_VISIT, P)
-    Hh = _heads_a_visit(H, rows, hd, ps, B, kv["k"].dtype.itemsize,
-                        q.dtype.itemsize, fp8)
+    Hh, B = _visit_shape(H, rows, hd, ps, P, kv["k"].dtype.itemsize,
+                         q.dtype.itemsize, fp8)
     tw = _lane_tile(H * hd, hd)
     W, tiles, hpt = Hh * hd, Hh * hd // tw, tw // hd
     Rp = -(-hpt * rows // 8) * 8
     qbase = qbase.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    # the kernel looks a visit's pages up in the table as it sends for
+    # them; the schedule's own page list serves fp8 scales alone (XLA
+    # drops it unused)
     lane, visit, pages, last, total = _live_visits(
-        tables.astype(jnp.int32), qbase, rows // group, ps, B, window)
+        tables, qbase, rows // group, ps, B, window)
     # the walk is as long as the pages held: the visit axis is dynamic
     grid = (H // Hh, total)
-
-    # scalar-prefetch index maps: the schedule's page id IS the
-    # BlockSpec index, so each page block DMAs straight from the pool
-    # (index_map args: grid indices, then the prefetched scalar refs)
-    def page_map(i):
-        return lambda h, g, ly, ln, vi, pg, *_: (ly[0], pg[g * B + i],
-                                                 0, h)
-
-    def scale_map(i):
-        return lambda h, g, ly, ln, vi, pg, *_: (ly[0], pg[g * B + i],
-                                                 h, 0, 0)
-
     q_spec = pl.BlockSpec((1, rows, W),
                           lambda h, g, ly, ln, *_: (ln[g], 0, h))
-    kv_specs = [pl.BlockSpec((1, 1, ps, W), page_map(i))
-                for i in range(B)]
-    in_specs = [q_spec] + kv_specs + kv_specs
-    args = [q.reshape(N, rows, H * hd)] + [kv["k"]] * B + [kv["v"]] * B
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, hbm, hbm]
+    args = [q.reshape(N, rows, H * hd), kv["k"], kv["v"]]
     if fp8:
-        # one scale per (layer, page, head), viewed a lane tile's heads
-        # a row ``[L, n_pages, H / hpt, 1, hpt]`` so that a block's last
-        # two dims are the array's
-        sc_specs = [pl.BlockSpec((1, 1, tiles, 1, hpt), scale_map(i))
-                    for i in range(B)]
-        in_specs += sc_specs + sc_specs
-        view = lambda sc: sc.reshape(*sc.shape[:2], H // hpt, 1, hpt)
-        args += [view(kv["k_scale"])] * B + [view(kv["v_scale"])] * B
+        # the scales of a step's pages, gathered by the schedule (a
+        # slot past the last live page has that page's): one block
+        # ``[B, 1, Hh]`` a step and head block, the array's last two dims
+        steps = pages.shape[0] // B
+        view = lambda sc: sc[layer[0]][pages] \
+            .reshape(steps, B, H // Hh, 1, Hh).transpose(0, 2, 1, 3, 4)
+        in_specs += [pl.BlockSpec((1, 1, B, 1, Hh),
+                                  lambda h, g, *_: (g, h, 0, 0, 0))] * 2
+        args += [view(kv["k_scale"]), view(kv["v_scale"])]
     kernel = functools.partial(
         _kernel, page_size=ps, head_dim=hd,
         sm_scale=float(1.0 / np.sqrt(np.float32(hd))), fp8=fp8,
-        group=group, pages=B, window=window)
+        group=group, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=7,
             grid=grid,
+            # the pools stay in HBM: the kernel fetches its own pages
             in_specs=in_specs,
             out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((tiles, Rp, 1), jnp.float32),
-                            pltpu.VMEM((tiles, Rp, 1), jnp.float32),
-                            pltpu.VMEM((tiles, Rp, tw), jnp.float32)]),
+            scratch_shapes=[
+                pltpu.VMEM((2, B, ps, W), kv["k"].dtype),
+                pltpu.VMEM((2, B, ps, W), kv["v"].dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((tiles, Rp, 1), jnp.float32),
+                pltpu.VMEM((tiles, Rp, 1), jnp.float32),
+                pltpu.VMEM((tiles, Rp, tw), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((N, rows, H * hd), q.dtype),
         interpret=interpret,
         # what a device trace calls the kernel (PERF.md section 3)
         name="paged_attention",
-    )(layer, lane, visit, pages, qbase, last, *args)
+    )(layer, lane, visit, tables, qbase, last, total.reshape(1), *args)
     return out.reshape(N, rows, H, hd)
 
 

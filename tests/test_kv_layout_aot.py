@@ -33,9 +33,10 @@ programs hold no ``convert`` with a weight's shape; the controls hand
 the same programs, and ``decode_step`` itself, the float32 tree.
 
 The kernel's grid is (KV head blocks, live visits): a visit holds every
-KV head of eight pages of one sequence, the heads along the lanes, and
-the visits are as many as the pages the sequences hold (PR 29); the
-last tests compile it at the cells' shapes.
+KV head of up to 64 pages of one sequence, the heads along the lanes,
+fetched by the kernel itself from pools left in HBM, and the visits are
+as many as the pages the sequences hold; the last tests compile it at
+the cells' shapes.
 
 The topology is described inside a fixture, never at import (one
 process a time may load libtpu; xdist workers all import this file).
@@ -383,7 +384,7 @@ def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, k, n):
 def test_paged_kernel_compiles_at_published_widths(one_chip, cell, slots,
                                                    queries, kv_dtype):
     """The chip's compiler takes the walk at the shapes the cells run:
-    every KV head of eight pages a visit, the heads along the lanes
+    every KV head of the pages a visit takes, the heads along the lanes
     and a lane tile's heads stacked on the query axis, the visits as
     many as the pages held (a dynamic grid), fewer heads a visit where
     the rows are many, the group of 4 on the query axis; and the pools
@@ -726,3 +727,65 @@ def test_grouped_matmul_compiles_for_64_experts_of_896(one_chip, rows, k, n):
                _sds((64, k, n), bf16, one_chip),
                _sds((64,), jnp.int32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo and "moe_experts" in hlo
+
+
+# ------------- the walk fetches its own pages: the visit the shapes choose
+#: the served decode shapes: pool, queries ``[N, Q, H, hd]``, the table's
+#: width, the window, and the table slots a visit takes
+WALKS = {
+    "mellum2-ring": ((MELLUM_RINGS[0], 64 * 65, 16, 512), (64, 1, 32, 128),
+                     65, 1024, 33),
+    "mellum2-full": (MELLUM_POOL, (64, 1, 32, 128), 768, None, 64),
+    "k-exaone-ring": ((EXAONE_RINGS[0], 64 * 9, 16, 1024), (64, 1, 64, 128),
+                      9, 128, 9),
+    "gpt2-large": (GPT2_LARGE[0], (4, 1, 20, 64), 64, None, 32),
+    "lfm2-24b-a2b": (LFM2_24B[0], (16, 1, 32, 64), 64, None, 64),
+}
+
+
+def _walk_hlo(one_chip, name):
+    from deeplearning4j_tpu.ops.paged_attention_pallas import paged_attention
+
+    shape, (S, Q, H, hd), P, window, _ = WALKS[name]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = _sds(shape, bf16, one_chip)
+    hlo = jax.jit(lambda q, kv, t, b: paged_attention(
+        q, kv, 0, t, b, mode="pallas", window=window)) \
+        .lower(_sds((S, Q, H, hd), bf16, one_chip), {"k": pool, "v": pool},
+               _sds((S, P), i32, one_chip), _sds((S,), i32, one_chip)) \
+        .compile().as_text()
+    return hlo, pool
+
+
+@pytest.mark.parametrize("name", list(WALKS))
+def test_the_walk_compiles_with_the_visit_its_shapes_choose(one_chip, name):
+    """Every KV head and the most pages whose two buffers fit beside the
+    working set (``_visit_shape``), the table spread evenly over its
+    visits: 64 pages of 16 KB, 32 of 32 or 40 KB, a ring of 65 as 33 +
+    32 and one of 9 in one visit. The chip's compiler places the
+    buffers in the scoped VMEM or refuses the kernel (the control
+    below), and the pools stay in HBM as they rest, with no copy."""
+    from deeplearning4j_tpu.ops import paged_attention_pallas as pa
+
+    shape, (S, Q, H, hd), P, _, B = WALKS[name]
+    Hkv = shape[3] // hd
+    assert pa._visit_shape(Hkv, Q * H // Hkv, hd, shape[2], P, 2, 2,
+                           False) == (Hkv, B)
+    hlo, pool = _walk_hlo(one_chip, name)
+    assert "tpu_custom_call" in hlo and "paged_attention" in hlo
+    assert pool_copies(hlo, pool)["all"] == 0
+
+
+def test_a_visit_past_the_scoped_vmem_is_refused(one_chip, monkeypatch):
+    """The control: 256 pages of 16 KB a visit, two buffers of K and of
+    V (16 MiB), do not fit, and the compiler says so."""
+    from deeplearning4j_tpu.ops import paged_attention_pallas as pa
+
+    monkeypatch.setattr(pa, "_MOST_PAGES_A_VISIT", 256)
+    monkeypatch.setattr(pa, "_VMEM_BUDGET", 64 << 20)
+    pa._pallas_paged_attention.clear_cache()
+    try:
+        with pytest.raises(Exception, match="vmem"):
+            _walk_hlo(one_chip, "mellum2-full")
+    finally:
+        pa._pallas_paged_attention.clear_cache()

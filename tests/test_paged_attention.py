@@ -13,6 +13,8 @@ identity (xla vs interpret, including sticky-session resume and
 prefix-cache hits) with pools draining to zero.
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -75,6 +77,29 @@ def _both(q, kv, layer, tables, qbase):
     xla = np.asarray(paged_attention(q, kv, layer, tables, qbase,
                                      mode="xla"))
     return ker, xla
+
+
+@contextlib.contextmanager
+def _most_pages(n):
+    """The kernel's visits take at most ``n`` table slots (the rule's cap
+    is the chip's; toy tables need a small one to be walked in several
+    visits). The kernel's jitted wrapper is traced anew on both sides:
+    its cache is keyed by shapes, not by the cap."""
+    was = pa._MOST_PAGES_A_VISIT
+    pa._MOST_PAGES_A_VISIT = n
+    pa._pallas_paged_attention.clear_cache()
+    try:
+        yield
+    finally:
+        pa._MOST_PAGES_A_VISIT = was
+        pa._pallas_paged_attention.clear_cache()
+
+
+@pytest.fixture
+def most_pages():
+    """``most_pages(n)`` for one test (``_most_pages``)."""
+    with contextlib.ExitStack() as stack:
+        yield lambda n: stack.enter_context(_most_pages(n))
 
 
 # ------------------------------------------------------- kernel golden
@@ -242,21 +267,33 @@ def _walk_case(qbase, P, *, ps, hd, Hkv, Q=1, G=1, fp8=False,
 
 
 class TestLiveWalk:
-    """What a walk over live pages only, ``B`` table slots and every KV
-    head a visit, can get wrong (``_walk_case``: every table slot past
-    a lane's last live page names a page of NaN)."""
+    """What a walk over live pages only, every KV head a visit and at
+    most ``MOST`` table slots, spread evenly over a table's visits
+    (``_visit_shape``), can get wrong (``_walk_case``: every table slot
+    past a lane's last live page names a page of NaN)."""
 
     PS, HD, HKV = 4, 8, 2
-    B = pa._PAGES_A_VISIT                 # table slots a visit
-    VISIT = B * PS                        # positions a visit
+    MOST = 8                              # table slots a visit, at most
+
+    @pytest.fixture(scope="class", autouse=True)
+    def _cap(self):
+        with _most_pages(self.MOST):      # once a class: shapes reuse traces
+            yield
+
+    def visit(self, P):
+        """Table slots a visit of a table ``P`` slots wide."""
+        return pa._visit_shape(self.HKV, 1, self.HD, self.PS, P, 4, 4,
+                               False)[1]
 
     def _case(self, qbase, P, **kw):
         _walk_case(qbase, P, ps=self.PS, hd=self.HD, Hkv=self.HKV, **kw)
 
     @pytest.mark.parametrize("fp8", [False, True], ids=["f32", "fp8"])
     def test_one_position_beside_a_full_table(self, fp8):
-        P = 3 * self.B
-        self._case([0, P * self.PS - 1, 5, self.VISIT + 1], P, fp8=fp8)
+        P = 3 * self.MOST
+        assert self.visit(P) == self.MOST
+        self._case([0, P * self.PS - 1, 5, self.MOST * self.PS + 1], P,
+                   fp8=fp8)
 
     @pytest.mark.parametrize("edge", [
         "page-1", "page", "page+1", "visit-1", "visit", "visit+1",
@@ -265,9 +302,10 @@ class TestLiveWalk:
     def test_qbase_at_every_edge(self, edge, Q):
         """The last query on the last position of a page or a visit,
         on the first of the next, and one further."""
-        P = 2 * self.B + 3
-        at = {"page": self.PS, "visit": self.VISIT,
-              "2visit": 2 * self.VISIT, "table": P * self.PS}
+        P = 2 * self.MOST + 3
+        visit = self.visit(P) * self.PS   # 3 visits of 7 slots
+        at = {"page": self.PS, "visit": visit, "2visit": 2 * visit,
+              "table": P * self.PS}
         name, _, d = edge.partition("-" if "-" in edge else "+")
         pos = at[name] + {"": 0, "1": -1 if "-" in edge else 1}[d]
         # ``pos`` is where the LAST query sits
@@ -276,15 +314,60 @@ class TestLiveWalk:
     @pytest.mark.parametrize("where", [0, 1, 2])
     def test_an_evicted_lane_beside_live_ones(self, where):
         """``engine._evict``: position 0 and an all-null table."""
-        qbase = [self.VISIT + 3, 2 * self.VISIT, 9]
+        P = 2 * self.MOST + 1
+        visit = self.visit(P) * self.PS
+        qbase = [visit + 3, 2 * visit, 9]
         qbase[where] = 0
-        self._case(qbase, 2 * self.B + 1, evicted=[where], seed=where)
+        self._case(qbase, P, evicted=[where], seed=where)
 
-    @pytest.mark.parametrize("P", [1, 3, pa._PAGES_A_VISIT + 1,
-                                   2 * pa._PAGES_A_VISIT + 5])
+    @pytest.mark.parametrize("P", [1, 3, MOST + 1, 2 * MOST + 5])
     def test_table_width_not_a_multiple_of_a_visit(self, P):
+        """Visits of the most slots that spread the table evenly: 1, 3,
+        5 + 4, 7 x 3, the lane of half the table's positions ending
+        inside a visit."""
+        B = self.visit(P)
+        assert B <= self.MOST and -(-P // B) == -(-P // self.MOST)
         top = P * self.PS - 1
         self._case([top, top // 2, 0], P, seed=P)
+
+    @pytest.mark.parametrize("P", [1, 5])
+    def test_a_table_narrower_than_a_visit(self, P):
+        """A visit is no wider than the table: one visit of ``P``
+        slots, whatever the cap, the last lane's ending mid-page."""
+        assert self.visit(P) == P
+        self._case([P * self.PS - 1, 2, P * self.PS - 3], P, Q=2, seed=P)
+
+    @pytest.mark.parametrize("fp8", [False, True], ids=["f32", "fp8"])
+    def test_pages_not_held_are_never_read(self, fp8):
+        """Every page no lane holds is NaN, the null page too, and
+        every table slot past a lane's last live page names the null
+        page, as the engine leaves it. The last visits hold 1, 3 and 6
+        live slots of 7, so the slots not fetched keep the buffers'
+        rows as they were (NaN in the interpreter's fresh VMEM): the
+        output is finite and equals the xla path's over the same pool
+        with those pages zeroed."""
+        ps, P = self.PS, 2 * self.MOST + 3
+        B = self.visit(P)
+        qbase = np.asarray([B * ps, (B + 2) * ps + 1, (B + 5) * ps + 2],
+                           np.int32)
+        rng = np.random.default_rng(11)
+        N = qbase.size
+        kv = _mk_kv(rng, 2, 1 + N * P, self.HKV, ps, self.HD, fp8=fp8)
+        last = qbase // ps
+        live = np.arange(P)[None, :] <= last[:, None]
+        tables = np.where(live, 1 + np.arange(N * P).reshape(N, P), 0)
+        held = np.isin(np.arange(1 + N * P), tables[live])
+        assert not held[0] and (~held).sum() > 1
+        dirty = {n: a.at[:, ~held].set(jnp.nan) for n, a in kv.items()}
+        clean = {n: a.at[:, ~held].set(0) for n, a in kv.items()}
+        q = jnp.asarray(rng.standard_normal((N, 1, self.HKV, self.HD)),
+                        jnp.float32)
+        args = (jnp.asarray(tables, jnp.int32), jnp.asarray(qbase))
+        ker = np.asarray(paged_attention(q, dirty, 1, *args,
+                                         mode="interpret"))
+        xla = np.asarray(paged_attention(q, clean, 1, *args, mode="xla"))
+        assert np.isfinite(ker).all()
+        np.testing.assert_allclose(ker, xla, atol=2e-5, rtol=2e-5)
 
     @pytest.mark.parametrize("fp8", [False, True], ids=["f32", "fp8"])
     @pytest.mark.parametrize("Q", [1, 4, 8],
@@ -294,12 +377,13 @@ class TestLiveWalk:
         """1 to 32 rows a KV head: both the few-rows and the many-rows
         form of the visit, queries straddling a visit's edge, and (the
         last lane) a padded suffix running past the table's end."""
-        P = 2 * self.B + 2
-        self._case([self.VISIT - 2, 3, P * self.PS - 3], P, Q=Q, G=G,
+        P = 2 * self.MOST + 2
+        visit = self.visit(P) * self.PS
+        self._case([visit - 2, 3, P * self.PS - 3], P, Q=Q, G=G,
                    fp8=fp8, seed=10 * G + Q)
 
     def test_the_schedule_covers_the_pages_held_and_nothing_else(self):
-        ps, B, P = self.PS, self.B, 2 * self.B + 3
+        ps, B, P = self.PS, self.MOST, 2 * self.MOST + 3
         qbase = np.asarray([0, ps, B * ps - 1, B * ps, P * ps - 1,
                             P * ps + 40], np.int32)
         tables = np.arange(qbase.size * P, dtype=np.int32) \
@@ -331,10 +415,52 @@ class TestLiveWalk:
         (64, 64, False, False)])
     def test_heads_a_visit_fit_the_budget(self, Hkv, rows, fp8, whole):
         """Whole lane tiles of heads (two heads of 64), every head
-        where the visit fits."""
-        h = pa._heads_a_visit(Hkv, rows, 64, 16, 8, 1 if fp8 else 2, 2,
-                              fp8)
+        where a visit of 8 pages fits; then as many pages as fit."""
+        h, B = pa._visit_shape(Hkv, rows, 64, 16, 64, 1 if fp8 else 2, 2,
+                               fp8)
         assert Hkv % h == 0 and h % 2 == 0 and (h == Hkv) == whole
+        assert B in (8, 16, 32, 64)
+
+    @pytest.mark.parametrize("Hkv,rows,hd,P,B", [
+        (4, 8, 128, 768, 64),             # mellum2 full layer, 16 KB
+        (4, 8, 128, 65, 33),              # its ring: 33 + 32, not 64 + 1
+        (8, 4, 64, 64, 64),               # lfm2-24b-a2b, 16 KB
+        (8, 8, 128, 320, 32),             # k-exaone global layer, 32 KB
+        (8, 8, 128, 9, 9),                # its ring of 9
+        (20, 1, 64, 64, 32)])             # gpt2-large, 40 KB
+    def test_pages_a_visit_follow_the_page_bytes(self, Hkv, rows, hd, P,
+                                                 B, most_pages):
+        """The cells' decode shapes (bf16 pages of 16 rows): the most
+        pages whose two buffers fit beside the working set, spread
+        evenly over the table."""
+        most_pages(64)
+        assert pa._visit_shape(Hkv, rows, hd, 16, P, 2, 2, False) \
+            == (Hkv, B)
+
+    @pytest.mark.parametrize("most", [4, 3], ids=["divides", "does-not"])
+    def test_a_ring_walk_across_the_wrap(self, most, most_pages):
+        """Rings of 8 pages of 4 under a window of 25 (walks of 7 or
+        8 pages) in visits of 4 (2 a ring) and of 3 (3 a ring, the last
+        of 1 or 2): walks that start mid-ring and cross the ring's wrap,
+        against the xla path."""
+        most_pages(most)
+        ps, R, window, Hkv, hd = 4, 8, 25, 2, 8
+        assert pa._visit_shape(Hkv, 1, hd, ps, R, 4, 4, False)[1] == most
+        # the walk's first page number, mod R: 0, 5, 6, 7 (with laps)
+        pos = np.asarray([24, 5 * ps + 24 + 2, (R + 6) * ps + 24,
+                          (3 * R + 7) * ps + 24 + 3, 10], np.int32)
+        rng = np.random.default_rng(most)
+        N = pos.size
+        kv = _mk_kv(rng, 1, N * R, Hkv, ps, hd)
+        tables = jnp.asarray(np.arange(N * R).reshape(N, R), jnp.int32)
+        q = jnp.asarray(rng.standard_normal((N, 1, 2 * Hkv, hd)),
+                        jnp.float32)
+        got = [np.asarray(paged_attention(q, kv, 0, tables,
+                                          jnp.asarray(pos), mode=m,
+                                          window=window))
+               for m in ("interpret", "xla")]
+        assert np.isfinite(got[0]).all()
+        np.testing.assert_allclose(*got, atol=2e-5, rtol=2e-5)
 
 
 # --------------------------------------------- heads along the lanes
@@ -348,11 +474,16 @@ class TestPageRows:
     and four; one query a lane, a verify call's few, a suffix
     prefill's bucket; fp8 pools with their scale planes. The tables
     are wider than a visit, so every case walks several visits and
-    ends on one whose last slots are clamped to the last live page
-    (``_walk_case`` fills what lies past it with NaN)."""
+    ends on one whose last slots lie past the last live page and are
+    not fetched (``_walk_case`` fills what lies past it with NaN)."""
 
     PS = 16
-    B = pa._PAGES_A_VISIT
+    B = 8                                 # table slots a visit, at most
+
+    @pytest.fixture(scope="class", autouse=True)
+    def _cap(self):
+        with _most_pages(self.B):
+            yield
 
     #: name -> KV heads, head width, query heads a KV head, queries
     CASES = {
@@ -370,7 +501,7 @@ class TestPageRows:
 
     def _case(self, name, fp8=False):
         Hkv, hd, G, Q = self.CASES[name]
-        P = self.B + 3                    # 2 visits, the last of 3 pages
+        P = self.B + 3                    # 2 visits, of 6 and 5 pages
         top = P * self.PS - Q
         lanes = [top, self.B * self.PS + 1, 5] if Q < 8 else [top - 7]
         _walk_case(lanes, P, ps=self.PS, hd=hd, Hkv=Hkv, Q=Q, G=G,
@@ -388,7 +519,8 @@ class TestPageRows:
 
     def test_a_clamped_last_visit_reads_nothing_past_the_last_page(self):
         """One lane whose last visit holds a single live page of
-        ``B``: the other ``B - 1`` slots name that page again."""
+        ``B``: the other ``B - 1`` slots are neither fetched nor
+        waited on."""
         P = 2 * self.B
         _walk_case([self.B * self.PS + 2], P, ps=self.PS, hd=64, Hkv=4)
 
